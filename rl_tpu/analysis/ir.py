@@ -56,10 +56,12 @@ CALLBACK_PRIMS = frozenset({
     "pure_callback", "io_callback", "debug_callback", "debug_print",
     "outside_call", "host_callback_call",
 })
-# cross-device primitives (R103); psum lowered as psum2 on current jax
+# cross-device primitives (R103); inside shard_map the installed jax
+# traces psum / all_gather as their *_invariant forms
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "pmean", "all_gather", "all_to_all",
-    "ppermute", "pbroadcast", "reduce_scatter", "psum_scatter",
+    "psum", "psum_invariant", "pmax", "pmin", "pmean", "all_gather",
+    "all_gather_invariant", "all_to_all", "ppermute", "pbroadcast",
+    "reduce_scatter", "psum_scatter",
 })
 # HLO op names the SPMD partitioner may insert post-trace (R103)
 _HLO_COLLECTIVE_RE = re.compile(
@@ -165,14 +167,14 @@ _KERNEL_CALL_PRIMS = ("pallas_call", "custom_call", "tpu_custom_call")
 
 
 def _call_target(params: dict) -> str:
-    """Best-effort call-target name of a kernel-call eqn (pallas names
-    the kernel body function via ``name_and_src_info``)."""
-    nsi = params.get("name_and_src_info")
-    name = (
-        getattr(nsi, "name", None)
-        or params.get("name")
-        or params.get("call_target_name")
-    )
+    """Call-target name of a kernel-call eqn: the ``name=`` its
+    ``pallas_call`` was given, else the kernel body function's name
+    (which a ``functools.partial`` kernel keeps only in the traced
+    jaxpr's debug info)."""
+    name = params.get("name") or params.get("call_target_name")
+    if not name:
+        info = getattr(params.get("jaxpr"), "debug_info", None)
+        name = getattr(info, "func_name", None)
     return str(name) if name else ""
 
 

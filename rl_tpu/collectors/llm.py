@@ -15,6 +15,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..analysis import hot_path
 from ..data import ArrayDict
@@ -108,9 +109,30 @@ class LLMCollector:
         if ref_params is not None:
             from ..models import token_log_probs
 
+            # the reference weights are an ARGUMENT: closed over, they are
+            # baked into the program as constants (a 763 MB executable at
+            # 110M, seen on the chip — slow to compile, too big to cache)
             self._ref_lp = jax.jit(
-                lambda toks, mask: token_log_probs(model, ref_params, toks, mask)
+                lambda params, toks, mask: token_log_probs(model, params, toks, mask)
             )
+            # reference weights placed on a mesh (ring / FSDP trainers):
+            # scoring runs where they live, on inputs replicated over it
+            sh = jax.tree.leaves(ref_params)[0].sharding
+            self._ref_replicated = (
+                NamedSharding(sh.mesh, PartitionSpec())
+                if isinstance(sh, NamedSharding)
+                else None
+            )
+
+    def _score_ref(self, toks, mask):
+        """Reference-policy log-probs of a rollout batch, returned where
+        the batch lives (device-to-device moves only)."""
+        if self._ref_replicated is None:
+            return self._ref_lp(self.ref_params, toks, mask)
+        lp = self._ref_lp(
+            self.ref_params, *jax.device_put((toks, mask), self._ref_replicated)
+        )
+        return jax.device_put(lp, toks.sharding)
 
     @hot_path(reason="drives the engine decode loop per rollout batch")
     def _engine_generate(self, params, toks, pmask, key, on_row_done=None):
@@ -350,7 +372,7 @@ class LLMCollector:
             "group_id": gid,
         }
         if self.ref_params is not None:
-            arrays["ref_log_prob"] = self._ref_lp(
+            arrays["ref_log_prob"] = self._score_ref(
                 arrays["tokens"], arrays["attention_mask"]
             )
         if self.reward_transform is not None:

@@ -431,6 +431,14 @@ class CachedProgram:
         with self._lock:
             return len(self._compiled)
 
+    def hlo_texts(self) -> list[str]:
+        """Optimized HLO of every executable in the table (compiled here
+        or loaded from the store) — what ``chip_smoke.py`` reads to see
+        that a kernel is in the program as a ``tpu_custom_call``."""
+        with self._lock:
+            compiled = list(self._compiled.values())
+        return [c.as_text() for c in compiled]
+
 
 class WarmupHandle:
     """Background ``aot_warmup``: join via :meth:`result` (re-raises any

@@ -4,7 +4,7 @@ program signature, so a restarted worker *loads* instead of recompiles.
 Two cache layers exist and they solve different problems:
 
 - JAX's persistent **compilation cache** (``jax_compilation_cache_dir``,
-  enabled by default via :func:`rl_tpu.compile.ensure_persistent_cache`)
+  enabled by default via :func:`rl_tpu.config.enable_compile_cache`)
   caches the XLA *backend compile* keyed by optimized HLO. It still pays
   tracing + lowering on every process start, and its key is only
   computable *after* lowering.
@@ -20,13 +20,15 @@ Two cache layers exist and they solve different problems:
 The key deliberately hashes the *registration-time* signature rather
 than the jaxpr: two programs registered under the same name with the
 same avals but different Python closures would collide, so the registry
-includes a caller-supplied ``fingerprint`` (source hash) in the key.
+includes a caller-supplied ``fingerprint`` (the closure's configuration)
+in the key, and the store adds a hash of the ``rl_tpu`` sources so an
+executable never outlives the code it was built from.
 Feature detection is per call — ``serialize`` raises on backends/
 executables that don't support it, and every failure degrades to the
 lower+compile path, never to an error.
 
-Layout on disk: one ``<sha256>.jexec`` pickle per executable —
-``(header_dict, payload, in_tree, out_tree)`` — plus a sibling
+Layout on disk: one ``<sha256>.jexec`` zlib-compressed pickle per
+executable — ``(header_dict, payload, in_tree, out_tree)`` — plus a sibling
 ``.json`` header for ``ls``-ability. Writes are atomic (tmp + rename)
 so concurrent fleet members racing on the same key are safe: last
 writer wins with identical content.
@@ -34,6 +36,7 @@ writer wins with identical content.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -41,6 +44,7 @@ import pickle
 import tempfile
 import threading
 import time
+import zlib
 from typing import Any
 
 __all__ = [
@@ -56,16 +60,24 @@ _ENV_DISABLE = "RL_TPU_NO_EXEC_STORE"
 _SUFFIX = ".jexec"
 
 
-def _serialize_mod():
-    """The serialize/deserialize entry points, or None when this jax
-    build lacks them (graceful-fallback satellite)."""
-    try:
-        from jax.experimental import serialize_executable as se
-    except Exception:
-        return None
-    if not hasattr(se, "serialize") or not hasattr(se, "deserialize_and_load"):
-        return None
-    return se
+@functools.cache
+def _code_version() -> str:
+    """Hash of the ``rl_tpu`` sources. The signature key below never sees
+    the traced function, so without this an executable built from an
+    older checkout would load under the same key once the cache directory
+    outlives the code (``JAX_COMPILATION_CACHE_DIR`` on a shared machine;
+    parent and change measured in one call) — and run the old program."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames.sort()
+        for fn in sorted(filenames):
+            if fn.endswith(".py"):
+                path = os.path.join(dirpath, fn)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()
 
 
 def _sharding_sig(sh: Any) -> str:
@@ -126,7 +138,9 @@ class ExecutableStore:
     """sha-keyed persistent store of serialized XLA executables.
 
     ``root=None`` resolves ``$RL_TPU_EXEC_STORE_DIR`` then
-    ``~/.cache/rl_tpu/executables``; ``$RL_TPU_NO_EXEC_STORE=1``
+    ``executables/`` under :func:`rl_tpu.config.compile_cache_dir` (so
+    ``JAX_COMPILATION_CACHE_DIR`` places both layers);
+    ``$RL_TPU_NO_EXEC_STORE=1``
     disables persistence (the in-memory layer still works, so duplicate
     programs within one process — e.g. N identical fleet engines —
     still compile once).
@@ -134,8 +148,10 @@ class ExecutableStore:
 
     def __init__(self, root: str | None = None, *, memory_cache: bool = True):
         if root is None:
-            root = os.environ.get(_ENV_DIR) or os.path.expanduser(
-                "~/.cache/rl_tpu/executables"
+            from ..config import compile_cache_dir
+
+            root = os.environ.get(_ENV_DIR) or os.path.join(
+                compile_cache_dir(), "executables"
             )
         self.root = root
         self.disabled = os.environ.get(_ENV_DISABLE, "") not in ("", "0")
@@ -160,7 +176,8 @@ class ExecutableStore:
             backend = jax.default_backend()
         h = hashlib.sha256()
         for part in (
-            "rl_tpu.exec.v1",
+            "rl_tpu.exec.v2",
+            _code_version(),
             jax.__version__,
             backend,
             name,
@@ -223,18 +240,23 @@ class ExecutableStore:
                 self._mem[key] = compiled
         if self.disabled:
             return False
-        se = _serialize_mod()
-        if se is None:
-            return False
+        from jax.experimental import serialize_executable
+
         try:
-            payload, in_tree, out_tree = se.serialize(compiled)
+            payload, in_tree, out_tree = serialize_executable.serialize(compiled)
             header = {
                 "version": 1,
                 "key": key,
                 "created": time.time(),
                 **(meta or {}),
             }
-            blob = pickle.dumps((header, payload, in_tree, out_tree), protocol=4)
+            # compressed: a serialized TPU executable is several times
+            # the size of jax's own (compressed) cache entry for the same
+            # program — 490 MB against 60 MB for chip_smoke.py's thirty
+            # programs — and both live under one size-limited directory
+            blob = zlib.compress(
+                pickle.dumps((header, payload, in_tree, out_tree), protocol=4), 3
+            )
         except Exception:
             with self._lock:
                 self.stats["errors"] += 1
@@ -272,15 +294,20 @@ class ExecutableStore:
         if self.disabled:
             return None
         path = self._path(key)
-        se = _serialize_mod()
-        if se is None or not os.path.exists(path):
+        if not os.path.exists(path):
             with self._lock:
                 self.stats["misses"] += 1
             return None
+        from jax.experimental import serialize_executable
+
         try:
             with open(path, "rb") as f:
-                header, payload, in_tree, out_tree = pickle.load(f)
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+                header, payload, in_tree, out_tree = pickle.loads(
+                    zlib.decompress(f.read())
+                )
+            compiled = serialize_executable.deserialize_and_load(
+                payload, in_tree, out_tree
+            )
         except Exception:
             # a corrupt/incompatible entry must not wedge startup: evict
             # it so the compile path rebuilds and overwrites.

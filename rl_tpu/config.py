@@ -24,43 +24,52 @@ from typing import Any, Callable, Mapping, Sequence
 
 __all__ = [
     "register", "get_component", "instantiate", "load_yaml", "to_dict",
-    "REGISTRY", "enable_compile_cache",
+    "REGISTRY", "compile_cache_dir", "enable_compile_cache",
 ]
 
-# -- persistent compilation cache (ROADMAP item 5) --------------------------
+# -- persistent compilation cache -------------------------------------------
 #
-# Wired by default: the first ProgramRegistry (any registered trainer or
-# serving engine) calls enable_compile_cache(), so every XLA backend compile
-# lands in an on-disk cache keyed by optimized HLO and a process restart
-# skips the backend-compile half of cold start. Opt out with
-# RL_TPU_NO_COMPILE_CACHE=1; point the cache elsewhere (CI sandboxes, test
-# tmpdirs) with RL_TPU_COMPILE_CACHE_DIR.
+# ONE rule places every cache the program keeps (XLA compile cache here,
+# the executable store under it — rl_tpu.compile.store): the directory
+# JAX_COMPILATION_CACHE_DIR names, which jax reads by itself; otherwise
+# <checkout>/.jax_cache. The path is part of the cache key, so it is
+# never built from a tmpdir, a pid or the time. bench.py, chip_smoke.py
+# and tests/conftest.py call enable_compile_cache(); so does the first
+# ProgramRegistry (any registered trainer or serving engine). Opt out with
+# RL_TPU_NO_COMPILE_CACHE=1.
 
 _ENV_NO_CACHE = "RL_TPU_NO_COMPILE_CACHE"
-_ENV_CACHE_DIR = "RL_TPU_COMPILE_CACHE_DIR"
+
+
+def compile_cache_dir() -> str:
+    """Where the caches live: what jax is already configured with (the
+    ``JAX_COMPILATION_CACHE_DIR`` variable, or an earlier call), else
+    ``.jax_cache`` beside the ``rl_tpu`` package."""
+    import os
+
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+    )
 
 
 def enable_compile_cache() -> str | None:
-    """Idempotently enable JAX's persistent compilation cache. Returns the
-    cache dir in use, or None when opted out. A dir already configured
-    (bench/_setup_jax, tests/conftest) is respected, not overridden."""
+    """Idempotently enable JAX's persistent compilation cache at
+    :func:`compile_cache_dir`. Returns the dir in use, or None when opted
+    out. A dir jax already has (from the environment) is left as it is:
+    nothing is set in code then."""
     import os
 
     if os.environ.get(_ENV_NO_CACHE, "") not in ("", "0"):
         return None
     import jax
 
-    current = jax.config.jax_compilation_cache_dir
-    if current:
-        return current
-    path = os.environ.get(_ENV_CACHE_DIR) or os.path.expanduser(
-        "~/.cache/rl_tpu_jax_cache"
-    )
-    jax.config.update("jax_compilation_cache_dir", path)
-    # fused trainer programs are the target; sub-second toy programs churn
-    # the cache for no win
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    path = compile_cache_dir()
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", path)
     return path
+
 
 REGISTRY: dict[str, Callable] = {}
 

@@ -1,12 +1,9 @@
-"""Native extension loader: host segment trees with graceful fallback.
+"""Native extension loader: host segment trees.
 
-Mirrors the reference's optional-extension pattern (reference:
-torchrl/_extension.py:40 ``_init_extension`` / :54 ``EXTENSION_WARNING`` —
-soft-fail to Python when the compiled module is missing): the C++ tree
-(segment_tree.cpp) is compiled on first import with g++ into a cached
-shared library and bound via ctypes; if no toolchain is available, a
-numpy fallback with identical semantics loads instead
-(``SumSegmentTree.IS_NATIVE`` tells you which you got).
+The C++ tree (segment_tree.cpp) is compiled with g++ at first
+construction into ``_build/`` (ignored by git: the library is built on
+the machine that runs it, never committed) and bound via ctypes. A build
+that fails raises — host PER does not quietly run on something slower.
 """
 
 from __future__ import annotations
@@ -14,16 +11,10 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import warnings
 
 import numpy as np
 
-__all__ = ["SumSegmentTree", "MinSegmentTree", "EXTENSION_WARNING"]
-
-EXTENSION_WARNING = (
-    "rl_tpu C++ segment-tree extension could not be built; falling back to "
-    "the numpy implementation (slower host-side prioritized sampling)."
-)
+__all__ = ["SumSegmentTree", "MinSegmentTree"]
 
 _LIB = None
 
@@ -35,19 +26,24 @@ def _build_and_load():
     src = os.path.join(os.path.dirname(__file__), "segment_tree.cpp")
     cache_dir = os.path.join(os.path.dirname(__file__), "_build")
     lib_path = os.path.join(cache_dir, "libsegment_tree.so")
-    try:
-        if not os.path.exists(lib_path) or os.path.getmtime(lib_path) < os.path.getmtime(src):
-            os.makedirs(cache_dir, exist_ok=True)
+    if not os.path.exists(lib_path) or os.path.getmtime(lib_path) < os.path.getmtime(src):
+        os.makedirs(cache_dir, exist_ok=True)
+        # build beside the target and rename: a concurrent first use in
+        # another process never loads a half-written library
+        tmp = f"{lib_path}.{os.getpid()}.tmp"
+        try:
             subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", lib_path],
+                ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
                 check=True,
                 capture_output=True,
+                text=True,
             )
-        lib = ctypes.CDLL(lib_path)
-    except (OSError, subprocess.CalledProcessError) as e:  # pragma: no cover
-        warnings.warn(f"{EXTENSION_WARNING} ({e})")
-        _LIB = False
-        return False
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"building {src} failed (g++ exit {e.returncode}): {e.stderr}"
+            ) from e
+        os.replace(tmp, lib_path)
+    lib = ctypes.CDLL(lib_path)
 
     lib.st_new.restype = ctypes.c_void_p
     lib.st_new.argtypes = [ctypes.c_int64, ctypes.c_int32]
@@ -94,13 +90,8 @@ def _f64(a):
 
 
 class _NativeTree:
-    IS_NATIVE = True
-
     def __init__(self, capacity: int, is_min: bool):
-        lib = _build_and_load()
-        if lib is False:  # pragma: no cover
-            raise ImportError(EXTENSION_WARNING)
-        self._lib = lib
+        self._lib = lib = _build_and_load()
         self.capacity = capacity
         self._h = ctypes.c_void_p(lib.st_new(capacity, 1 if is_min else 0))
         if not self._h:
@@ -145,14 +136,8 @@ class _NativeTree:
 
 class SumSegmentTree(_NativeTree):
     """O(log N) sum tree with prefix-sum search (reference SumSegmentTree,
-    csrc/segment_tree.h:243). Falls back to the numpy implementation when
-    no toolchain is available (build happens lazily at FIRST construction —
-    importing rl_tpu stays side-effect free)."""
-
-    def __new__(cls, capacity: int):
-        if _build_and_load() is False:  # pragma: no cover
-            return _NumpySumTree(capacity)
-        return super().__new__(cls)
+    csrc/segment_tree.h:243). The build happens lazily at FIRST
+    construction — importing rl_tpu stays side-effect free."""
 
     def __init__(self, capacity: int):
         super().__init__(capacity, is_min=False)
@@ -173,19 +158,13 @@ class SumSegmentTree(_NativeTree):
 class MinSegmentTree(_NativeTree):
     """O(log N) min tree (reference MinSegmentTree, csrc/segment_tree.h:303)."""
 
-    def __new__(cls, capacity: int):
-        if _build_and_load() is False:  # pragma: no cover
-            return _NumpyMinTree(capacity)
-        return super().__new__(cls)
-
     def __init__(self, capacity: int):
         super().__init__(capacity, is_min=True)
 
 
 class _NumpySumTree:
-    """Fallback with identical semantics (O(N) scan)."""
-
-    IS_NATIVE = False
+    """Plain numpy reference with identical semantics (O(N) scan): what
+    ``tests/test_csrc.py`` holds the native tree to."""
 
     def __init__(self, capacity: int):
         self.capacity = capacity
@@ -203,22 +182,3 @@ class _NumpySumTree:
     def scan(self, us):
         cs = np.cumsum(self._v)
         return np.clip(np.searchsorted(cs, np.atleast_1d(us), side="right"), 0, self.capacity - 1)
-
-
-class _NumpyMinTree:
-    IS_NATIVE = False
-
-    def __init__(self, capacity: int):
-        self.capacity = capacity
-        self._v = np.full(capacity, np.inf, np.float64)
-
-    def __setitem__(self, idx, value):
-        self._v[idx] = value
-
-    def __getitem__(self, idx):
-        return self._v[idx]
-
-    def reduce(self, start: int = 0, end: int | None = None) -> float:
-        return float(self._v[start:end].min())
-
-

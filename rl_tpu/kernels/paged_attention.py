@@ -142,6 +142,7 @@ def paged_flash_decode_int8(
     )
     out = pl.pallas_call(
         kernel,
+        name="_paged_decode_int8_kernel",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S * H, 8, D), q.dtype),
         interpret=interpret,

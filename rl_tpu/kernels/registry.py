@@ -13,10 +13,10 @@ a trace gets, and the one place the rest of the framework asks about it:
   ``accuracy-gated``). The four tier kernels self-register below.
 - :func:`selection` resolves a kernel to ``"native"`` (real Mosaic
   lowering), ``"interpret"`` (Pallas interpret mode — how tier-1 proves
-  parity on CPU and how the bench A/Bs the kernels without a chip), or
-  ``None`` (stock-XLA fallback). ``RL_TPU_NO_KERNELS`` force-disables
-  (``1`` = all, or a comma list of kernel names);
-  ``RL_TPU_KERNELS_INTERPRET`` opts interpret mode in on any backend.
+  parity on CPU), or ``None`` (the stock-XLA path).
+  ``RL_TPU_NO_KERNELS`` force-disables (``1`` = all, or a comma list of
+  kernel names); ``RL_TPU_KERNELS_INTERPRET`` opts interpret mode in on
+  any backend but ``"tpu"``, where it is an error.
 - :func:`price_call` is the IR cost model's hook
   (:func:`rl_tpu.analysis.ir.summarize_jaxpr`): a ``pallas_call`` counts
   0 FLOPs / 0 bytes under the generic per-equation rules, which would
@@ -47,6 +47,7 @@ __all__ = [
     "kernel_targets",
     "kernels_fingerprint",
     "price_call",
+    "refuse_interpret_on_tpu",
     "register_kernel",
     "registered_kernels",
     "selection",
@@ -70,7 +71,7 @@ class KernelSpec:
 
     name: str
     # jaxpr call-target substrings this kernel's pallas_call lowers under
-    # (the kernel body function's name rides pallas' name_and_src_info)
+    # (the ``name=`` the call passes: the kernel body function's name)
     targets: tuple = ()
     # backends whose native Mosaic lowering supports the kernel
     backends: tuple = ("tpu",)
@@ -106,27 +107,38 @@ def _disabled(name: str) -> bool:
 
 
 def _backend() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend()
-    except Exception:
-        return ""
+    return jax.default_backend()
+
+
+def refuse_interpret_on_tpu(switch: str, backend: str | None = None) -> None:
+    """Raise if ``switch`` (an interpret-mode override that is set) meets
+    backend ``"tpu"``: there the Pallas interpreter would run where Mosaic
+    is available and be reported as the kernel tier."""
+    if (backend if backend is not None else _backend()) == "tpu":
+        raise RuntimeError(
+            f"{switch} is set on backend 'tpu': the Pallas interpreter is a "
+            "CPU test mode, not a way to run on the chip — unset it"
+        )
 
 
 def selection(name: str, backend: str | None = None) -> str | None:
-    """``"native"`` | ``"interpret"`` | ``None`` (stock-XLA fallback).
+    """``"native"`` | ``"interpret"`` | ``None`` (stock-XLA path).
 
-    Interpret mode outranks native when both would apply — it is an
-    explicit test/bench request (``RL_TPU_KERNELS_INTERPRET=1``) and the
-    parity gate needs the interpreter, not Mosaic.
+    Interpret mode is how tier-1 proves parity on a host without the
+    chip (``RL_TPU_KERNELS_INTERPRET=1``). On backend ``"tpu"`` it is
+    never a mode: the override set there would run the Pallas
+    interpreter where Mosaic is available and report it as the kernel
+    tier, so it raises.
     """
     spec = _KERNELS.get(name)
     if spec is None or _disabled(name):
         return None
-    if os.environ.get(ENV_INTERPRET, "") not in ("", "0"):
-        return "interpret"
     b = backend if backend is not None else _backend()
+    if os.environ.get(ENV_INTERPRET, "") not in ("", "0"):
+        refuse_interpret_on_tpu(ENV_INTERPRET, b)
+        return "interpret"
     if b in spec.backends:
         return "native"
     return None

@@ -3,29 +3,38 @@
 The stock path (``rl_tpu.models.speculative.sample_tokens``) lowers to a
 full-vocab log-softmax, a separate gumbel materialization, an argmax, and
 a gather — four [S, V] traversals stitched by XLA. The fused kernel does
-scale → (optional) top-k filter → log-softmax → gumbel-argmax → logprob
-gather in ONE pass with the vocab row resident in VMEM.
+scale → (optional) top-k filter → log-softmax → gumbel-argmax → chosen
+log-prob in ONE pass over a block of whole vocab rows resident in VMEM.
+
+What Mosaic compiles (v5e, ``tests/test_chip_compile.py``): the grid runs
+over blocks of 8 rows, never over the vocab, so every row's softmax
+reduction is whole inside one grid step; the chosen log-prob is a
+one-hot masked max instead of a gather; the top-k threshold is computed
+outside the kernel (``lax.top_k`` has no in-kernel lowering) and enters
+as a ``[S, 1]`` operand.
 
 Bit-exactness contract (the PR 16 guarantee rides on this):
 
-- The **fallback** (``mode is None``) with ``top_k=0`` is literally the
+- The **stock path** (``mode is None``) with ``top_k=0`` is literally the
   legacy ``sample_tokens`` body — same ops, same order — so it is
   bitwise-identical to every artifact PR 16 committed.
 - The **kernel** consumes the same f32 logits plus gumbel noise computed
   OUTSIDE with the exact key math ``jax.random.categorical`` uses
   (categorical(key, lps) ≡ argmax(gumbel(key, lps.shape, lps.dtype) +
-  lps)), and its body is whole-array jnp ops over the same shapes — so
-  interpret mode reproduces the fallback bit for bit. f32 add is
-  commutative bitwise and argmax ties resolve to the first index in
-  both.
+  lps)), and its body is jnp ops over whole rows — so interpret mode
+  reproduces the stock path bit for bit. f32 add is commutative bitwise,
+  argmax ties resolve to the first index in both, and a max over one
+  unmasked element returns that element's bits.
 - Greedy argmaxes the UNSCALED f32 logits: bf16→f32 is monotone and
   injective, so ties (and their first-index resolution) match the legacy
   ``argmax(logits)`` exactly; dividing by temperature first could round
   two distinct logits onto the same value and flip a tie.
 
-Top-k keeps the k highest scaled logits (ties at the threshold all
-survive, matching ``lax.top_k``'s value threshold) and sends the rest to
--inf before the softmax; ``top_k=0`` disables filtering.
+Top-k keeps every logit at or above the k-th highest (ties at the
+threshold all survive) and sends the rest to -inf before the softmax;
+the comparison is made on the unscaled logits — the temperature is
+positive, so the order is the same, and no backend's division rounding
+can move a logit across the threshold. ``top_k=0`` disables filtering.
 """
 
 from __future__ import annotations
@@ -34,36 +43,32 @@ import functools
 
 from . import registry
 
+_ROWS = 8  # f32 sublane tile: rows per grid step
 
-def _kernel_body(x, g, t, *, greedy, top_k):
-    """Shared math: runs as the Pallas kernel body AND (op-for-op) as the
-    stock-XLA fallback, so parity is by construction. x, g: [S, V] f32;
-    t: f32 scalar. Returns (tok [S] int32, lp [S] f32)."""
+
+def _fused_sample_kernel(t_ref, x_ref, *refs, greedy, top_k):
+    """t [1] in SMEM; x [rows, V]; then g [rows, V] unless greedy, thr
+    [rows, 1] if top_k; outputs tok [rows, 1] int32, lp [rows, 1] f32."""
     import jax
     import jax.numpy as jnp
 
-    xs = x / t
-    if top_k:
-        thr = jax.lax.top_k(xs, top_k)[0][:, -1:]
-        xs = jnp.where(xs >= thr, xs, -jnp.inf)
+    refs = list(refs)
+    g_ref = None if greedy else refs.pop(0)
+    thr_ref = refs.pop(0) if top_k else None
+    tok_ref, lp_ref = refs
+
+    x = x_ref[...]
+    xs = x / t_ref[0]
+    if thr_ref is not None:
+        xs = jnp.where(x >= thr_ref[...], xs, -jnp.inf)
     lps = jax.nn.log_softmax(xs, axis=-1)
-    if greedy:
-        tok = jnp.argmax(x, axis=-1).astype(jnp.int32)
-    else:
-        tok = jnp.argmax(g + lps, axis=-1).astype(jnp.int32)
-    lp = jnp.take_along_axis(lps, tok[:, None].astype(jnp.int32), axis=-1)[:, 0]
-    return tok, lp
-
-
-def _fused_sample_kernel(x_ref, g_ref, t_ref, tok_ref, lp_ref, *, greedy, top_k):
-    # grid=1, whole-[S, V] blocks: the body IS the fallback math, so
-    # interpret mode is bitwise the fallback (no per-tile reduction
-    # reordering to reason about)
-    tok, lp = _kernel_body(
-        x_ref[...], g_ref[...], t_ref[0, 0], greedy=greedy, top_k=top_k
+    score = x if greedy else g_ref[...] + lps
+    tok = jnp.argmax(score, axis=-1).astype(jnp.int32)[:, None]
+    col = jax.lax.broadcasted_iota(jnp.int32, lps.shape, 1)
+    tok_ref[...] = tok
+    lp_ref[...] = jnp.max(
+        jnp.where(col == tok, lps, -jnp.inf), axis=-1, keepdims=True
     )
-    tok_ref[...] = tok[:, None]
-    lp_ref[...] = lp[:, None]
 
 
 def _gumbel_like(key, x):
@@ -86,6 +91,7 @@ def fused_sample(logits, key, *, temperature=1.0, greedy=False, top_k=0):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     mode = registry.selection("sampling")
     x = logits.astype(jnp.float32)
@@ -95,13 +101,13 @@ def fused_sample(logits, key, *, temperature=1.0, greedy=False, top_k=0):
     top_k = top_k or 0
     if top_k >= x.shape[-1]:
         top_k = 0  # keeping the whole vocab = no filter
+    thr = jax.lax.top_k(x, top_k)[0][:, -1:] if top_k else None
 
     if mode is None:
         # Legacy sample_tokens body, verbatim (top_k=0): PR 16 bit-exact.
         xs = x / t
         if top_k:
-            thr = jax.lax.top_k(xs, top_k)[0][:, -1:]
-            xs = jnp.where(xs >= thr, xs, -jnp.inf)
+            xs = jnp.where(x >= thr, xs, -jnp.inf)
         lps = jax.nn.log_softmax(xs, axis=-1)
         if greedy:
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -113,14 +119,35 @@ def fused_sample(logits, key, *, temperature=1.0, greedy=False, top_k=0):
         return tok, lp
 
     S, V = x.shape
-    g = jnp.zeros_like(x) if greedy else _gumbel_like(key, x)
-    kernel = functools.partial(_fused_sample_kernel, greedy=greedy, top_k=top_k)
+    rows = _ROWS if S % _ROWS == 0 else S
+
+    def row_block(width):
+        return pl.BlockSpec((rows, width), lambda i: (i, 0))
+
+    operands = [t.reshape(1), x]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), row_block(V)]
+    if not greedy:
+        operands.append(_gumbel_like(key, x))
+        in_specs.append(row_block(V))
+    if top_k:
+        operands.append(thr)
+        in_specs.append(row_block(1))
     tok, lp = pl.pallas_call(
-        kernel,
+        functools.partial(_fused_sample_kernel, greedy=greedy, top_k=top_k),
+        name="_fused_sample_kernel",
+        grid=(S // rows,),
+        in_specs=in_specs,
+        out_specs=[row_block(1), row_block(1)],
         out_shape=[
             jax.ShapeDtypeStruct((S, 1), jnp.int32),
             jax.ShapeDtypeStruct((S, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # two double-buffered row blocks + the body's block-sized
+            # temporaries (xs, lps, score, iota, mask, ...)
+            vmem_limit_bytes=12 * rows * V * 4 + (4 << 20),
+        ),
         interpret=(mode == "interpret"),
-    )(x, g, t.reshape(1, 1))
+    )(*operands)
     return tok[:, 0], lp[:, 0]

@@ -1,9 +1,9 @@
 """SLO-burn autoscaler: elastic membership control for the serving fleet
 (ISSUE 19 tentpole).
 
-A fixed fleet cannot absorb diurnal+bursty traffic: ``OBS_pr12.json``
-shows TTFT attainment collapsing through a burst+crash window while
-members idle between bursts. RLAX (arXiv 2512.06392) flexes its
+A fixed fleet cannot absorb diurnal+bursty traffic: TTFT attainment
+collapses through a burst+crash window while members idle between
+bursts (``BENCH_MODE=fleet``'s ``obs`` section shows it). RLAX (arXiv 2512.06392) flexes its
 disaggregated generation fleet with load; Podracer (arXiv 2104.06272)
 harvests every idle chip-second. Every signal this control loop needs
 already exists in-tree, which is the whole design:

@@ -75,6 +75,16 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
 
+def _flash_interpret(cfg) -> bool:
+    """``cfg.flash_interpret``, which is a CPU test fixture: on the chip
+    the Pallas interpreter is never a way to run a kernel."""
+    if cfg.flash_interpret:
+        from ..kernels.registry import refuse_interpret_on_tpu
+
+        refuse_interpret_on_tpu("TransformerConfig.flash_interpret")
+    return cfg.flash_interpret
+
+
 def _paged_attention(cfg, q, k, v, cache, active):
     """Attention over a paged KV cache + block-table writes.
 
@@ -150,7 +160,7 @@ def _paged_attention(cfg, q, k, v, cache, active):
     mode = decode_mode(int8=int8) if T == 1 else None
     if T == 1 and (mode is not None or cfg.flash_decode):
         # the block table drives the DMA; the pool is read in place
-        interpret = (mode == "interpret") or cfg.flash_interpret
+        interpret = (mode == "interpret") or _flash_interpret(cfg)
         attend = lens + 1  # decode-after-write: positions 0..len inclusive
         if int8:
             from ..kernels.paged_attention import paged_flash_decode_int8
@@ -302,7 +312,7 @@ class _Attention(nn.Module):
                     v,
                     new_cache["len"],
                     kv_mask=mask,
-                    interpret=cfg.flash_interpret,
+                    interpret=_flash_interpret(cfg),
                 ).astype(cfg.dtype)
             else:
                 kv_pos = jnp.arange(S)
@@ -318,7 +328,7 @@ class _Attention(nn.Module):
 
             # ragged batches ride the kernel: padding mask -> segment ids
             o = flash_attention(
-                q, k, v, causal=True, interpret=cfg.flash_interpret,
+                q, k, v, causal=True, interpret=_flash_interpret(cfg),
                 kv_mask=None if mask is None else mask,
             ).astype(cfg.dtype)
         elif cfg.attention_impl == "ring":

@@ -183,6 +183,7 @@ def _flash_fwd_bhtd(
         operands += [_lane8(qseg), _lane8(kseg)]
     out, lse = pl.pallas_call(
         kernel,
+        name="_fwd_kernel",
         out_shape=(
             jax.ShapeDtypeStruct((BH, T_pad, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T_pad, 8), jnp.float32),
@@ -373,6 +374,7 @@ def _flash_bwd_bhtd(
         operands += [_lane8(qseg), _lane8(kseg)]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
+        name="_bwd_dq_kernel",
         out_shape=jax.ShapeDtypeStruct((BH, T_pad, D), q.dtype),
         grid=(BH, T_pad // block_q, T_pad // block_k),
         in_specs=common_in,
@@ -398,6 +400,7 @@ def _flash_bwd_bhtd(
         dkv_operands += [_lane8(qseg), _lane8(kseg)]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
+        name="_bwd_dkv_kernel",
         out_shape=(
             jax.ShapeDtypeStruct((BH, T_pad, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T_pad, D), v.dtype),
@@ -681,6 +684,7 @@ def flash_decode(
     )
     out = pl.pallas_call(
         kernel,
+        name="_decode_kernel",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 8, D), q.dtype),
         interpret=interpret,
@@ -832,6 +836,7 @@ def paged_flash_decode(
     )
     out = pl.pallas_call(
         kernel,
+        name="_paged_decode_kernel",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S * H, 8, D), q.dtype),
         interpret=interpret,

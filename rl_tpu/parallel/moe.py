@@ -145,7 +145,7 @@ def moe_ffn_ep(
     Output matches :func:`moe_ffn_dense` exactly for kept tokens (modulo
     per-shard capacity rounding; see test oracle).
     """
-    from ._compat import shard_map
+    from jax import shard_map
 
     ep = mesh.shape[axis]
     n, d = x.shape

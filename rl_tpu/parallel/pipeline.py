@@ -97,15 +97,13 @@ def pipeline_apply(
         # other ranks contribute zeros)
         return lax.psum(ys, axis_name)
 
-    from jax.experimental.shard_map import shard_map
-
     spec_params = jax.tree.map(lambda _: PartitionSpec(axis_name), stacked_params)
-    out = shard_map(
+    out = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(spec_params, PartitionSpec()),
         out_specs=PartitionSpec(),
-        check_rep=False,
+        check_vma=False,
     )(stacked_params, xs)
     return out.reshape(B, *x.shape[1:])
 

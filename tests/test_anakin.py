@@ -45,7 +45,7 @@ def make_actor_critic():
 
 
 def make_program(num_envs=8, unroll=8, steps_per_dispatch=1, mesh=None,
-                 device_metrics=True, donate=True, max_episode_steps=20):
+                 device_metrics=True, max_episode_steps=20):
     policy, loss = make_actor_critic()
     cfg = AnakinConfig(
         num_envs=num_envs,
@@ -53,7 +53,6 @@ def make_program(num_envs=8, unroll=8, steps_per_dispatch=1, mesh=None,
         steps_per_dispatch=steps_per_dispatch,
         num_epochs=2,
         minibatch_size=num_envs * unroll // 2,
-        donate=donate,
     )
     return AnakinProgram(
         "cartpole", policy, loss, cfg, mesh=mesh,

@@ -19,8 +19,37 @@ KEY = jax.random.key(0)
 
 
 class TestSumTree:
-    def test_native_built(self):
-        assert SumSegmentTree(8).IS_NATIVE, "C++ extension failed to build"
+    def test_library_is_built_from_source_on_this_machine(self, monkeypatch):
+        """git carries segment_tree.cpp only: with no library on disk the
+        first construction compiles one here (g++), and the tree that
+        comes back is bound to it — there is nothing else to fall to."""
+        import os
+
+        import rl_tpu.csrc as csrc
+
+        here = os.path.dirname(csrc.__file__)
+        lib = os.path.join(here, "_build", "libsegment_tree.so")
+        if os.path.exists(lib):
+            os.remove(lib)
+        monkeypatch.setattr(csrc, "_LIB", None)
+        t = SumSegmentTree(8)
+        assert os.path.getmtime(lib) >= os.path.getmtime(
+            os.path.join(here, "segment_tree.cpp")
+        )
+        assert t._lib._name == lib
+        t[3] = 2.0
+        assert t.reduce() == 2.0
+
+    def test_failed_build_raises(self, monkeypatch, tmp_path):
+        import rl_tpu.csrc as csrc
+
+        bad = tmp_path / "csrc"
+        bad.mkdir()
+        (bad / "segment_tree.cpp").write_text("this is not C++\n")
+        monkeypatch.setattr(csrc, "_LIB", None)
+        monkeypatch.setattr(csrc, "__file__", str(bad / "__init__.py"))
+        with pytest.raises(RuntimeError, match="g\\+\\+ exit"):
+            SumSegmentTree(8)
 
     def test_set_get_reduce(self):
         t = SumSegmentTree(10)

@@ -122,7 +122,7 @@ class TestR102:
 def _psum_prog():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from rl_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("x",))
 
@@ -157,7 +157,7 @@ class TestR103:
 class TestR104:
     def test_upcast_flagged(self, iso):
         reg, aud = iso
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             prog = reg.register(
                 "fixture.upcast",
                 lambda x: jnp.sum(x.astype(jnp.float64)),
@@ -171,7 +171,7 @@ class TestR104:
         # a program whose INPUTS are already f64 opted into wide math;
         # the rule only hunts silent promotion
         reg, aud = iso
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             prog = reg.register("fixture.wide_in", lambda x: jnp.sum(x) * 2.0)
             prog(jnp.zeros((16,), jnp.float64))
         assert "R104" not in rules_of(aud.findings())

@@ -79,11 +79,13 @@ class TestRegistry:
         assert kreg.selection("paged_attention", backend="tpu") == "native"
         assert kreg.selection("paged_attention", backend="cpu") is None
 
-    def test_interpret_outranks_native(self, kernels_interpret):
-        # the parity gate asked for the interpreter; Mosaic must not win
-        assert kreg.selection("sampling", backend="tpu") == "interpret"
+    def test_interpret_applies_off_chip_only(self, kernels_interpret):
+        # the parity gate asked for the interpreter on a host backend;
+        # on the chip the same override is an error, never a mode
         assert kreg.selection("sampling", backend="cpu") == "interpret"
         assert kreg.expected_active("sampling")
+        with pytest.raises(RuntimeError, match=kreg.ENV_INTERPRET):
+            kreg.selection("sampling", backend="tpu")
 
     def test_no_kernels_disables_all(self, kernels_interpret, monkeypatch):
         monkeypatch.setenv(kreg.ENV_NO_KERNELS, "1")
@@ -245,18 +247,20 @@ class TestR106:
 class TestFusedSampling:
     S, V = 5, 37
 
-    def _logits(self):
-        x = jax.random.normal(jax.random.fold_in(KEY, 9), (self.S, self.V))
+    def _logits(self, shape=None):
+        x = jax.random.normal(jax.random.fold_in(KEY, 9), shape or (self.S, self.V))
         # plant exact ties so first-index resolution is under test too
         return x.at[0, 5].set(x[0, 11])
 
+    # (5, 37): one whole-array block; (16, 384): two grid steps of 8 rows
+    @pytest.mark.parametrize("shape", [(5, 37), (16, 384)])
     @pytest.mark.parametrize("greedy", [True, False])
     @pytest.mark.parametrize("top_k", [0, 8])
     @pytest.mark.parametrize("per_row", [False, True])
     def test_interpret_bitwise_matches_fallback(self, monkeypatch, greedy,
-                                                top_k, per_row):
-        x = self._logits()
-        key = jax.random.split(KEY, self.S) if per_row else KEY
+                                                top_k, per_row, shape):
+        x = self._logits(shape)
+        key = jax.random.split(KEY, shape[0]) if per_row else KEY
         kw = dict(temperature=0.7, greedy=greedy, top_k=top_k)
         monkeypatch.delenv(kreg.ENV_INTERPRET, raising=False)
         monkeypatch.delenv(kreg.ENV_NO_KERNELS, raising=False)
@@ -478,15 +482,26 @@ class TestInt8KV:
 
         ref = serve(m)
         got = serve(TransformerLM(dataclasses.replace(cfg, kv_int8=True)))
-        n = same = 0
+        # Greedy decoding is compared while the two engines share a
+        # context. Random-init logits are nearly flat, so quantization
+        # noise may flip a near-tie, after which the contexts differ and
+        # the tokens say nothing; the flip itself must BE a near-tie in
+        # the f32 model (the weights the installed jax draws from KEY put
+        # a 0.005-nat one at the second token of prompt 2).
+        n = 0
         deltas = []
         for r, g in zip(ref, got):
-            for a, b, la, lb in zip(r.tokens, g.tokens, r.log_probs,
-                                    g.log_probs):
-                n += 1
-                same += int(a == b)
-                deltas.append(abs(la - lb))
-        assert same / n >= 0.75, (same, n)
+            agree = np.asarray(r.tokens) == np.asarray(g.tokens)
+            k = len(agree) if agree.all() else int(np.argmin(agree))
+            n += k
+            deltas += [abs(a - b) for a, b in zip(r.log_probs[:k],
+                                                  g.log_probs[:k])]
+            if k < len(agree):
+                ctx = np.concatenate([r.prompt, r.tokens[:k]])[None]
+                logits = m.apply({"params": params}, jnp.asarray(ctx))
+                top2 = jax.lax.top_k(jax.nn.log_softmax(logits[0, -1]), 2)[0]
+                assert float(top2[0] - top2[1]) < 0.05, (k, top2)
+        assert n >= 8, n
         assert float(np.mean(deltas)) < 0.1, deltas
 
 
@@ -500,23 +515,44 @@ class TestSumtreeKernel:
         esum = pr.reshape(nb, -1).sum(axis=-1)
         return pr, esum
 
-    def test_interpret_bitwise_matches_fallback(self, monkeypatch):
-        pr, esum = self._state()
-        idx = jnp.asarray([3, 17, 17, 40, 63], jnp.int32)
+    # (64, 4): padded to one lane row; (4096, 256): lane-aligned, no pad
+    @pytest.mark.parametrize("p,nb", [(64, 4), (4096, 256)])
+    def test_interpret_bitwise_matches_fallback(self, monkeypatch, p, nb):
+        pr, esum = self._state(p, nb)
+        idx = jnp.asarray([3, 17, 17, 40, p - 1], jnp.int32)
         # the caller contract: duplicates pre-collapsed to the last
         # writer (non-last delta 0.0), so order can't diverge
         delta = jnp.asarray([0.5, 0.0, -0.25, 1.75, 0.125], jnp.float32)
         monkeypatch.delenv(kreg.ENV_INTERPRET, raising=False)
         monkeypatch.delenv(kreg.ENV_NO_KERNELS, raising=False)
-        p_fb, e_fb = sumtree_update(pr, esum, idx, delta, fanout=16)
+        p_fb, e_fb = sumtree_update(pr, esum, idx, delta, fanout=p // nb)
         monkeypatch.setenv(kreg.ENV_INTERPRET, "1")
-        p_k, e_k = sumtree_update(pr, esum, idx, delta, fanout=16)
+        p_k, e_k = sumtree_update(pr, esum, idx, delta, fanout=p // nb)
         assert np.array_equal(
             np.asarray(p_fb).view(np.uint32), np.asarray(p_k).view(np.uint32)
         )
         assert np.array_equal(
             np.asarray(e_fb).view(np.uint32), np.asarray(e_k).view(np.uint32)
         )
+
+    @pytest.mark.parametrize(
+        "leaves,updates,kernel",
+        [(2**20, 256, True), (2**24, 256, False), (2**20, 150_000, False)],
+        ids=["bench-shape", "tree-over-vmem", "batch-over-smem"],
+    )
+    def test_shape_rule_selects_the_path(self, kernels_interpret, leaves,
+                                         updates, kernel):
+        # both levels live in VMEM whole and the batch in SMEM whole; the
+        # rule is on static shapes, so the jaxpr shows which path ran
+        from rl_tpu.kernels import sumtree
+
+        assert sumtree.fits(leaves, leaves // 16, updates) is kernel
+        f32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.float32)  # noqa: E731
+        jaxpr = jax.make_jaxpr(
+            lambda p, e, i, d: sumtree_update(p, e, i, d, fanout=16)
+        )(f32(leaves), f32(leaves // 16),
+          jax.ShapeDtypeStruct((updates,), jnp.int32), f32(updates))
+        assert ("pallas_call" in str(jaxpr)) is kernel
 
     def test_fallback_math(self, kernels_off):
         pr, esum = self._state()

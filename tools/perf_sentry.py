@@ -1,25 +1,24 @@
 """Offline performance sentry: the committed-artifact regression gate.
 
-Every healthy relay window commits measurement artifacts (``BENCH_*``
-round captures, plus the distilled per-subsystem files: ``FLEET_pr6``,
-``COMPILE_pr10``, ``PREFIX_pr11``, ``SPEC_pr16``, ``KERNELS_pr17``,
-``PROF_pr18``, ...). Nothing *read* them back — a regression landed in
+Benchmark runs commit measurement artifacts (``BENCH_*`` round
+captures, plus the distilled per-subsystem files: ``FLEET_pr6``,
+``REPLAY_pr20``, ...). Nothing *read* them back — a regression landed in
 a commit looked identical to a win until a human diffed the JSON. This
 tool closes that loop offline, the artifact-side complement of the
 runtime :class:`~rl_tpu.obs.drift.DriftDetector`:
 
 1. **Distill** every committed artifact into one schema-tolerant time
-   series (whole-file JSON or JSONL; missing files, dead-relay rounds
-   with ``parsed: null``, and pre-PR checkouts all tolerated — an absent
-   series is *skipped*, never failed, so the gate works at every point
-   in history).
+   series (whole-file JSON or JSONL; missing files, rounds that
+   recorded ``parsed: null``, and pre-PR checkouts all tolerated — an
+   absent series is *skipped*, never failed, so the gate works at every
+   point in history).
 2. **Enforce** the declared gate table below: headline throughput
    ratios, accepted-tokens/dispatch, cache hit rates, lost==0
    accounting, steady-state ``CompileDelta == 0``, and the PR-18
    armed-profiler overhead bound.
 3. **Write** the roll-up to ``PERF_HISTORY.json`` (committed alongside
    the artifacts it summarizes) and exit nonzero iff any gate failed —
-   the CI/watch-loop contract.
+   the CI contract.
 
 Usage::
 

@@ -33,7 +33,7 @@ The baseline (``.rlint-baseline.json`` at the repo root) is the triage
 ledger: suppressions need a reason, stale entries are warnings
 (failures under ``--strict``). The ``--artifact`` mode writes the
 bench.py-style committed summary (findings by rule, fixed vs
-suppressed, IR audit roll-up) that tools/relay_watch.py keeps current.
+suppressed, IR audit roll-up).
 """
 
 from __future__ import annotations
