@@ -22,6 +22,7 @@ from ..data import ArrayDict
 from ..envs.llm.chat import DatasetChatEnv
 from ..models import generate
 from ..objectives.llm import mc_advantage
+from ..obs.trace import get_tracer
 
 __all__ = ["LLMCollector"]
 
@@ -312,7 +313,8 @@ class LLMCollector:
             remaining[g] -= 1
             if remaining[g] == 0:
                 rows = group_rows[g]
-                rewards[rows] = self.env.score_rows(state, resp, rmask, rows)
+                with get_tracer().span("collector.reward", {"rows": len(rows)}):
+                    rewards[rows] = self.env.score_rows(state, resp, rmask, rows)
 
         gen = (
             self._fleet_generate
@@ -337,47 +339,59 @@ class LLMCollector:
             if self.weight_scheme is None:
                 raise ValueError("params=None requires a weight_scheme to pull from")
             params = self.weight_scheme.pull()
-        state, group_ids = self.env.sample_batch(self.num_prompts)
+        tracer = get_tracer()
+        with tracer.span("collector.prompts", {"n": self.num_prompts}):
+            state, group_ids = self.env.sample_batch(self.num_prompts)
         toks = np.asarray(state["tokens"])
         pmask = np.asarray(state["attention_mask"], np.float32)
-        if self.fleet is not None or self.continuous_batching:
-            # the engine consumes prompts on the host (slot-packing and
-            # submit copies) — handing it a device array would round-trip
-            # the freshly-uploaded batch straight back through a blocking
-            # transfer, so the upload happens once, inside _engine_generate
-            out, rewards = self._engine_collect(params, toks, pmask, key, state, group_ids)
-        else:
-            out = self._gen(params, jnp.asarray(toks), jnp.asarray(pmask), key)
-            rewards = None
-
-        resp = np.asarray(out.response_tokens)
-        rmask = np.asarray(out.response_mask)
+        G, P_len = toks.shape
+        with tracer.span("collector.rollout") as rollout:
+            if self.fleet is not None or self.continuous_batching:
+                # the engine consumes prompts on the host (slot-packing and
+                # submit copies) — handing it a device array would round-trip
+                # the freshly-uploaded batch straight back through a blocking
+                # transfer, so the upload happens once, inside _engine_generate
+                out, rewards = self._engine_collect(params, toks, pmask, key, state, group_ids)
+            else:
+                out = self._gen(params, jnp.asarray(toks), jnp.asarray(pmask), key)
+                rewards = None
+            # the fixed-batch path blocks here, on the tokens it generated
+            resp = np.asarray(out.response_tokens)
+            rmask = np.asarray(out.response_mask)
+            if tracer.enabled:
+                rollout.args = {"requests": G, "tokens": np.count_nonzero(rmask)}
         if rewards is None:
-            _, rewards, _ = self.env.step(state, resp, rmask)
+            with tracer.span("collector.reward", {"rows": G}):
+                _, rewards, _ = self.env.step(state, resp, rmask)
 
-        G = toks.shape[0]
-        P_len = toks.shape[1]
         T = P_len + self.max_new_tokens
-        gid = jnp.asarray(group_ids)
-
-        arrays: dict = {
-            "tokens": out.tokens,
-            "attention_mask": out.full_mask[:, :T].astype(jnp.float32),
-            "assistant_mask": jnp.concatenate(
-                [jnp.zeros((G, P_len), bool), out.response_mask], axis=1
-            ),
-            "sample_log_prob": jnp.concatenate(
-                [jnp.zeros((G, P_len)), out.response_log_probs], axis=1
-            ),
-            "group_id": gid,
-        }
+        attention_mask = out.full_mask[:, :T].astype(jnp.float32)
+        ref_lp = None
         if self.ref_params is not None:
-            arrays["ref_log_prob"] = self._score_ref(
-                arrays["tokens"], arrays["attention_mask"]
-            )
-        if self.reward_transform is not None:
-            rewards = np.asarray(self.reward_transform(rewards, arrays))
-        # advantages AFTER reward shaping, same ordering as the reference's
-        # in-env KLRewardTransform (the estimator sees the shaped reward)
-        adv = mc_advantage(jnp.asarray(rewards), gid, self.num_prompts)
-        return ArrayDict(advantage=adv, reward=jnp.asarray(rewards), **arrays)
+            with tracer.span("collector.ref_score"):
+                ref_lp = self._score_ref(out.tokens, attention_mask)
+        with tracer.span("collector.assemble"):
+            gid = jnp.asarray(group_ids)
+            arrays: dict = {
+                "tokens": out.tokens,
+                "attention_mask": attention_mask,
+                "assistant_mask": jnp.concatenate(
+                    [jnp.zeros((G, P_len), bool), out.response_mask], axis=1
+                ),
+                "sample_log_prob": jnp.concatenate(
+                    [jnp.zeros((G, P_len)), out.response_log_probs], axis=1
+                ),
+                "group_id": gid,
+            }
+            if ref_lp is not None:
+                arrays["ref_log_prob"] = ref_lp
+            if self.reward_transform is not None:
+                # the host needs the shaped rewards, and the stock KL
+                # transform reads the reference scores to the host itself:
+                # this is the first wait on the scoring dispatched above
+                with tracer.span("collector.assemble.wait"):
+                    rewards = np.asarray(self.reward_transform(rewards, arrays))
+            # advantages AFTER reward shaping, same ordering as the reference's
+            # in-env KLRewardTransform (the estimator sees the shaped reward)
+            adv = mc_advantage(jnp.asarray(rewards), gid, self.num_prompts)
+            return ArrayDict(advantage=adv, reward=jnp.asarray(rewards), **arrays)

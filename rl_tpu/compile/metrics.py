@@ -113,7 +113,7 @@ def _on_event(event: str, duration: float) -> None:
         # the compile already happened — stamp a completed span covering it
         tracer.end_span(
             f"xla_compile:{label}",
-            tracer._now_us() - duration * 1e6,
+            tracer.now_us() - duration * 1e6,
             {"seconds": round(duration, 4)},
         )
     except Exception:

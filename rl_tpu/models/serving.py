@@ -86,6 +86,7 @@ class Request:
     # causal link to the submitter (fleet dispatch span, TCP handler, ...);
     # None outside any traced request
     ctx: Any = None
+    t_submit: float = 0.0  # seconds on the recorder's clock (now_us() / 1e6)
 
 
 @dataclasses.dataclass
@@ -95,6 +96,14 @@ class FinishedRequest:
     tokens: np.ndarray  # [N] generated ids (eos included if hit)
     log_probs: np.ndarray  # [N] behavior log-probs of the sampled tokens
     finished_reason: str  # "eos" | "length"
+    # seconds on the recorder's clock (``get_tracer().now_us() / 1e6``):
+    # submitted, just before its prefill's program call, first token on the
+    # host, slot freed
+    t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0
+    t_finish: float = 0.0
+    slot: int = -1
 
 
 @dataclasses.dataclass
@@ -130,7 +139,9 @@ class _InFlight:
     run_mask: np.ndarray  # slots this chunk was allowed to advance
     chunk: int
     fresh_compile: bool  # first launch at this K: exclude from tuning
-    dispatch_s: float  # host wall spent dispatching (tuner input)
+    # the ``engine.launch.dispatch`` span's duration: host wall from the
+    # program call to the async copies' start (tuner input)
+    dispatch_s: float
     # speculative verify dispatches carry the drafts they proposed so the
     # host drain can re-derive the device's chain-acceptance rule exactly
     kind: str = "decode"  # "decode" | "verify"
@@ -418,7 +429,9 @@ class ContinuousBatchingEngine:
         self.spec_accepted_tokens = 0
         self.spec_accept_ema = 1.0
         self._spec_accept_counts: dict[int, int] = {}  # n_emit -> dispatches
-        self._slot_ctx: dict[int, Any] = {}  # rid -> trace ctx (spec spans)
+        self._slot_ctx: dict[int, Any] = {}  # rid -> the submitter's trace ctx
+        # slot -> (t_submit, t_admit, t_first) of its occupant
+        self._slot_times = [(0.0, 0.0, 0.0)] * n_slots
         self._n_pool_blocks = n_blocks - 1
         # on-device token accounting: the decode scan counts every token
         # generated by an effectively-active slot, so throughput telemetry
@@ -946,34 +959,54 @@ class ContinuousBatchingEngine:
         if not self._pending_table_writes:
             return
         w = self._pending_table_writes
-        n = _pow2ceil(len(w))
-        w = w + [w[-1]] * (n - len(w))
-        rows, cols, vals = (np.asarray(c, np.int32) for c in zip(*w))
-        self.dev_table = self.dev_table.at[rows, cols].set(jnp.asarray(vals))
-        self._pending_table_writes.clear()
+        with get_tracer().span("engine.flush_tables", {"writes": len(w)}):
+            n = _pow2ceil(len(w))
+            w = w + [w[-1]] * (n - len(w))
+            rows, cols, vals = (np.asarray(c, np.int32) for c in zip(*w))
+            self.dev_table = self.dev_table.at[rows, cols].set(jnp.asarray(vals))
+            self._pending_table_writes.clear()
 
     def _free_slot(self, slot: int, reason: str):
         self.completions[reason] = self.completions.get(reason, 0) + 1
         rid = int(self.slot_rid[slot])
-        self._slot_ctx.pop(rid, None)
+        ctx = self._slot_ctx.pop(rid, None)
         chunks = self.slot_tokens[slot]
-        self.finished.append(
-            FinishedRequest(
-                rid=rid,
-                prompt=self.slot_prompt.pop(rid),
-                tokens=(
-                    np.concatenate(chunks).astype(np.int32)
-                    if chunks
-                    else np.zeros(0, np.int32)
-                ),
-                log_probs=(
-                    np.concatenate(self.slot_lps[slot]).astype(np.float32)
-                    if self.slot_lps[slot]
-                    else np.zeros(0, np.float32)
-                ),
-                finished_reason=reason,
-            )
+        tracer = get_tracer()
+        t_submit, t_admit, t_first = self._slot_times[slot]
+        fin = FinishedRequest(
+            rid=rid,
+            prompt=self.slot_prompt.pop(rid),
+            tokens=(
+                np.concatenate(chunks).astype(np.int32)
+                if chunks
+                else np.zeros(0, np.int32)
+            ),
+            log_probs=(
+                np.concatenate(self.slot_lps[slot]).astype(np.float32)
+                if self.slot_lps[slot]
+                else np.zeros(0, np.float32)
+            ),
+            finished_reason=reason,
+            t_submit=t_submit,
+            t_admit=t_admit,
+            t_first=t_first,
+            t_finish=tracer.now_us() * 1e-6,
+            slot=slot,
         )
+        self.finished.append(fin)
+        if tracer.enabled:
+            # one complete event a request, submit to finish; ``rid`` is the
+            # identifier its spans share, the ctx ids hang it in the
+            # submitter's causal tree beside ``engine_admit``
+            tracer.end_span(
+                "request",
+                t_submit * 1e6,
+                {"rid": rid, "slot": slot, "queue_s": t_admit - t_submit,
+                 "prefill_s": t_first - t_admit, "tokens": len(fin.tokens),
+                 "reason": reason,
+                 **(ctx_args(ctx.child()) if ctx is not None else {})},
+                end_us=fin.t_finish * 1e6,
+            )
         used = self.table[slot]
         if self._kvmem is not None:
             # the lease ends here, BEFORE the host mirrors reset: lens[slot]
@@ -981,7 +1014,6 @@ class ContinuousBatchingEngine:
             # tokens minus the final sample, which was never fed back), so
             # the allocator can extend/donate the generated blocks into the
             # tree for multi-turn reuse and free the rest
-            fin = self.finished[-1]
             lease, self._slot_lease[slot] = self._slot_lease[slot], None
             self._kvmem.release(
                 lease,
@@ -1400,6 +1432,8 @@ class ContinuousBatchingEngine:
         self.sched_lens[s] = P
         self.slot_budget[s] = ho.budget
         self.sched_budget[s] = ho.budget
+        # the prefill ran elsewhere: this engine's clock starts at adoption
+        self._slot_times[s] = (get_tracer().now_us() * 1e-6,) * 3
         self.admissions += 1
         self._flush_table_writes()
         surv = np.zeros(self.n_slots, bool)
@@ -1455,7 +1489,10 @@ class ContinuousBatchingEngine:
             )
         rid = self._next_rid
         self._next_rid += 1
-        self.queue.append(Request(rid, prompt, max_new_tokens, ctx=current_context()))
+        self.queue.append(Request(
+            rid, prompt, max_new_tokens, ctx=current_context(),
+            t_submit=get_tracer().now_us() * 1e-6,
+        ))
         return rid
 
     def _admit(self):
@@ -1506,190 +1543,182 @@ class ContinuousBatchingEngine:
                 batch.append((s, self.queue.pop(0)))
         if not batch:
             return
-        if self._kvmem is not None:
-            # the compile ladder buckets the SUFFIX, not the prompt: a
-            # 500-token prompt with 480 cached prefills through the same
-            # small program as a 20-token cold prompt
-            bucket = self.shape_buckets.suffix_bucket(
-                max(len(r.prompt) - st for (_, r), st in zip(batch, starts))
-            )
-        else:
-            bucket = self.shape_buckets.prompt_bucket(
-                max(len(r.prompt) for _, r in batch)
-            )
-        A = len(batch)
-        self.admissions += A
-        # round the admitted-count dim up its ladder: the pad rows carry an
-        # all-False token mask, so the paged cache routes their writes to
-        # the reserved scratch block and the host never reads their rows —
-        # admission shapes come from a FIXED set instead of one program per
-        # count (the serving shape-bucket tentpole)
-        pad_a = self.shape_buckets.admit_bucket(A, self.n_slots)
-        tokens = np.zeros((pad_a, bucket), np.int32)
-        mask = np.zeros((pad_a, bucket), bool)
-        for i, (s, req) in enumerate(batch):
-            P = len(req.prompt)
-            st = starts[i]
-            tokens[i, : P - st] = req.prompt[st:]
-            mask[i, : P - st] = True
-            self.slot_rid[s] = req.rid
-            self.slot_prompt[req.rid] = req.prompt
-            self.slot_tokens[s] = []
-            self.slot_lps[s] = []
-        # pad rows gather slot 0's (or any) table row — harmless, since an
-        # inactive row never writes through its table and reads are masked
-        slots = np.zeros(pad_a, np.int64)
-        slots[:A] = [s for s, _ in batch]
-        self._flush_table_writes()  # prefill reads the new rows on device
-        if not self.slot_rng:
-            # the legacy engine stream splits here; slot-stream mode
-            # derives keys in-program from (base_key, rid, 0) instead and
-            # must leave this stream byte-for-byte untouched
-            self._key, k = jax.random.split(self._key)
-        rid_v = np.full(pad_a, -1, np.int32)
-        rid_v[:A] = [req.rid for _, req in batch]
-        pools = _pools_from(self.cache)
-        if self._kvmem is not None:
-            if cows:
-                pools = self._dispatch_cow(pools, cows)
-            start_v = np.zeros(pad_a, np.int32)
-            start_v[:A] = starts
-            if self.slot_rng:
-                fn = self._get_spprefill_prog(pad_a, bucket)
-                tok, lp, new_pools = fn(
-                    self.params,
-                    pools,
-                    self.dev_table[jnp.asarray(slots)],
-                    jnp.asarray(tokens),
-                    jnp.asarray(mask),
-                    jnp.asarray(start_v),
-                    jnp.asarray(rid_v),
-                    self._base_key,
+        cached = sum(starts)
+        computed = sum(len(r.prompt) for _, r in batch) - cached
+        tracer = get_tracer()
+        with tracer.span("engine.admit") as span:
+            if tracer.enabled:
+                span.args = {
+                    "admitted": len(batch),
+                    "slots": [s for s, _ in batch],
+                    "queue_depth": len(self.queue),
+                    "prefill_tokens": computed,
+                    "prefill_cached": cached,
+                }
+            if self._kvmem is not None:
+                # the compile ladder buckets the SUFFIX, not the prompt: a
+                # 500-token prompt with 480 cached prefills through the same
+                # small program as a 20-token cold prompt
+                bucket = self.shape_buckets.suffix_bucket(
+                    max(len(r.prompt) - st for (_, r), st in zip(batch, starts))
                 )
             else:
-                fn = self._get_pprefill_prog(pad_a, bucket)
-                tok, lp, new_pools = fn(
-                    self.params,
-                    pools,
-                    self.dev_table[jnp.asarray(slots)],
-                    jnp.asarray(tokens),
-                    jnp.asarray(mask),
-                    jnp.asarray(start_v),
-                    k,
+                bucket = self.shape_buckets.prompt_bucket(
+                    max(len(r.prompt) for _, r in batch)
                 )
-            # the round's published blocks are now behind a dispatched
-            # prefill: safe for next round's admissions to share
-            self._kvmem.end_round()
-            self.prefill_tokens_computed += sum(
-                len(r.prompt) - st for (_, r), st in zip(batch, starts)
-            )
-            self.prefill_tokens_cached += sum(starts)
-        else:
-            if self.slot_rng:
+            A = len(batch)
+            self.admissions += A
+            # round the admitted-count dim up its ladder: the pad rows carry an
+            # all-False token mask, so the paged cache routes their writes to
+            # the reserved scratch block and the host never reads their rows —
+            # admission shapes come from a FIXED set instead of one program per
+            # count (the serving shape-bucket tentpole)
+            pad_a = self.shape_buckets.admit_bucket(A, self.n_slots)
+            tokens = np.zeros((pad_a, bucket), np.int32)
+            mask = np.zeros((pad_a, bucket), bool)
+            for i, (s, req) in enumerate(batch):
+                P = len(req.prompt)
+                st = starts[i]
+                tokens[i, : P - st] = req.prompt[st:]
+                mask[i, : P - st] = True
+                self.slot_rid[s] = req.rid
+                self.slot_prompt[req.rid] = req.prompt
+                self.slot_tokens[s] = []
+                self.slot_lps[s] = []
+            # pad rows gather slot 0's (or any) table row — harmless, since an
+            # inactive row never writes through its table and reads are masked
+            slots = np.zeros(pad_a, np.int64)
+            slots[:A] = [s for s, _ in batch]
+            self._flush_table_writes()  # prefill reads the new rows on device
+            if not self.slot_rng:
+                # the legacy engine stream splits here; slot-stream mode
+                # derives keys in-program from (base_key, rid, 0) instead and
+                # must leave this stream byte-for-byte untouched
+                self._key, k = jax.random.split(self._key)
+            rid_v = np.full(pad_a, -1, np.int32)
+            rid_v[:A] = [req.rid for _, req in batch]
+            pools = _pools_from(self.cache)
+            if self._kvmem is not None:
+                if cows:
+                    pools = self._dispatch_cow(pools, cows)
+                start_v = np.zeros(pad_a, np.int32)
+                start_v[:A] = starts
+                if self.slot_rng:
+                    fn = self._get_spprefill_prog(pad_a, bucket)
+                    tail = (jnp.asarray(start_v), jnp.asarray(rid_v), self._base_key)
+                else:
+                    fn = self._get_pprefill_prog(pad_a, bucket)
+                    tail = (jnp.asarray(start_v), k)
+            elif self.slot_rng:
                 fn = self._get_sprefill_prog(pad_a, bucket)
-                tok, lp, new_pools = fn(
-                    self.params,
-                    pools,
-                    self.dev_table[jnp.asarray(slots)],
-                    jnp.asarray(tokens),
-                    jnp.asarray(mask),
-                    jnp.asarray(rid_v),
-                    self._base_key,
-                )
+                tail = (jnp.asarray(rid_v), self._base_key)
             else:
                 fn = self._get_prefill_prog(pad_a, bucket)
+                tail = (k,)
+            t_admit = tracer.now_us() * 1e-6
+            # the program call is a span of its own: on this runtime a
+            # dispatch blocks while its output pools cannot be allocated,
+            # and that wait must not read as the admission's host work
+            with tracer.span("engine.prefill.dispatch"):
                 tok, lp, new_pools = fn(
                     self.params,
                     pools,
                     self.dev_table[jnp.asarray(slots)],
                     jnp.asarray(tokens),
                     jnp.asarray(mask),
-                    k,
+                    *tail,
                 )
-            self.prefill_tokens_computed += sum(len(r.prompt) for _, r in batch)
-        for layer, bufs in zip(self.cache, new_pools):
-            layer.update(zip(_POOL_FIELDS, bufs))
-        self.prefill_token_slots += A * bucket
-        tok_host, lp_host = np.asarray(tok), np.asarray(lp)
-        self.host_transfers += 1
-        surv = np.zeros(self.n_slots, bool)
-        new_lens = np.zeros(self.n_slots, np.int32)
-        new_budget = np.zeros(self.n_slots, np.int32)
-        new_last = np.zeros(self.n_slots, np.int32)
-        new_rid = np.zeros(self.n_slots, np.int32)
-        for i, (s, req) in enumerate(batch):
-            P = len(req.prompt)
-            t0, l0 = int(tok_host[i]), float(lp_host[i])
-            self.lens[s] = P
-            self.sched_lens[s] = P
-            self.slot_tokens[s] = [np.asarray([t0], np.int32)]
-            self.slot_lps[s] = [np.asarray([l0], np.float32)]
-            b = req.max_new_tokens - 1  # prefill emitted the first token
-            self.slot_budget[s] = b
-            self.sched_budget[s] = b
-            if self.speculative:
-                self._slot_ctx[req.rid] = req.ctx
-            if self.eos_id is not None and t0 == self.eos_id:
-                self._free_slot(s, "eos")
-            elif b <= 0:
-                self._free_slot(s, "length")
-            else:
-                surv[s] = True
-                new_lens[s], new_budget[s], new_last[s] = P, b, t0
-                new_rid[s] = req.rid
-        if self.on_admit is not None:
-            for _s, req in batch:
-                self.on_admit(req.rid)
-        tracer = get_tracer()
-        if tracer.enabled:
-            # one causal node per admitted request, hanging under its
-            # submitter's context: the kvmem-admit/CoW/partial-prefill leg
-            # of the request tree (cached_prefix tells how partial)
-            for (_s, req), st in zip(batch, starts):
+            if self._kvmem is not None:
+                # the round's published blocks are now behind a dispatched
+                # prefill: safe for next round's admissions to share
+                self._kvmem.end_round()
+                self.prefill_tokens_cached += cached
+            self.prefill_tokens_computed += computed
+            for layer, bufs in zip(self.cache, new_pools):
+                layer.update(zip(_POOL_FIELDS, bufs))
+            self.prefill_token_slots += A * bucket
+            with tracer.span("engine.prefill.wait"):
+                tok_host, lp_host = np.asarray(tok), np.asarray(lp)
+            t_first = tracer.now_us() * 1e-6
+            self.host_transfers += 1
+            surv = np.zeros(self.n_slots, bool)
+            new_lens = np.zeros(self.n_slots, np.int32)
+            new_budget = np.zeros(self.n_slots, np.int32)
+            new_last = np.zeros(self.n_slots, np.int32)
+            new_rid = np.zeros(self.n_slots, np.int32)
+            for i, (s, req) in enumerate(batch):
+                P = len(req.prompt)
+                t0, l0 = int(tok_host[i]), float(lp_host[i])
+                self.lens[s] = P
+                self.sched_lens[s] = P
+                self.slot_tokens[s] = [np.asarray([t0], np.int32)]
+                self.slot_lps[s] = [np.asarray([l0], np.float32)]
+                b = req.max_new_tokens - 1  # prefill emitted the first token
+                self.slot_budget[s] = b
+                self.sched_budget[s] = b
+                self._slot_times[s] = (req.t_submit, t_admit, t_first)
                 if req.ctx is not None:
-                    tracer.instant(
-                        "engine_admit",
-                        {"rid": req.rid, "cached_prefix": st,
-                         **ctx_args(req.ctx.child())},
+                    self._slot_ctx[req.rid] = req.ctx
+                if self.eos_id is not None and t0 == self.eos_id:
+                    self._free_slot(s, "eos")
+                elif b <= 0:
+                    self._free_slot(s, "length")
+                else:
+                    surv[s] = True
+                    new_lens[s], new_budget[s], new_last[s] = P, b, t0
+                    new_rid[s] = req.rid
+            if self.on_admit is not None:
+                for _s, req in batch:
+                    self.on_admit(req.rid)
+            if tracer.enabled:
+                # one causal node per admitted request, hanging under its
+                # submitter's context: the kvmem-admit/CoW/partial-prefill leg
+                # of the request tree (cached_prefix tells how partial)
+                for (_s, req), st in zip(batch, starts):
+                    if req.ctx is not None:
+                        tracer.instant(
+                            "engine_admit",
+                            {"rid": req.rid, "cached_prefix": st,
+                             **ctx_args(req.ctx.child())},
+                        )
+            if surv.any():
+                if self.slot_rng:
+                    (
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        self.dev_rid,
+                        self.dev_ntok,
+                    ) = self._sadmit_update(
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        self.dev_rid,
+                        self.dev_ntok,
+                        jnp.asarray(surv),
+                        jnp.asarray(new_lens),
+                        jnp.asarray(new_budget),
+                        jnp.asarray(new_last),
+                        jnp.asarray(new_rid),
                     )
-        if surv.any():
-            if self.slot_rng:
-                (
-                    self.dev_lens,
-                    self.dev_active,
-                    self.dev_budget,
-                    self.dev_last,
-                    self.dev_rid,
-                    self.dev_ntok,
-                ) = self._sadmit_update(
-                    self.dev_lens,
-                    self.dev_active,
-                    self.dev_budget,
-                    self.dev_last,
-                    self.dev_rid,
-                    self.dev_ntok,
-                    jnp.asarray(surv),
-                    jnp.asarray(new_lens),
-                    jnp.asarray(new_budget),
-                    jnp.asarray(new_last),
-                    jnp.asarray(new_rid),
-                )
-            else:
-                (
-                    self.dev_lens,
-                    self.dev_active,
-                    self.dev_budget,
-                    self.dev_last,
-                ) = self._admit_update(
-                    self.dev_lens,
-                    self.dev_active,
-                    self.dev_budget,
-                    self.dev_last,
-                    jnp.asarray(surv),
-                    jnp.asarray(new_lens),
-                    jnp.asarray(new_budget),
-                    jnp.asarray(new_last),
-                )
+                else:
+                    (
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                    ) = self._admit_update(
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        jnp.asarray(surv),
+                        jnp.asarray(new_lens),
+                        jnp.asarray(new_budget),
+                        jnp.asarray(new_last),
+                    )
 
     # -- the de-synced decode loop ---------------------------------------------
 
@@ -1755,80 +1784,88 @@ class ContinuousBatchingEngine:
                     f"blocks); the pool cannot hold this working set"
                 )
             break
-        self._flush_table_writes()
-        run_dev = self._dev_all_slots if run.all() else jnp.asarray(run)
-        pools = _pools_from(self.cache)
-        if self.slot_rng:
-            fresh = chunk not in self._sdecode_progs
-            prog = self._get_sdecode_prog(chunk)
-            t0 = time.perf_counter()
-            (
-                toks,
-                lps,
-                new_pools,
-                self.dev_lens,
-                self.dev_active,
-                self.dev_budget,
-                self.dev_last,
-                self.dev_ntok,
-                self.dev_obs,
-            ) = prog(
-                self.params,
-                pools,
-                self.dev_table,
-                self.dev_lens,
-                self.dev_active,
-                self.dev_budget,
-                self.dev_last,
-                run_dev,
-                self.dev_rid,
-                self.dev_ntok,
-                self._base_key,
-                self.dev_obs,
+        tracer = get_tracer()
+        with tracer.span("engine.launch") as span:
+            self._flush_table_writes()
+            run_dev = self._dev_all_slots if run.all() else jnp.asarray(run)
+            pools = _pools_from(self.cache)
+            if self.slot_rng:
+                fresh = chunk not in self._sdecode_progs
+                prog = self._get_sdecode_prog(chunk)
+            else:
+                fresh = chunk not in self._decode_progs
+                prog = self._get_decode_prog(chunk)
+                self._key, k = jax.random.split(self._key)
+            # the program call to the async copies' start: the tuner's
+            # dispatch interval, and a span of its own because a dispatch
+            # can block on the device (its output pools' allocation)
+            with tracer.span("engine.launch.dispatch") as disp:
+                if self.slot_rng:
+                    (
+                        toks,
+                        lps,
+                        new_pools,
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        self.dev_ntok,
+                        self.dev_obs,
+                    ) = prog(
+                        self.params,
+                        pools,
+                        self.dev_table,
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        run_dev,
+                        self.dev_rid,
+                        self.dev_ntok,
+                        self._base_key,
+                        self.dev_obs,
+                    )
+                else:
+                    (
+                        toks,
+                        lps,
+                        new_pools,
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        self.dev_obs,
+                    ) = prog(
+                        self.params,
+                        pools,
+                        self.dev_table,
+                        self.dev_lens,
+                        self.dev_active,
+                        self.dev_budget,
+                        self.dev_last,
+                        run_dev,
+                        k,
+                        self.dev_obs,
+                    )
+                for layer, bufs in zip(self.cache, new_pools):
+                    layer.update(zip(_POOL_FIELDS, bufs))
+                try:  # start the device->host copy early; the drain just awaits it
+                    toks.copy_to_host_async()
+                    lps.copy_to_host_async()
+                except Exception:
+                    pass
+            want = np.minimum(chunk, self.sched_budget) * run
+            self.sched_lens += want
+            self.sched_budget -= want
+            self._inflight.append(
+                _InFlight(toks, lps, self.slot_rid.copy(), run.copy(), chunk, fresh, disp.dur_s)
             )
-        else:
-            fresh = chunk not in self._decode_progs
-            prog = self._get_decode_prog(chunk)
-            self._key, k = jax.random.split(self._key)
-            t0 = time.perf_counter()
-            (
-                toks,
-                lps,
-                new_pools,
-                self.dev_lens,
-                self.dev_active,
-                self.dev_budget,
-                self.dev_last,
-                self.dev_obs,
-            ) = prog(
-                self.params,
-                pools,
-                self.dev_table,
-                self.dev_lens,
-                self.dev_active,
-                self.dev_budget,
-                self.dev_last,
-                run_dev,
-                k,
-                self.dev_obs,
-            )
-        for layer, bufs in zip(self.cache, new_pools):
-            layer.update(zip(_POOL_FIELDS, bufs))
-        try:  # start the device->host copy early; the drain just awaits it
-            toks.copy_to_host_async()
-            lps.copy_to_host_async()
-        except Exception:
-            pass
-        dispatch_s = time.perf_counter() - t0
-        want = np.minimum(chunk, self.sched_budget) * run
-        self.sched_lens += want
-        self.sched_budget -= want
-        self._inflight.append(
-            _InFlight(toks, lps, self.slot_rid.copy(), run.copy(), chunk, fresh, dispatch_s)
-        )
-        self.decode_steps += chunk
-        self.decode_launches += 1
-        self.decode_chunk_last = chunk
+            self.decode_steps += chunk
+            self.decode_launches += 1
+            self.decode_chunk_last = chunk
+            if tracer.enabled:
+                span.args = {"launch": self.decode_launches, "chunk": chunk,
+                             "active": np.count_nonzero(run)}
         return True
 
     def _launch_spec(self) -> bool:
@@ -1880,142 +1917,152 @@ class ContinuousBatchingEngine:
         draft_np = np.zeros((self.n_slots, K - 1), np.int32)
         for s, d in drafts.items():
             draft_np[s, : len(d)] = d
-        self._flush_table_writes()
-        fresh = K not in self._verify_progs
-        prog = self._get_verify_prog(K)
-        run_dev = self._dev_all_slots if run.all() else jnp.asarray(run)
-        pools = _pools_from(self.cache)
-        t0 = time.perf_counter()
-        (
-            toks,
-            lps,
-            new_pools,
-            self.dev_lens,
-            self.dev_active,
-            self.dev_budget,
-            self.dev_last,
-            self.dev_ntok,
-            self.dev_obs,
-        ) = prog(
-            self.params,
-            pools,
-            self.dev_table,
-            self.dev_lens,
-            self.dev_active,
-            self.dev_budget,
-            self.dev_last,
-            run_dev,
-            jnp.asarray(draft_np),
-            self.dev_rid,
-            self.dev_ntok,
-            self._base_key,
-            self.dev_obs,
-        )
-        for layer, bufs in zip(self.cache, new_pools):
-            layer.update(zip(_POOL_FIELDS, bufs))
-        try:
-            toks.copy_to_host_async()
-            lps.copy_to_host_async()
-        except Exception:
-            pass
-        dispatch_s = time.perf_counter() - t0
-        # scheduled UPPER bound (the chain length is on device); the
-        # verify drain resyncs sched_* to actuals before the next launch
-        want = np.minimum(K, self.sched_budget) * run
-        self.sched_lens += want
-        self.sched_budget -= want
-        self._inflight.append(
-            _InFlight(
-                toks, lps, self.slot_rid.copy(), run.copy(), K, fresh,
-                dispatch_s, kind="verify", draft=draft_np,
+        tracer = get_tracer()
+        with tracer.span("engine.launch") as span:
+            self._flush_table_writes()
+            fresh = K not in self._verify_progs
+            prog = self._get_verify_prog(K)
+            run_dev = self._dev_all_slots if run.all() else jnp.asarray(run)
+            pools = _pools_from(self.cache)
+            with tracer.span("engine.launch.dispatch") as disp:
+                (
+                    toks,
+                    lps,
+                    new_pools,
+                    self.dev_lens,
+                    self.dev_active,
+                    self.dev_budget,
+                    self.dev_last,
+                    self.dev_ntok,
+                    self.dev_obs,
+                ) = prog(
+                    self.params,
+                    pools,
+                    self.dev_table,
+                    self.dev_lens,
+                    self.dev_active,
+                    self.dev_budget,
+                    self.dev_last,
+                    run_dev,
+                    jnp.asarray(draft_np),
+                    self.dev_rid,
+                    self.dev_ntok,
+                    self._base_key,
+                    self.dev_obs,
+                )
+                for layer, bufs in zip(self.cache, new_pools):
+                    layer.update(zip(_POOL_FIELDS, bufs))
+                try:
+                    toks.copy_to_host_async()
+                    lps.copy_to_host_async()
+                except Exception:
+                    pass
+            # scheduled UPPER bound (the chain length is on device); the
+            # verify drain resyncs sched_* to actuals before the next launch
+            want = np.minimum(K, self.sched_budget) * run
+            self.sched_lens += want
+            self.sched_budget -= want
+            self._inflight.append(
+                _InFlight(
+                    toks, lps, self.slot_rid.copy(), run.copy(), K, fresh,
+                    disp.dur_s, kind="verify", draft=draft_np,
+                )
             )
-        )
-        self.spec_dispatches += 1
-        self.spec_draft_tokens += sum(len(d) for d in drafts.values())
-        self.decode_steps += 1  # one forward, however many positions
-        self.decode_launches += 1
-        self.decode_chunk_last = K
+            self.spec_dispatches += 1
+            self.spec_draft_tokens += sum(len(d) for d in drafts.values())
+            self.decode_steps += 1  # one forward, however many positions
+            self.decode_launches += 1
+            self.decode_chunk_last = K
+            if tracer.enabled:
+                span.args = {"launch": self.decode_launches, "chunk": K,
+                             "active": np.count_nonzero(run)}
         return True
 
     def _drain_one(self):
         """Accept the OLDEST in-flight chunk: one blocking transfer, then
         one vectorized pass over all S slots (the device stop rule
         re-derived in numpy: accept min(first-eos+1, budget, K) tokens)."""
-        fl = self._inflight.popleft()
-        t0 = time.perf_counter()
-        tok = np.asarray(fl.toks)
-        lp = np.asarray(fl.lps)
-        wait_s = time.perf_counter() - t0
-        self.host_transfers += 1
-        self.decode_drains += 1
-        t1 = time.perf_counter()
-        K = fl.chunk
-        # a slot's tokens count only while the SAME request still owns it
-        # (a slot freed by an earlier drain — and possibly re-admitted —
-        # ran this chunk deactivated on device; its rows are garbage)
-        valid = fl.run_mask & (self.slot_rid == fl.rid0) & (fl.rid0 >= 0)
-        if fl.kind == "verify":
-            # re-derive the device's chain-acceptance rule from the SAME
-            # inputs: drafts 1..j accepted iff each equalled the sample
-            # before it (positions past the first mismatch are resampled
-            # next round from the corrected history)
-            good = (tok[:, : K - 1] == fl.draft).astype(np.int64)
-            chain = 1 + np.cumprod(good, axis=1).sum(axis=1)
-        else:
-            chain = np.full(self.n_slots, K, np.int64)
-        if self.eos_id is None:
-            eos_pos = np.full(self.n_slots, K, np.int64)
-        else:
-            is_eos = tok == self.eos_id
-            has = is_eos.any(axis=1)
-            eos_pos = np.where(has, is_eos.argmax(axis=1), K)
-        n_emit = np.minimum(np.minimum(eos_pos + 1, self.slot_budget), chain)
-        n_emit = np.where(valid, n_emit, 0)
-        self.lens += n_emit
-        self.slot_budget -= n_emit
-        for s in map(int, np.nonzero(n_emit)[0]):
-            n = int(n_emit[s])
-            self.slot_tokens[s].append(tok[s, :n])
-            self.slot_lps[s].append(lp[s, :n])
-        fin_eos = valid & (eos_pos < n_emit)
-        fin_len = valid & ~fin_eos & (self.slot_budget <= 0)
-        if fl.kind == "verify":
-            emitted = int(n_emit.sum())
-            n_valid = int(valid.sum())
-            self.spec_accepted_tokens += emitted
-            if n_valid:
-                self.spec_accept_ema = (
-                    0.8 * self.spec_accept_ema + 0.2 * (emitted / n_valid)
-                )
-                for s in map(int, np.nonzero(valid)[0]):
-                    n = int(n_emit[s])
-                    self._spec_accept_counts[n] = (
-                        self._spec_accept_counts.get(n, 0) + 1
+        tracer = get_tracer()
+        with tracer.span("engine.drain") as span:
+            fl = self._inflight.popleft()
+            with tracer.span("engine.drain.wait") as wait:
+                tok = np.asarray(fl.toks)
+                lp = np.asarray(fl.lps)
+            self.host_transfers += 1
+            self.decode_drains += 1
+            K = fl.chunk
+            # a slot's tokens count only while the SAME request still owns it
+            # (a slot freed by an earlier drain — and possibly re-admitted —
+            # ran this chunk deactivated on device; its rows are garbage)
+            valid = fl.run_mask & (self.slot_rid == fl.rid0) & (fl.rid0 >= 0)
+            if fl.kind == "verify":
+                # re-derive the device's chain-acceptance rule from the SAME
+                # inputs: drafts 1..j accepted iff each equalled the sample
+                # before it (positions past the first mismatch are resampled
+                # next round from the corrected history)
+                good = (tok[:, : K - 1] == fl.draft).astype(np.int64)
+                chain = 1 + np.cumprod(good, axis=1).sum(axis=1)
+            else:
+                chain = np.full(self.n_slots, K, np.int64)
+            if self.eos_id is None:
+                eos_pos = np.full(self.n_slots, K, np.int64)
+            else:
+                is_eos = tok == self.eos_id
+                has = is_eos.any(axis=1)
+                eos_pos = np.where(has, is_eos.argmax(axis=1), K)
+            n_emit = np.minimum(np.minimum(eos_pos + 1, self.slot_budget), chain)
+            n_emit = np.where(valid, n_emit, 0)
+            self.lens += n_emit
+            self.slot_budget -= n_emit
+            for s in map(int, np.nonzero(n_emit)[0]):
+                n = int(n_emit[s])
+                self.slot_tokens[s].append(tok[s, :n])
+                self.slot_lps[s].append(lp[s, :n])
+            fin_eos = valid & (eos_pos < n_emit)
+            fin_len = valid & ~fin_eos & (self.slot_budget <= 0)
+            if fl.kind == "verify":
+                emitted = int(n_emit.sum())
+                n_valid = int(valid.sum())
+                self.spec_accepted_tokens += emitted
+                if n_valid:
+                    self.spec_accept_ema = (
+                        0.8 * self.spec_accept_ema + 0.2 * (emitted / n_valid)
                     )
-            tracer = get_tracer()
-            if tracer.enabled:
-                for s in map(int, np.nonzero(valid)[0]):
-                    ctx = self._slot_ctx.get(int(fl.rid0[s]))
-                    if ctx is not None:
-                        tracer.instant(
-                            "spec_verify",
-                            {"rid": int(fl.rid0[s]), "k": K,
-                             "accepted": int(n_emit[s]),
-                             **ctx_args(ctx.child())},
+                    for s in map(int, np.nonzero(valid)[0]):
+                        n = int(n_emit[s])
+                        self._spec_accept_counts[n] = (
+                            self._spec_accept_counts.get(n, 0) + 1
                         )
-        for s in map(int, np.nonzero(fin_eos)[0]):
-            self._free_slot(s, "eos")
-        for s in map(int, np.nonzero(fin_len)[0]):
-            self._free_slot(s, "length")
-        if fl.kind == "verify":
-            # chain breaks emit fewer tokens than were scheduled without
-            # finishing the slot — resync the scheduled bounds to actuals
-            # (safe: spec mode drains before every launch)
-            self.sched_lens[:] = self.lens
-            self.sched_budget[:] = self.slot_budget
+                if tracer.enabled:
+                    for s in map(int, np.nonzero(valid)[0]):
+                        ctx = self._slot_ctx.get(int(fl.rid0[s]))
+                        if ctx is not None:
+                            tracer.instant(
+                                "spec_verify",
+                                {"rid": int(fl.rid0[s]), "k": K,
+                                 "accepted": int(n_emit[s]),
+                                 **ctx_args(ctx.child())},
+                            )
+            for s in map(int, np.nonzero(fin_eos)[0]):
+                self._free_slot(s, "eos")
+            for s in map(int, np.nonzero(fin_len)[0]):
+                self._free_slot(s, "length")
+            if fl.kind == "verify":
+                # chain breaks emit fewer tokens than were scheduled without
+                # finishing the slot — resync the scheduled bounds to actuals
+                # (safe: spec mode drains before every launch)
+                self.sched_lens[:] = self.lens
+                self.sched_budget[:] = self.slot_budget
+            if tracer.enabled:
+                span.args = {
+                    "emitted": operator.index(n_emit.sum()),
+                    "finished": np.count_nonzero(fin_eos) + np.count_nonzero(fin_len),
+                }
         if self._tuner is not None and fl.kind == "decode" and not fl.fresh_compile:
-            host_s = (time.perf_counter() - t1) + fl.dispatch_s
-            self._tuner.observe(host_s, wait_s, K)
+            # the drain's own host work (its span less the blocking wait)
+            # plus the dispatch of the chunk it settles
+            self._tuner.observe(
+                (span.dur_s - wait.dur_s) + fl.dispatch_s, wait.dur_s, K)
 
     def _inflight_ready(self) -> bool:
         try:
@@ -2028,36 +2075,37 @@ class ContinuousBatchingEngine:
         """Admit + dispatch one decode chunk, then accept the PREVIOUS
         chunk's tokens while the new one runs (double buffering). Returns
         False when all work is done."""
-        if self.speculative:
-            return self._step_spec()
-        # if the previous chunk already finished on device, settle it
-        # first — admissions and the next launch then see fresh slots
-        # instead of riding a known-finished batch for another chunk
-        if self._inflight and self._inflight_ready():
-            self._drain_one()
-        self._admit()
-        launched = self._launch()
-        if not launched:
-            if self._inflight:
-                while self._inflight:
-                    self._drain_one()
-                self._admit()
-                launched = self._launch()
+        with get_tracer().span("engine.step"):
+            if self.speculative:
+                return self._step_spec()
+            # if the previous chunk already finished on device, settle it
+            # first — admissions and the next launch then see fresh slots
+            # instead of riding a known-finished batch for another chunk
+            if self._inflight and self._inflight_ready():
+                self._drain_one()
+            self._admit()
+            launched = self._launch()
             if not launched:
-                if self.queue and not (self.slot_rid >= 0).any():
-                    # nothing in flight, yet admission failed: the pool
-                    # cannot hold the front request at all — no progress
-                    # is possible
-                    raise RuntimeError(
-                        f"block pool too small: request rid="
-                        f"{self.queue[0].rid} needs "
-                        f"{self._blocks_needed(len(self.queue[0].prompt) + 1)} "
-                        f"blocks, pool has {len(self.free_blocks)} free"
-                    )
-                return bool(self.queue) or bool((self.slot_rid >= 0).any())
-        while len(self._inflight) > 1:
-            self._drain_one()
-        return True
+                if self._inflight:
+                    while self._inflight:
+                        self._drain_one()
+                    self._admit()
+                    launched = self._launch()
+                if not launched:
+                    if self.queue and not (self.slot_rid >= 0).any():
+                        # nothing in flight, yet admission failed: the pool
+                        # cannot hold the front request at all — no progress
+                        # is possible
+                        raise RuntimeError(
+                            f"block pool too small: request rid="
+                            f"{self.queue[0].rid} needs "
+                            f"{self._blocks_needed(len(self.queue[0].prompt) + 1)} "
+                            f"blocks, pool has {len(self.free_blocks)} free"
+                        )
+                    return bool(self.queue) or bool((self.slot_rid >= 0).any())
+            while len(self._inflight) > 1:
+                self._drain_one()
+            return True
 
     def _step_spec(self) -> bool:
         """The speculative step: drafting reads each slot's FULL context

@@ -9,11 +9,13 @@ its ring) and at export. Events use the Chrome trace-event JSON schema
 instants, ``"C"`` counters, ``"M"`` thread-name metadata), so an
 ``export()`` file loads directly in Perfetto / ``chrome://tracing``.
 
-``rl_tpu.utils.timing.timeit`` and ``record_function`` are thin clients
-of this recorder: every timed block becomes a span here, and (when JAX
-profiling is on) the same name is forwarded to
-``jax.profiler.TraceAnnotation`` so host spans line up with XLA device
-tracks in a combined capture.
+``span`` is also the bridge to the profiler: every span enters a
+``jax.profiler.TraceAnnotation`` of the same name, which is a flag test
+while no profiler session is open and writes the span into the
+``.xplane.pb`` next to the device's ops while one is. Opening a
+``jax.profiler`` session is the whole switch for a combined host+device
+capture. ``rl_tpu.utils.timing.timeit`` is a thin client of this
+recorder: every timed block becomes a span here.
 
 Causal request tracing (PR 12) rides on top: a :class:`TraceContext`
 (``trace_id``/``span_id``/``parent_id``) lives in a ``contextvars``
@@ -33,13 +35,18 @@ import dataclasses
 import json
 import os
 import threading
-import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
+from time import perf_counter_ns as _clock_ns
 from typing import Any, Callable, Iterator, Mapping
 
+# ``import rl_tpu`` has imported jax already, and jax its profiler: this
+# adds no import-time work for the light consumers of this module
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = [
+    "Span",
     "TraceContext",
     "TraceRecorder",
     "carry_context",
@@ -172,6 +179,41 @@ class _ThreadRing:
         self.dropped = 0
 
 
+class Span:
+    """One timed block on the calling thread: what :meth:`TraceRecorder.span`
+    returns. One clock read at each end, one tuple ``(name, start_ns,
+    dur_ns, args)`` appended to the thread's ring on exit (the Chrome-event
+    dict is built in ``export``), and a ``jax.profiler.TraceAnnotation`` of
+    the same name around it. ``args`` may be assigned inside the block
+    (counts known only at the boundary); ``dur_s`` holds the duration once
+    the block has exited, with the recorder disabled too, so code that
+    needs the number reads the span and keeps no clock of its own."""
+
+    __slots__ = ("_rec", "_ann", "_t0", "name", "args", "dur_s")
+
+    def __init__(self, rec: "TraceRecorder", name: str, args: Mapping[str, Any] | None):
+        self._rec = rec
+        self.name = name
+        self.args = args
+        self.dur_s = 0.0
+
+    def __enter__(self) -> "Span":
+        ann = self._ann = _TraceAnnotation(self.name)
+        ann.__enter__()
+        self._t0 = _clock_ns()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        t1 = _clock_ns()
+        self._ann.__exit__(et, ev, tb)
+        t0 = self._t0
+        self.dur_s = (t1 - t0) * 1e-9
+        rec = self._rec
+        if rec._enabled:
+            rec._emit((self.name, t0, t1 - t0, self.args))
+        return False
+
+
 class TraceRecorder:
     """Span/instant/counter recorder, one ring buffer per thread."""
 
@@ -188,7 +230,7 @@ class TraceRecorder:
         self._pid = os.getpid()
         # trace timestamps are perf_counter-based (monotonic, ns); remember
         # the origin so ts starts near zero and stays readable.
-        self._t0_ns = time.perf_counter_ns()
+        self._t0_ns = _clock_ns()
 
     # -- enable/disable -------------------------------------------------
     @property
@@ -212,38 +254,26 @@ class TraceRecorder:
             self._local.ring = ring
         return ring
 
-    def _emit(self, ev: dict) -> None:
+    def _emit(self, ev: dict | tuple) -> None:
         """Append to the calling thread's ring, counting the lap when a
-        full ring is about to evict its oldest event."""
+        full ring is about to evict its oldest event. ``ev`` is a Chrome
+        event dict, or a span's ``(name, start_ns, dur_ns, args)``."""
         ring = self._ring()
-        if len(ring.events) == self.capacity:
+        events = ring.events
+        if len(events) == self.capacity:
             ring.dropped += 1
-        ring.events.append(ev)
-
-    def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._t0_ns) / 1e3
+        events.append(ev)
 
     def now_us(self) -> float:
         """Current trace-clock time (µs since recorder creation) — the
         same clock event ``ts`` fields use; lets consumers (flight
-        recorder) window the export without private access."""
-        return self._now_us()
+        recorder, the engine's request timestamps) share it."""
+        return (_clock_ns() - self._t0_ns) / 1e3
 
-    @contextmanager
-    def span(self, name: str, args: Mapping[str, Any] | None = None) -> Iterator[None]:
-        """Time a block as a complete ("X") event on the calling thread."""
-        if not self._enabled:
-            yield
-            return
-        start = self._now_us()
-        try:
-            yield
-        finally:
-            end = self._now_us()
-            ev = {"ph": "X", "name": name, "ts": start, "dur": end - start}
-            if args:
-                ev["args"] = dict(args)
-            self._emit(ev)
+    def span(self, name: str, args: Mapping[str, Any] | None = None) -> Span:
+        """Time a block as a complete ("X") event on the calling thread,
+        and as a ``TraceAnnotation`` in an open profiler session."""
+        return Span(self, name, args)
 
     @contextmanager
     def ctx_span(
@@ -266,11 +296,11 @@ class TraceRecorder:
         parent = ctx if ctx is not None else _CTX.get()
         span_ctx = parent.child() if parent is not None else new_trace()
         token = _CTX.set(span_ctx)
-        start = self._now_us()
+        start = self.now_us()
         try:
             yield span_ctx
         finally:
-            end = self._now_us()
+            end = self.now_us()
             _CTX.reset(token)
             ev = {"ph": "X", "name": name, "ts": start, "dur": end - start}
             a = dict(args) if args else {}
@@ -278,17 +308,24 @@ class TraceRecorder:
             ev["args"] = a
             self._emit(ev)
 
-    def begin_span(self, name: str, args: Mapping[str, Any] | None = None) -> float:
+    def begin_span(self, name: str) -> float:
         """Manual span start for code that can't use a ``with`` block
         (e.g. ``timeit.__enter__``); pair with :meth:`end_span`."""
-        return self._now_us()
+        return self.now_us()
 
     def end_span(
-        self, name: str, start_us: float, args: Mapping[str, Any] | None = None
+        self,
+        name: str,
+        start_us: float,
+        args: Mapping[str, Any] | None = None,
+        end_us: float | None = None,
     ) -> None:
+        """A complete event from ``start_us`` to now, or to ``end_us`` for
+        an interval the caller already timed on this clock."""
         if not self._enabled:
             return
-        ev = {"ph": "X", "name": name, "ts": start_us, "dur": self._now_us() - start_us}
+        end = self.now_us() if end_us is None else end_us
+        ev = {"ph": "X", "name": name, "ts": start_us, "dur": end - start_us}
         if args:
             ev["args"] = dict(args)
         self._emit(ev)
@@ -297,7 +334,7 @@ class TraceRecorder:
         """Point event (watchdog death, preemption signal, straggler cut)."""
         if not self._enabled:
             return
-        ev = {"ph": "i", "name": name, "ts": self._now_us(), "s": "t"}
+        ev = {"ph": "i", "name": name, "ts": self.now_us(), "s": "t"}
         if args:
             ev["args"] = dict(args)
         self._emit(ev)
@@ -310,7 +347,7 @@ class TraceRecorder:
             {
                 "ph": "C",
                 "name": name,
-                "ts": self._now_us(),
+                "ts": self.now_us(),
                 "args": {k: float(v) for k, v in values.items()},
             }
         )
@@ -344,11 +381,18 @@ class TraceRecorder:
                 }
             )
             for ev in list(ring.events):
+                if type(ev) is tuple:  # a span, as Span.__exit__ left it
+                    name, t0_ns, dur_ns, args = ev
+                    out = {"ph": "X", "name": name,
+                           "ts": (t0_ns - self._t0_ns) / 1e3, "dur": dur_ns / 1e3}
+                    if args:
+                        out["args"] = dict(args)
+                else:
+                    out = dict(ev)
                 if since_us is not None and (
-                    ev.get("ts", 0.0) + ev.get("dur", 0.0) < since_us
+                    out.get("ts", 0.0) + out.get("dur", 0.0) < since_us
                 ):
                     continue
-                out = dict(ev)
                 out["pid"] = self._pid
                 out["tid"] = ring.tid
                 events.append(out)
@@ -387,7 +431,7 @@ _TRACER = TraceRecorder()
 
 
 def get_tracer() -> TraceRecorder:
-    """The process-default recorder (what ``timeit``/``record_function``
+    """The process-default recorder (what ``timeit``, the program's spans
     and the liveness/resilience hooks record into)."""
     return _TRACER
 

@@ -60,7 +60,7 @@ from ..models import (
     token_log_probs_with_aux,
 )
 from ..obs import DeviceMetrics
-from ..obs.trace import carry_context
+from ..obs.trace import carry_context, get_tracer
 from ..objectives.llm.grpo import GRPOLoss
 from ..parallel.mesh import AXIS_CONTEXT, AXIS_FSDP, DATA_AXES, data_sharding, fsdp_sharding
 from ..resilience.faults import fault_point, get_injector
@@ -475,22 +475,26 @@ class GRPOTrainer:
 
     def _consume(self, batch: ArrayDict) -> dict[str, float]:
         """Update on a collected batch, publish weights, drain metrics."""
+        tracer = get_tracer()
         inj = get_injector()
-        if inj is None and not self._fsdp:
-            self.params, self.opt_state, self._dm = self._update(
-                self.params, self.opt_state, batch, self._dm
-            )
-        else:
-            p = inj.poison("grpo.update") if inj is not None else 0.0
-            if self._poison_zero is None:
-                self._poison_zero = jnp.zeros((), jnp.float32)
-            pv = self._poison_zero if p == 0.0 else jnp.asarray(p, jnp.float32)
-            self.params, self.opt_state, self._dm = self._update(
-                self.params, self.opt_state, batch, self._dm, pv
-            )
-        self.scheme.push(self.params)  # non-blocking dispatch
-        self.policy_version.bump()
-        out = self._drain_metrics()
+        with tracer.span("grpo.update"):
+            if inj is None and not self._fsdp:
+                self.params, self.opt_state, self._dm = self._update(
+                    self.params, self.opt_state, batch, self._dm
+                )
+            else:
+                p = inj.poison("grpo.update") if inj is not None else 0.0
+                if self._poison_zero is None:
+                    self._poison_zero = jnp.zeros((), jnp.float32)
+                pv = self._poison_zero if p == 0.0 else jnp.asarray(p, jnp.float32)
+                self.params, self.opt_state, self._dm = self._update(
+                    self.params, self.opt_state, batch, self._dm, pv
+                )
+        with tracer.span("grpo.push"):
+            self.scheme.push(self.params)  # non-blocking dispatch
+            self.policy_version.bump()
+        with tracer.span("grpo.drain_metrics.wait"):
+            out = self._drain_metrics()
         self.history["reward"].append(out["reward"])
         self.history["loss"].append(out["loss"])
         return out
@@ -526,11 +530,15 @@ class GRPOTrainer:
     @hot_path(reason="per-iteration GRPO train step")
     def step(self) -> dict[str, float]:
         """collect → update → push weights. Returns step metrics."""
-        self._key, k = jax.random.split(self._key)
-        batch = self.collector.collect(None, k)  # scheme snapshot
-        if self._batch_placement is not None:
-            batch = jax.device_put(batch, self._batch_placement)
-        return self._consume(batch)
+        tracer = get_tracer()
+        with tracer.span("grpo.step", {"version": self.scheme.version}):
+            self._key, k = jax.random.split(self._key)
+            with tracer.span("grpo.collect"):
+                batch = self.collector.collect(None, k)  # scheme snapshot
+            if self._batch_placement is not None:
+                with tracer.span("grpo.place"):
+                    batch = jax.device_put(batch, self._batch_placement)
+            return self._consume(batch)
 
     def train(
         self,
@@ -844,27 +852,28 @@ class PipelinedGRPOTrainer(GRPOTrainer):
 
     @hot_path(reason="pipelined GRPO consumer step")
     def step(self) -> dict[str, float]:
-        batch, version = self._ensure_pipeline().get()
-        staleness = self.scheme.version - version
-        self.staleness_history.append(int(staleness))
-        if staleness > self.max_pending:
-            raise RuntimeError(
-                f"staleness invariant violated: batch generated at version "
-                f"{version}, trainer at {self.scheme.version} "
-                f"(bound {self.max_pending})"
+        with get_tracer().span("grpo.step", {"version": self.scheme.version}):
+            batch, version = self._ensure_pipeline().get()
+            staleness = self.scheme.version - version
+            self.staleness_history.append(int(staleness))
+            if staleness > self.max_pending:
+                raise RuntimeError(
+                    f"staleness invariant violated: batch generated at version "
+                    f"{version}, trainer at {self.scheme.version} "
+                    f"(bound {self.max_pending})"
+                )
+            # restamp with the version the GENERATOR snapshotted — the
+            # PolicyVersion transform stamped inside collect, racing the
+            # learner's bump; the snapshot is the authoritative value
+            B = batch["reward"].shape[0]
+            batch = batch.set(
+                "policy_version", np.full(B, version, np.int32)
             )
-        # restamp with the version the GENERATOR snapshotted — the
-        # PolicyVersion transform stamped inside collect, racing the
-        # learner's bump; the snapshot is the authoritative value
-        B = batch["reward"].shape[0]
-        batch = batch.set(
-            "policy_version", np.full(B, version, np.int32)
-        )
-        if self._batch_placement is not None:
-            batch = jax.device_put(batch, self._batch_placement)
-        out = self._consume(batch)
-        out["staleness"] = float(staleness)
-        return out
+            if self._batch_placement is not None:
+                batch = jax.device_put(batch, self._batch_placement)
+            out = self._consume(batch)
+            out["staleness"] = float(staleness)
+            return out
 
     def close(self):
         if self._pipeline is not None:

@@ -1,7 +1,7 @@
 import logging
 
 from .seeding import fold_seed, key_chain, seed_generator
-from .timing import record_function, set_profiling_enabled, timeit
+from .timing import timeit
 
 logger = logging.getLogger("rl_tpu")
 if not logger.handlers:
@@ -14,8 +14,6 @@ if not logger.handlers:
 __all__ = [
     "logger",
     "timeit",
-    "record_function",
-    "set_profiling_enabled",
     "seed_generator",
     "key_chain",
     "fold_seed",

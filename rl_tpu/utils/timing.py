@@ -3,7 +3,8 @@
 Usable as decorator, context manager, or explicit start/stop. On TPU, wall
 timing of jitted calls measures dispatch unless the result is blocked on, so
 ``timeit`` optionally calls ``block_until_ready`` on the wrapped function's
-output. ``jax.profiler`` spans are layered via :func:`record_function`.
+output. For a span that also lands in a ``jax.profiler`` capture use
+``rl_tpu.obs.get_tracer().span``.
 
 ``timeit`` is a thin client of :class:`rl_tpu.obs.trace.TraceRecorder`:
 every timed block is also recorded as a span on the calling thread, so a
@@ -15,7 +16,6 @@ a class-level lock and per-call start times live in thread-local stacks.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import threading
 import time
@@ -26,14 +26,7 @@ import jax
 
 from ..obs.trace import get_tracer
 
-__all__ = ["timeit", "record_function", "set_profiling_enabled"]
-
-_PROFILING = False
-
-
-def set_profiling_enabled(mode: bool = True) -> None:
-    global _PROFILING
-    _PROFILING = mode
+__all__ = ["timeit"]
 
 
 class timeit:
@@ -106,23 +99,3 @@ class timeit:
     def erase(cls) -> None:
         with cls._REG_LOCK:
             cls._REG.clear()
-
-
-@contextlib.contextmanager
-def record_function(name: str):
-    """Host trace span, plus a ``jax.profiler`` device annotation when
-    profiling is enabled.
-
-    Analog of the reference's ``_maybe_record_function``
-    (torchrl/_utils.py:470) over ``torch.profiler.record_function``. The
-    host span always goes to the process :class:`TraceRecorder` (cheap:
-    one ring-buffer append); ``jax.profiler.TraceAnnotation`` is layered
-    on only under :func:`set_profiling_enabled` so the same name shows up
-    against XLA device tracks in a combined capture.
-    """
-    with get_tracer().span(name):
-        if _PROFILING:
-            with jax.profiler.TraceAnnotation(name):
-                yield
-        else:
-            yield

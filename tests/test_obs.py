@@ -228,12 +228,13 @@ class TestTimeit:
         timeit.erase()
 
     def test_timeit_emits_tracer_spans(self, fresh_obs):
-        from rl_tpu.utils.timing import record_function, timeit
+        from rl_tpu.obs import get_tracer
+        from rl_tpu.utils.timing import timeit
 
         _, tracer = fresh_obs
         with timeit("timed_block"):
             pass
-        with record_function("rf_block"):
+        with get_tracer().span("rf_block"):
             pass
         names = [
             e["name"] for e in tracer.export()["traceEvents"] if e["ph"] == "X"

@@ -8,8 +8,8 @@ the original request span — verified here by walking the Perfetto
 export. Around it: TraceContext propagation across thread and wire
 boundaries, the timestamp-interleaved export fix, the SLO engine's
 attainment/burn-rate math and gauges, the flight recorder's postmortem
-bundle on an injected Supervisor budget exhaustion, and the <5%
-tracing-overhead bound on a fused device cycle."""
+bundle on an injected Supervisor budget exhaustion, and what a span
+costs (ns a span against fixed limits, spans a decode launch)."""
 
 import json
 import os
@@ -688,47 +688,63 @@ class TestFleetTraceTree:
 
 
 class TestTracingOverhead:
-    def test_armed_ctx_tracing_under_five_percent(self):
-        """Tracing armed + context propagation on a fused device cycle
-        stays inside the bench obs budget (overhead_frac < 0.05)."""
+    """What a span costs, as its own best-of-N reading against fixed
+    limits (a ratio of two wall times of a parallel run guards nothing),
+    and how many spans a decode launch records."""
+
+    @staticmethod
+    def _ns_a_span(make_span, n=20_000, rounds=7):
+        best = float("inf")
+        for _ in range(rounds):
+            t0 = time.perf_counter_ns()
+            for _ in range(n):
+                with make_span("x"):
+                    pass
+            best = min(best, (time.perf_counter_ns() - t0) / n)
+        return best
+
+    def test_span_cost_within_budget(self):
+        from contextlib import contextmanager
+
+        armed, off = TraceRecorder(), TraceRecorder(enabled=False)
+
+        @contextmanager
+        def span_before(name):  # TraceRecorder.span as it was before PR 25
+            start = armed.now_us()
+            try:
+                yield
+            finally:
+                armed._emit({"ph": "X", "name": name, "ts": start, "dur": armed.now_us() - start})
+
+        cost_armed = self._ns_a_span(armed.span)
+        cost_off = self._ns_a_span(off.span)
+        cost_before = self._ns_a_span(span_before)
+        print(f"ns a span: armed {cost_armed:.0f}, disabled {cost_off:.0f}, before PR 25 {cost_before:.0f}")
+        assert cost_armed < 5_000, f"armed span costs {cost_armed:.0f} ns"
+        assert cost_off < 2_000, f"disabled span costs {cost_off:.0f} ns"
+        assert cost_armed <= cost_before, (cost_armed, cost_before)
+        assert len(armed.export()["traceEvents"]) > 1  # and it did record
+
+    def test_armed_ctx_span_cost_within_budget(self):
+        """The causal span (context derivation and ids) stays cheap too."""
         tracer = TraceRecorder()
-        prev = set_tracer(tracer)
-        try:
-            @jax.jit
-            def fused(x):
-                return jax.lax.fori_loop(
-                    0, 200, lambda i, a: a @ a * 0.999 + 0.001, x)
+        with use_context(new_trace()):
+            cost = self._ns_a_span(tracer.ctx_span, n=5_000)
+        assert cost < 50_000, f"ctx_span costs {cost:.0f} ns"
+        spans = _events(tracer, "x")
+        assert spans and all("trace_id" in e["args"] for e in spans)
 
-            x = jnp.full((128, 128), 0.001, jnp.float32)
-            jax.block_until_ready(fused(x))
-            N = 20
-
-            def run_plain():
-                t0 = time.perf_counter()
-                for _ in range(N):
-                    jax.block_until_ready(fused(x))
-                return time.perf_counter() - t0
-
-            def run_traced():
-                root = new_trace()
-                t0 = time.perf_counter()
-                with use_context(root):
-                    for _ in range(N):
-                        with tracer.ctx_span("cycle"):
-                            jax.block_until_ready(fused(x))
-                return time.perf_counter() - t0
-
-            # interleaved best-of: the ratio divides near-equal numbers,
-            # so one-sided wall jitter must not masquerade as overhead
-            best_plain = best_traced = float("inf")
-            for _ in range(5):
-                best_plain = min(best_plain, run_plain())
-                best_traced = min(best_traced, run_traced())
-            frac = best_traced / best_plain - 1.0
-            assert frac < 0.05, f"tracing overhead {frac:.3%} >= 5%"
-            # and it actually traced: N spans per run, all context-linked
-            spans = _events(tracer, "cycle")
-            assert len(spans) == 5 * N
-            assert all("trace_id" in e["args"] for e in spans)
-        finally:
-            set_tracer(prev)
+    def test_engine_records_at_most_ten_spans_a_launch(self, fresh_obs):
+        _, tracer = fresh_obs
+        cfg = TransformerConfig(vocab_size=97, d_model=32, n_layers=1, n_heads=2, d_ff=64,
+                                max_seq_len=64, dtype=jnp.float32)
+        m = TransformerLM(cfg)
+        params = m.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+        eng = ContinuousBatchingEngine(m, params, n_slots=4, block_size=4, n_blocks=65,
+                                       prompt_buckets=(8,), decode_chunk=1)
+        for i in range(8):
+            eng.submit(np.arange(1, 6 + i % 3), 12)
+        eng.run()
+        n = len([e for e in _events(tracer) if e["ph"] == "X" and e["name"].startswith("engine.")])
+        assert eng.decode_launches >= 20
+        assert n <= 10 * eng.decode_launches, (n, eng.decode_launches)
