@@ -63,27 +63,36 @@ def _attr_worker(q) -> None:
     """Attribution drain loop (its own daemon thread, never a dispatch
     thread): block until the sampled dispatch's first output leaf is
     device-ready, then credit the elapsed wall time to the program. The
-    host sync lives HERE, off every hot path — dispatch only enqueues."""
-    import jax
+    host sync lives HERE, off every hot path — dispatch only enqueues.
 
+    The loop keeps nothing of a sample while it waits for the next: a
+    program pins its function's closure (``grpo.update`` the whole
+    trainer, a serving program its engine) and the leaf a device buffer,
+    and the last sample of a run would hold them for the process's life."""
     while True:
         item = q.get()
         if item is None:
             return
-        ref, t0, leaf = item
-        try:
-            jax.block_until_ready(leaf)
-        except Exception:
-            continue
-        dt = time.perf_counter() - t0
-        prog = ref()
-        if prog is None:
-            continue
-        with prog._lock:
-            prog.stats["device_s"] += dt
-            prog.stats["device_samples"] += 1
-            prog.stats["device_flops"] += prog.flops_per_call
-        _notify_dispatch(prog, dt)
+        _attr_credit(*item)
+        del item
+
+
+def _attr_credit(ref, t0: float, leaf: Any) -> None:
+    import jax
+
+    try:
+        jax.block_until_ready(leaf)
+    except Exception:
+        return
+    dt = time.perf_counter() - t0
+    prog = ref()
+    if prog is None:
+        return
+    with prog._lock:
+        prog.stats["device_s"] += dt
+        prog.stats["device_samples"] += 1
+        prog.stats["device_flops"] += prog.flops_per_call
+    _notify_dispatch(prog, dt)
 
 
 def _notify_dispatch(prog: "CachedProgram", dt: float) -> None:

@@ -2,13 +2,14 @@
 
 The f32 kernel itself lives in :mod:`rl_tpu.ops.attention`
 (``paged_flash_decode`` — gather-free reads straight off the PR 11 block
-tables via scalar-prefetch index maps). This module adds the registry
+tables via scalar-prefetch index maps, a slot's heads and several table
+entries a grid step). This module adds the registry
 glue (:func:`decode_mode` decides kernel vs stock-XLA gather per trace)
-and :func:`paged_flash_decode_int8`: the same grid and online-softmax
-recurrence, but K/V blocks arrive as int8 and are dequantized IN the
-kernel from scalar-prefetched per-(block, kv-head) scales — the dequant
-multiply rides the VMEM-resident block, so the f32 pool never exists in
-HBM.
+and :func:`paged_flash_decode_int8`: the same online-softmax recurrence
+over a grid of one (slot, head, table entry) a step, where K/V blocks
+arrive as int8 and are dequantized IN the kernel from scalar-prefetched
+per-(block, kv-head) scales — the dequant multiply rides the
+VMEM-resident block, so the f32 pool never exists in HBM.
 """
 
 from __future__ import annotations
@@ -26,17 +27,46 @@ def decode_mode(*, int8: bool):
     return registry.selection("kv_int8" if int8 else "paged_attention")
 
 
+def _decode_softmax_update(q, k_blk, v_blk, valid, m_ref, l_ref, acc_ref):
+    """The online-softmax recurrence of one (row, table entry) grid cell:
+    score one KV block, mask, and fold it into the running (m, l, acc)
+    scratch state."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.attention import _NEG_INF
+
+    s = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    s = jnp.where(valid, s, _NEG_INF)
+    m = m_ref[:]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    p = jnp.exp(s - m_new[:, None])
+    corr = jnp.exp(m - m_new)
+    m_ref[:] = m_new
+    l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1)
+    acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
+        p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
 def _paged_decode_int8_kernel(
     table_ref, len_ref, sk_ref, sv_ref, *refs, block_k, n_heads, group
 ):
-    """`ops.attention._paged_decode_kernel` with int8 K/V: scales are
-    scalar-prefetched flat [N*Hk] and looked up by the SAME block index
-    the index map fetched, then folded into the f32 upcast."""
+    """One grid step = one BLOCK-TABLE entry for one (slot, q-head), over
+    int8 K/V. The index map picks the cell's kv block from the
+    scalar-prefetched block table (trailing/unassigned entries re-point at
+    the slot's last valid block and ``pl.when`` skips their compute);
+    scales are scalar-prefetched flat [N*Hk] and looked up by the SAME
+    block index the index map fetched, then folded into the f32 upcast.
+    This is the grid `ops.attention._paged_decode_kernel` had before it
+    took a slot a step; the port is ROADMAP A1b."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from ..ops.attention import _NEG_INF, _decode_softmax_update
+    from ..ops.attention import _NEG_INF
 
     q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)

@@ -264,13 +264,18 @@ def price_call(target: str, in_avals: list, out_avals: list) -> dict | None:
 
 
 def _cost_paged_decode(in_avals: list, out_avals: list) -> dict:
-    # operands: table [S, max_blocks], lens [S], (scales [N*Hk] x2 on the
-    # int8 variant), q [S*H, 8, D], k_flat/v_flat [N*Hk, block, D] — q and
-    # the pools are the only rank-3 operands, in that order
+    # operands: table [S, max_blocks], lens [S], then rank-1 scalars (the
+    # f32 kernel's chunk list, the int8 variant's scales [N*Hk] x2),
+    # q [S*Hk, Gp, D] (the int8 variant: [S*H, 8, D]), k_flat/v_flat
+    # [N*Hk, block, D] once per operand — q and the pools are the only
+    # rank-3 operands, q first
     table = in_avals[0]
     rank3 = [a for a in in_avals if len(getattr(a, "shape", ()) or ()) == 3]
     q, k_flat = rank3[0], rank3[1]
-    rows = float(q.shape[0])  # S*H query rows
+    # query rows as the q operand counts them: S*H for MHA and for the
+    # int8 variant; the f32 kernel packs a GQA group's heads into one
+    # row block, so under GQA this prices its FLOPs a group short
+    rows = float(q.shape[0])
     D = float(q.shape[-1])
     block = float(k_flat.shape[1])
     max_blocks = float(table.shape[1])
@@ -278,7 +283,10 @@ def _cost_paged_decode(in_avals: list, out_avals: list) -> dict:
     # per attendable position per head: QK dot (2D) + PV dot (2D)
     flops = 4.0 * rows * L * D
     kv_item = float(getattr(getattr(k_flat, "dtype", None), "itemsize", 4) or 4)
-    # each (row, table entry) grid cell DMAs one K and one V block
+    # the bound is a full table: every (row, table entry) moves one K and
+    # one V block of one kv head. The int8 variant's grid does fetch per
+    # (slot, q-head, entry); the f32 kernel fetches an entry's Hk heads
+    # whole, once a slot, and only the entries under the slot's length
     bytes_ = rows * max_blocks * block * D * kv_item * 2.0
     bytes_ += _nbytes(q) + sum(_nbytes(a) for a in out_avals)
     return {"flops": flops, "bytes": bytes_}

@@ -702,64 +702,90 @@ def _dense_reference(q, k, v, causal, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _decode_softmax_update(q, k_blk, v_blk, valid, m_ref, l_ref, acc_ref):
-    """The shared decode-side online-softmax recurrence: score one KV
-    block, mask, and fold it into the running (m, l, acc) scratch state
-    (used by both the dense-cache and paged decode kernels)."""
-    s = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = jnp.where(valid, s, _NEG_INF)
-    m = m_ref[:]
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m - m_new)
-    m_ref[:] = m_new
-    l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1)
-    acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+# K and V blocks of one paged-decode step (both, double-buffered by the
+# pipeline) may take this much VMEM; the step's f32 temporaries take about
+# as much again, and the two stay well inside a v5e's 16 MiB scoped default.
+_PAGED_TILE_BYTES = 4 << 20
+# keys a paged-decode step attends at least (one full lane row of scores)
+_PAGED_STEP_KEYS = 128
 
 
-def _paged_decode_kernel(table_ref, len_ref, *refs, block_k, n_heads):
-    """One grid step = one BLOCK-TABLE entry for one (slot, q-head).
+def _paged_pages(max_blocks, block_k, n_kv, D, itemsize):
+    """Table entries one paged-decode step takes: enough for
+    ``_PAGED_STEP_KEYS`` keys, halved until the double-buffered K and V
+    blocks fit ``_PAGED_TILE_BYTES`` (VMEM pads the head width to 128
+    lanes), and never more than the table has."""
+    pages = min(max(1, -(-_PAGED_STEP_KEYS // block_k)), max_blocks)
+    entry = 2 * 2 * n_kv * block_k * max(D, 128) * itemsize
+    while pages > 1 and pages * entry > _PAGED_TILE_BYTES:
+        pages //= 2
+    return pages
 
-    The kv block fetched for grid cell (b, j) is chosen by the index map
-    from the scalar-prefetched block table — the pool is read IN PLACE,
-    no per-step gather of the slot's KV into a contiguous buffer (the
-    copy the XLA paged path pays). Trailing/unassigned entries re-point
-    at the slot's last valid block (Pallas skips the re-fetch) and
-    ``pl.when`` skips their compute.
+
+def _paged_decode_kernel(
+    table_ref, len_ref, slot_ref, chunk_ref, row_ref, q_ref, *refs, block_k, pages
+):
+    """One grid step = one CHUNK of one slot: ``pages`` block-table entries,
+    for every kv head and every query head of its GQA group at once.
+
+    The grid is the list of live chunks (``slot_ref``/``chunk_ref``, one
+    pair a step, a slot's chunks in a row), as long as the slots' lengths
+    make it: there is no step for table entries a slot does not reach, so
+    a call's time follows the live keys. Each of a chunk's entries is its
+    own operand, a whole ``[Hk, block, D]`` run of the head-major pool
+    that the index map picks from the scalar-prefetched block table — the
+    pool is read IN PLACE, no per-step gather of the slot's KV into a
+    contiguous buffer (the copy the XLA paged path pays). ``table_ref``
+    holds 0 (the scratch block: finite, never attended) wherever an entry
+    is not attendable: unassigned, scratch, or starting at or past
+    ``attend_len``; such an entry's keys are masked, as are the keys past
+    ``attend_len`` inside the last entry, and a slot with no valid key
+    returns zeros. Every step writes the slot's output as far as it has
+    come; ``row_ref`` (read by the output's index map alone) sends all
+    but a slot's last step to a spare output block, so each real block is
+    written back once, by the step that completes it.
     """
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_j = pl.num_programs(1)
-    slot = b // n_heads
-    attend_len = len_ref[slot]  # number of attendable positions
+    k_refs, v_refs = refs[:pages], refs[pages : 2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages :]
+    step = pl.program_id(0)
+    slot, chunk = slot_ref[step], chunk_ref[step]
+    span = pages * block_k
 
-    @pl.when(j == 0)
+    @pl.when(chunk == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_start = j * block_k
-    assigned = table_ref[slot, j] > 0  # 0 = reserved scratch, -1 = unassigned
-
-    @pl.when((kv_start < attend_len) & assigned)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [8, D]
-        k_blk = k_ref[0].astype(jnp.float32)  # [block_k, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        kv_pos = kv_start + jax.lax.iota(jnp.int32, block_k)
-        valid = kv_pos[None, :] < attend_len
-        _decode_softmax_update(q, k_blk, v_blk, valid, m_ref, l_ref, acc_ref)
-
-    @pl.when(j == num_j - 1)
-    def _finish():
-        l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
+    key = jax.lax.broadcasted_iota(jnp.int32, (1, 1, span), 2)
+    assigned = jnp.zeros((1, 1, span), jnp.int32)
+    for i in range(pages):
+        assigned = jnp.where(
+            key // block_k == i, table_ref[slot, chunk * pages + i], assigned
+        )
+    valid = (assigned > 0) & (chunk * span + key < len_ref[slot])
+    q = q_ref[...]  # [Hk, Gp, D], scaled
+    k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [Hk, span, D]
+    v = jnp.concatenate([r[...].astype(jnp.float32) for r in v_refs], axis=1)
+    # bf16 x bf16 products are exact in the f32 accumulator: a bf16 query
+    # on a bf16 pool scores as the f32 product does, unconverted
+    score_dtype = jnp.promote_types(q.dtype, k.dtype)
+    s = jax.lax.dot_general(
+        q.astype(score_dtype), k.astype(score_dtype),
+        (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+    )  # [Hk, Gp, span]
+    s = jnp.where(valid, s, _NEG_INF)
+    m = m_ref[...]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    # a masked key weighs exactly 0, also while every key so far is masked
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m - m_new)
+    l = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    )  # [Hk, Gp, D]
+    m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
+    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_flash_decode(
@@ -778,17 +804,23 @@ def paged_flash_decode(
     Args:
         q: [S, 1, H, D] — one query per sequence slot.
         pool_k, pool_v: [N, Hk, block, D] HEAD-MAJOR shared block pools
-            (``Hk`` may divide H — GQA); viewed as [N*Hk, block, D] so
-            the Mosaic block dims are (block, D). Block 0 is reserved
-            scratch (never read).
+            (``Hk`` may divide H — GQA), so one table entry is one
+            contiguous ``[Hk, block, D]`` run; viewed as [N*Hk, block, D]
+            (the Mosaic block dims are (block, D)). Block 0 is reserved
+            scratch (never attended).
         block_table: [S, max_blocks] int32 — per-slot pool indices;
             -1 = unassigned.
         attend_lens: [S] int32 — attendable positions per slot (for the
             decode-after-write step this is ``len + 1``).
 
-    Returns [S, 1, H, D]. The index map reads the scalar-prefetched
-    block table, so each (slot, head, j) grid cell DMAs exactly its
-    block's single KV head from the pool — no contiguous per-slot copy.
+    Returns [S, 1, H, D]. A grid step (:func:`_paged_decode_kernel`)
+    DMAs ``pages`` whole table entries of one slot, each by an index map
+    that reads the scalar-prefetched block table, and attends every head
+    to them; the grid's length is dynamic, the sum over slots of
+    ``cdiv(attend_lens, pages * block)``, so there is no contiguous
+    per-slot copy and no fetch or compute past a slot's length. ``pages``
+    follows from ``block``, ``D``, ``Hk`` and the pool's dtype
+    (:func:`_paged_pages`).
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -799,46 +831,74 @@ def paged_flash_decode(
     if H % Hk:
         raise ValueError(f"q heads ({H}) must be a multiple of kv heads ({Hk})")
     group = H // Hk
-    max_blocks = block_table.shape[1]
     scale = scale if scale is not None else D**-0.5
+    pages = _paged_pages(
+        block_table.shape[1], block_k, Hk, D, pool_k.dtype.itemsize
+    )
 
-    q_b = jnp.moveaxis(q * scale, 2, 1).reshape(S * H, 1, D)
-    q_b = jnp.pad(q_b, ((0, 0), (0, 7), (0, 0)))
-    table = jnp.asarray(block_table, jnp.int32)
+    # [S, 1, H, D] -> [S*Hk, Gp, D]: a kv head's query heads share its K/V
+    # as the rows of one product (sublane-padded to a multiple of 8)
+    q_b = (q * scale).reshape(S * Hk, group, D)
+    q_b = jnp.pad(q_b, ((0, 0), (0, -group % 8), (0, 0)))
+    rows = q_b.shape[1]
     lens = jnp.asarray(attend_lens, jnp.int32).reshape(S)
+    # the table as the index maps read it: 0 (scratch) for every entry
+    # that is unassigned (-1) or starts at or past the slot's length,
+    # padded to a whole number of chunks
+    table = jnp.asarray(block_table, jnp.int32)
+    starts = jnp.arange(table.shape[1], dtype=jnp.int32) * block_k
+    table = jnp.where(starts[None, :] < lens[:, None], jnp.maximum(table, 0), 0)
+    table = jnp.pad(table, ((0, 0), (0, -table.shape[1] % pages)))
+    # the grid: each slot's live chunks in a row (one even where it has no
+    # key, so that its output is written). Step w belongs to the slot that
+    # follows those whose chunks end at or before w, and is its chunk
+    # number w less the chunks of those slots.
+    n_chunks = table.shape[1] // pages
+    live = jnp.clip(-(-lens // (pages * block_k)), 1, n_chunks)  # [S]
+    ends = jnp.cumsum(live)
+    w = jnp.arange(S * n_chunks, dtype=jnp.int32)
+    done = ends[:, None] <= w[None, :]  # [S, S * n_chunks]
+    slot_of = jnp.minimum(jnp.sum(done, axis=0, dtype=jnp.int32), S - 1)
+    chunk_of = w - jnp.sum(jnp.where(done, live[:, None], 0), axis=0, dtype=jnp.int32)
+    chunk_of = jnp.minimum(chunk_of, n_chunks - 1)  # past the grid's end: unused
+    # output block of a step: the slot's own from its last chunk, the spare
+    # block S from the others. The pipeline may write a block back after
+    # every step, and two write-backs of one block in flight are unordered.
+    last = jnp.any(ends[:, None] == w[None, :] + 1, axis=0)
+    row_of = jnp.where(last, slot_of, S)
     # head-major pool -> [N*Hk, block, D] (a reshape, not a copy)
     k_flat = pool_k.reshape(N * Hk, block_k, D)
     v_flat = pool_v.reshape(N * Hk, block_k, D)
 
-    def kv_index(b, j, table_ref, len_ref):
-        slot = b // H
-        kvh = (b % H) // group
-        # clamp trailing entries at the slot's last data-bearing block so
-        # Pallas re-points (and skips) instead of fetching garbage
-        last = jnp.maximum(len_ref[slot] - 1, 0) // block_k
-        jj = jnp.minimum(j, last)
-        blk = jnp.maximum(table_ref[slot, jj], 0)
-        return (blk * Hk + kvh, 0, 0)
+    def q_index(w, table_ref, len_ref, slot_ref, chunk_ref, row_ref):
+        return (slot_ref[w], 0, 0)
 
-    kernel = functools.partial(
-        _paged_decode_kernel, block_k=block_k, n_heads=H
-    )
+    def o_index(w, table_ref, len_ref, slot_ref, chunk_ref, row_ref):
+        return (row_ref[w], 0, 0)
+
+    def kv_spec(i):
+        def index(w, table_ref, len_ref, slot_ref, chunk_ref, row_ref):
+            entry = chunk_ref[w] * pages + i
+            return (table_ref[slot_ref[w], entry], 0, 0)  # in units of Hk rows
+
+        return pl.BlockSpec((Hk, block_k, D), index)
+
+    kv_specs = [kv_spec(i) for i in range(pages)]
+    kernel = functools.partial(_paged_decode_kernel, block_k=block_k, pages=pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S * H, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 8, D), lambda b, j, table_ref, len_ref: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
+        num_scalar_prefetch=5,
+        grid=(ends[-1],),
+        in_specs=[pl.BlockSpec((Hk, rows, D), q_index), *kv_specs, *kv_specs],
+        out_specs=pl.BlockSpec((Hk, rows, D), o_index),
+        scratch_shapes=[
+            _scratch((Hk, rows, 1)), _scratch((Hk, rows, 1)), _scratch((Hk, rows, D))
         ],
-        out_specs=pl.BlockSpec((1, 8, D), lambda b, j, table_ref, len_ref: (b, 0, 0)),
-        scratch_shapes=[_scratch((8,)), _scratch((8,)), _scratch((8, D))],
     )
     out = pl.pallas_call(
         kernel,
         name="_paged_decode_kernel",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S * H, 8, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(((S + 1) * Hk, rows, D), q.dtype),
         interpret=interpret,
-    )(table, lens, q_b, k_flat, v_flat)
-    return jnp.moveaxis(out[:, :1].reshape(S, H, 1, D), 1, 2)
+    )(table, lens, slot_of, chunk_of, row_of, q_b, *[k_flat] * pages, *[v_flat] * pages)
+    return out[: S * Hk, :group].reshape(S, 1, H, D)

@@ -7,7 +7,8 @@ no lowering for, a block that breaks the (8, 128) tiling, a kernel that
 does not fit VMEM. Interpret mode shows none of that. Each case compiles
 one kernel at the width its default path runs it (the 110M model: 12
 heads x 64, vocab 32768, ctx 1024, bf16, 16 slots, KV blocks of 16; the
-PER bench: capacity 2**20, batch 256) and asserts the kernel is in the
+PER bench: capacity 2**20, batch 256; the paged decode kernel also at the
+benchmark cells' shapes and at a GQA width) and asserts the kernel is in the
 program as a ``tpu_custom_call``.
 
 Nothing runs, so this says nothing about results or speed
@@ -104,6 +105,27 @@ ATTENTION_CASES = {
     "paged_flash_decode": (
         paged_flash_decode,
         [((SLOTS, 1, H, D), jnp.bfloat16)] + [(_POOL, jnp.bfloat16)] * 2 + _TABLE,
+    ),
+    # the benchmark's two cells: gpt2-medium (16 heads x 64, bf16), the GRPO
+    # collector's 8 slots and the rollout engine's 32, 64-entry tables
+    "paged_flash_decode_grpo_cell": (
+        paged_flash_decode,
+        [((8, 1, 16, 64), jnp.bfloat16)]
+        + [((513, 16, 16, 64), jnp.bfloat16)] * 2
+        + [((8, 64), jnp.int32), ((8,), jnp.int32)],
+    ),
+    "paged_flash_decode_rollout_cell": (
+        paged_flash_decode,
+        [((32, 1, 16, 64), jnp.bfloat16)]
+        + [((2049, 16, 16, 64), jnp.bfloat16)] * 2
+        + [((32, 64), jnp.int32), ((32,), jnp.int32)],
+    ),
+    # a Queue B width: GQA (32 query heads on 8), head width 128
+    "paged_flash_decode_gqa_d128": (
+        paged_flash_decode,
+        [((SLOTS, 1, 32, 128), jnp.bfloat16)]
+        + [((N_BLOCKS, 8, BLOCK, 128), jnp.bfloat16)] * 2
+        + _TABLE,
     ),
     "paged_flash_decode_int8": (
         paged_flash_decode_int8,

@@ -290,6 +290,37 @@ class TestDriftDetector:
 
 
 class TestAttributionFeed:
+    def test_worker_keeps_no_sample_while_idle(self, tmp_path):
+        """The attribution worker credits a sampled dispatch and lets go:
+        between samples it must hold neither the program (whose function
+        pins its owner: ``grpo.update`` the trainer with its parameters
+        and optimizer state) nor the output leaf."""
+        import gc
+        import weakref
+
+        from rl_tpu.compile.registry import _ATTR_SAMPLE_EVERY
+
+        class Owner:
+            def double(self, x):
+                return x * 2.0
+
+        creg = ProgramRegistry(store=ExecutableStore(str(tmp_path / "store")))
+        owner = Owner()
+        prog = creg.register("t.pin_demo", owner.double)
+        x = jnp.ones((4, 4), jnp.float32)
+        for _ in range(_ATTR_SAMPLE_EVERY):  # the last of these is sampled
+            out = prog(x)
+        deadline = time.monotonic() + 30.0
+        while prog.stats["device_samples"] < 1:  # the worker drains async
+            assert time.monotonic() < deadline, "sample never credited"
+            time.sleep(0.01)
+        time.sleep(0.05)  # the worker is back in its queue's get()
+        alive, leaf = weakref.ref(owner), weakref.ref(out)
+        del owner, prog, out
+        gc.collect()
+        assert alive() is None, "the idle worker pins the program's owner"
+        assert leaf() is None, "the idle worker pins the sampled output"
+
     def test_forced_kernel_fallback_detected_within_sampled_dispatches(
             self, tmp_path, fresh_obs, monkeypatch):
         """The PR-18 acceptance demo: a program registered (and

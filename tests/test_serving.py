@@ -249,6 +249,83 @@ class TestAllocatorEdgeCases:
                     attention_mask=jnp.ones((2, 4), bool), cache=cache)
 
 
+def _paged_oracle(q, pool_k, pool_v, table, lens):
+    """Dense float64 softmax over each slot's attendable keys: position p
+    of slot s lives in table entry p // block, and counts when that entry
+    names a real block (> 0) and p < lens[s]. No key: zeros."""
+    q, pool_k, pool_v = (np.asarray(a, np.float64) for a in (q, pool_k, pool_v))
+    S, _, H, D = q.shape
+    _, Hk, Bk, _ = pool_k.shape
+    out = np.zeros((S, 1, H, D))
+    for s_ in range(S):
+        pos = np.arange(min(int(lens[s_]), table.shape[1] * Bk))
+        blk = table[s_, pos // Bk]
+        pos, blk = pos[blk > 0], blk[blk > 0]
+        if not len(pos):
+            continue
+        for h in range(H):
+            kh = pool_k[blk, h // (H // Hk), pos % Bk]  # [L, D]
+            vh = pool_v[blk, h // (H // Hk), pos % Bk]
+            sc = kh @ q[s_, 0, h] * D**-0.5
+            w = np.exp(sc - sc.max())
+            out[s_, 0, h] = (w / w.sum()) @ vh
+    return out
+
+
+def _around(Bk, span):
+    # 1; one under / on / one over a block boundary and a step boundary
+    return [1, Bk - 1, Bk, Bk + 1, span - 1, span, span + 1, 2 * span + 1]
+
+
+_F32 = dict(rtol=1e-4, atol=1e-5)
+# bf16 pools: the kernel rounds only its output to bf16 (scores, softmax
+# state and the PV sum are f32), the oracle not at all
+_BF16 = dict(rtol=2e-2, atol=2e-2)
+_DECODE_BASE = dict(
+    H=4, Hk=4, D=64, block=16, max_blocks=24, pages=8, dtype=jnp.float32,
+    lens=_around, holes=(), scan=False, tol=_F32,
+)
+_DECODE_CASES = {
+    "mha_d64_block16": {},
+    "mha_d128_block16": dict(D=128),
+    "mha_d64_block8": dict(block=8, pages=16, max_blocks=40),
+    "gqa2_d64_block16": dict(Hk=2),
+    "gqa8_d128_block8": dict(H=8, Hk=1, D=128, block=8, pages=16, max_blocks=40),
+    "mqa_group_over_8_rows": dict(H=12, Hk=1),
+    "bf16_mha_d64_block16": dict(dtype=jnp.bfloat16, tol=_BF16),
+    "bf16_gqa_d128_block8": dict(
+        H=8, Hk=2, D=128, block=8, pages=16, max_blocks=40,
+        dtype=jnp.bfloat16, tol=_BF16,
+    ),
+    # the cells' table: 64 entries of 16; -n = n short of the full table
+    "full_64_entry_table": dict(
+        max_blocks=64, lens=lambda Bk, span: [0, -1, -Bk, 150, 1]
+    ),
+    "table_not_a_multiple_of_pages": dict(
+        max_blocks=11, lens=lambda Bk, span: [0, -1, span + 3, 5]
+    ),
+    "table_shorter_than_a_step": dict(
+        max_blocks=3, pages=3, lens=lambda Bk, span: [0, Bk + 1, 1]
+    ),
+    "pages_halved_to_fit_vmem": dict(
+        H=32, Hk=32, D=128, pages=4, lens=lambda Bk, span: [span + 1, 3 * span]
+    ),
+    # slot 0: every entry -1 (zeros out); slot 1: scratch 0 and -1 inside
+    # its live range; slot 2: a whole step of holes before live entries
+    "unassigned_and_scratch_entries": dict(
+        lens=lambda Bk, span: [span + 5, 2 * span + 5, 2 * span + 5],
+        holes=[(0, e, -1) for e in range(24)]
+        + [(1, 0, 0), (1, 3, -1), (1, 9, 0)]
+        + [(2, e, -1 if e % 2 else 0) for e in range(8)],
+    ),
+    "under_lax_scan": dict(lens=lambda Bk, span: [Bk - 1, span - 1, 1], scan=True),
+    "bf16_under_lax_scan": dict(
+        Hk=2, lens=lambda Bk, span: [Bk - 1, span - 1, 1], scan=True,
+        dtype=jnp.bfloat16, tol=_BF16,
+    ),
+}
+
+
 class TestPagedDecodeKernel:
     """Pallas paged-decode (interpret mode on CPU; reads the pool in
     place through the scalar-prefetched block table)."""
@@ -286,6 +363,59 @@ class TestPagedDecodeKernel:
                 np.testing.assert_allclose(
                     np.asarray(out[s_, 0, h]), p @ vh, rtol=1e-4, atol=1e-5
                 )
+
+    @pytest.mark.parametrize("name", list(_DECODE_CASES))
+    def test_decomposition_matches_dense_oracle(self, name):
+        """A grid step takes every head of a slot and ``pages`` table
+        entries: lengths around a block and a step boundary, holes in the
+        table, GQA, both head widths, block sizes and pool dtypes."""
+        from rl_tpu.ops.attention import _paged_pages, paged_flash_decode
+
+        c = {**_DECODE_BASE, **_DECODE_CASES[name]}
+        H, Hk, D, Bk, maxb, dtype = (
+            c["H"], c["Hk"], c["D"], c["block"], c["max_blocks"], c["dtype"]
+        )
+        pages = _paged_pages(maxb, Bk, Hk, D, jnp.dtype(dtype).itemsize)
+        assert pages == c["pages"]  # the boundary the lengths below straddle
+        span = pages * Bk
+        lens = [n if n > 0 else maxb * Bk + n for n in c["lens"](Bk, span)]
+        S = len(lens)
+        N = 1 + S * maxb
+        rng = np.random.default_rng(7)
+        # block 0 is scratch: a huge value there shows in any output that
+        # attends it
+        pool_k = rng.standard_normal((N, Hk, Bk, D)).astype(np.float32)
+        pool_v = rng.standard_normal((N, Hk, Bk, D)).astype(np.float32)
+        pool_k[0], pool_v[0] = 50.0, 1e4
+        table = np.full((S, maxb), -1, np.int32)
+        for s_, n in enumerate(lens):
+            nb = -(-n // Bk)
+            table[s_, :nb] = 1 + s_ * maxb + rng.permutation(maxb)[:nb]
+        for s_, e, val in c["holes"]:
+            table[s_, e] = val
+        q = rng.standard_normal((S, 1, H, D)).astype(np.float32)
+        q, pool_k, pool_v = (jnp.asarray(a, dtype) for a in (q, pool_k, pool_v))
+        args = (jnp.asarray(table), jnp.asarray(lens, jnp.int32))
+        if c["scan"]:
+            # chunked decode: the kernel inside a lax.scan body, lengths
+            # growing a token a step
+            def body(n, _):
+                o = paged_flash_decode(q, pool_k, pool_v, args[0], n, interpret=True)
+                return n + 1, o
+
+            steps = 3
+            _, outs = jax.jit(
+                lambda n: jax.lax.scan(body, n, None, length=steps)
+            )(args[1])
+        else:
+            steps = 1
+            outs = paged_flash_decode(q, pool_k, pool_v, *args, interpret=True)[None]
+        assert outs.dtype == q.dtype
+        for t in range(steps):
+            want = _paged_oracle(q, pool_k, pool_v, table, np.asarray(lens) + t)
+            np.testing.assert_allclose(
+                np.asarray(outs[t], np.float32), want, **c["tol"]
+            )
 
     def test_model_decode_path_matches_xla_paged(self):
         """TransformerLM with flash_decode=True routes paged decode steps
