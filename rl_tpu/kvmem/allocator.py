@@ -336,6 +336,17 @@ class PrefixKVAllocator:
             need = -(-total_len // self.block) - len(chain)
             return base + cow_lcp, need
 
+    def charge(self, tokens, total_len: int) -> int:
+        """Blocks by which :meth:`free_adjusted` falls if ``tokens`` is
+        admitted now and grows to ``total_len``: the new blocks, and the
+        matched resident blocks that nobody references yet (they stop
+        being reclaimable). What the engine holds against its free
+        capacity before it admits."""
+        with self._lock:
+            chain, _cow, _lcp, _ = self.tree.match(tuple(tokens))
+            need = -(-total_len // self.block) - len(chain)
+            return need + sum(1 for n in chain if n.refs == 0)
+
     # -- lifecycle / telemetry -------------------------------------------------
 
     def reset(self) -> None:

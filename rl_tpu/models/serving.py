@@ -87,6 +87,7 @@ class Request:
     # None outside any traced request
     ctx: Any = None
     t_submit: float = 0.0  # seconds on the recorder's clock (now_us() / 1e6)
+    blocks: int = 0  # KV blocks it needs to run to its budget (set at submit)
 
 
 @dataclasses.dataclass
@@ -362,9 +363,23 @@ class ContinuousBatchingEngine:
         self.spec_lookahead = int(spec_lookahead)
         self._base_key = jax.random.key(seed)
 
+        if model.cfg.early_exit_threshold < 1.0:
+            raise ValueError(
+                "the engine runs every loop for every slot: "
+                f"early_exit_threshold={model.cfg.early_exit_threshold} < 1.0 "
+                "would need a decode step whose depth differs by slot"
+            )
         self.cache = model.init_paged_cache(
             n_slots, n_blocks, block_size, self.max_blocks
         )
+        self.n_blocks = n_blocks
+        # K/V sets a token leaves in the pools, counted from the cache: one
+        # an entry, or several stacked in one entry's pools (scan_layers)
+        self.cache_entries = sum(c["pool_k"].shape[0] // n_blocks for c in self.cache)
+        self.kv_bytes_per_token = sum(
+            c[f].nbytes for c in self.cache for f in ("pool_k", "pool_v")
+        ) // (n_blocks * block_size)
+        self.loop_steps = model.cfg.loop_steps
         # host mirrors (the allocator's source of truth)
         self.free_blocks = list(range(1, n_blocks))  # 0 = reserved scratch
         self._kvmem: PrefixKVAllocator | None = None
@@ -380,6 +395,11 @@ class ContinuousBatchingEngine:
         self.lens = np.zeros(n_slots, np.int64)  # prompt + ACCEPTED tokens
         self.slot_rid = np.full(n_slots, -1, np.int64)  # -1 = free slot
         self.slot_budget = np.zeros(n_slots, np.int64)  # tokens left to emit
+        # admission by the pool: blocks the slot's request needs to run to
+        # its budget. What of it the slot's table does not hold yet is
+        # RESERVED: no admission may count on it, so a running slot never
+        # waits for a block (see _admit)
+        self.slot_need = np.zeros(n_slots, np.int64)
         # scheduled upper bounds: cover launches whose tokens are still in
         # flight (== lens/slot_budget whenever nothing is undrained)
         self.sched_lens = np.zeros(n_slots, np.int64)
@@ -419,6 +439,10 @@ class ContinuousBatchingEngine:
         self.host_transfers = 0  # blocking device->host materializations
         self.decode_chunk_last = 1
         self.admissions = 0
+        # admission rounds that left a free slot empty for want of blocks,
+        # and the blocks the slots' tables held, summed over decode steps
+        self.admissions_deferred_kv = 0
+        self.kv_block_steps = 0
         self.completions: dict[str, int] = {"eos": 0, "length": 0}
         # speculative accounting: dispatches that carried drafts, tokens
         # proposed/accepted, and the accept-rate EMA the fleet's lane
@@ -436,7 +460,7 @@ class ContinuousBatchingEngine:
         # on-device token accounting: the decode scan counts every token
         # generated by an effectively-active slot, so throughput telemetry
         # never adds a per-chunk host sync (read only at scrape time)
-        self._obs_spec = DeviceMetrics(counters=("tokens",))
+        self._obs_spec = DeviceMetrics(counters=("tokens", "loop_steps_run"))
         self.dev_obs = self._obs_spec.init()
 
         # every hot program is a registry-named CachedProgram: compiles are
@@ -571,13 +595,20 @@ class ContinuousBatchingEngine:
         tok, lp = self._sample(last_logits, key)
         return tok, lp, _pools_from(cache)
 
+    def _count_tokens(self, dm, n):
+        """The device counters of ``n`` decoded tokens: the tokens, and the
+        loops of the layer stack run for them."""
+        n = n.astype(jnp.float32)
+        dm = self._obs_spec.inc(dm, "tokens", n)
+        return self._obs_spec.inc(dm, "loop_steps_run", n * self.loop_steps)
+
     def _get_decode_prog(self, chunk: int):
         prog = self._decode_progs.get(chunk)
         if prog is not None:
             return prog
 
         eos = self.eos_id
-        obs_spec = self._obs_spec
+        count_tokens = self._count_tokens
 
         def fn(params, pools, table, lens, active, budget, last, run_mask, key, dm):
             """K decode steps in one program, with the per-slot stop rule
@@ -592,7 +623,7 @@ class ContinuousBatchingEngine:
             def body(carry, k):
                 pools, lens, active, budget, last, dm = carry
                 eff = active & run_mask
-                dm = obs_spec.inc(dm, "tokens", eff.sum().astype(jnp.float32))
+                dm = count_tokens(dm, eff.sum())
                 cache = _pool_caches(
                     pools, block_table=table, len=lens, active=eff
                 )
@@ -686,8 +717,21 @@ class ContinuousBatchingEngine:
         dataflow orders the prefill's writes after these copies without
         any host sync."""
         return tuple(
-            tuple(a.at[dst].set(a[src]) for a in lp) for lp in pools
+            tuple(
+                a.at[self._block_rows(a, dst)].set(a[self._block_rows(a, src)])
+                for a in lp
+            )
+            for lp in pools
         )
+
+    def _block_rows(self, a, blocks):
+        """Rows of pool array ``a`` that hold ``blocks``: themselves, or in
+        a stacked pool each entry's copy (entry e's block b is row
+        ``e * n_blocks + b``), entry by entry."""
+        entries = a.shape[0] // self.n_blocks
+        if entries == 1:
+            return blocks
+        return (self.n_blocks * jnp.arange(entries)[:, None] + blocks[None, :]).reshape(-1)
 
     def _get_cow_prog(self, n: int):
         prog = self._cow_progs.get(n)
@@ -788,7 +832,7 @@ class ContinuousBatchingEngine:
             return prog
 
         eos = self.eos_id
-        obs_spec = self._obs_spec
+        count_tokens = self._count_tokens
 
         def fn(params, pools, table, lens, active, budget, last, run_mask,
                rids, ntok, base_key, dm):
@@ -800,7 +844,7 @@ class ContinuousBatchingEngine:
             def body(carry, _):
                 pools, lens, active, budget, last, ntok, dm = carry
                 eff = active & run_mask
-                dm = obs_spec.inc(dm, "tokens", eff.sum().astype(jnp.float32))
+                dm = count_tokens(dm, eff.sum())
                 cache = _pool_caches(
                     pools, block_table=table, len=lens, active=eff
                 )
@@ -856,7 +900,6 @@ class ContinuousBatchingEngine:
             return prog
 
         eos = self.eos_id
-        obs_spec = self._obs_spec
         msl = self.max_seq_len
         K = int(k)
 
@@ -895,7 +938,7 @@ class ContinuousBatchingEngine:
                 budget,
             )
             n_emit = jnp.where(eff, n_emit, 0)
-            dm = obs_spec.inc(dm, "tokens", n_emit.sum().astype(jnp.float32))
+            dm = self._count_tokens(dm, n_emit.sum())
             lens = lens + n_emit
             ntok = ntok + n_emit
             budget = budget - n_emit
@@ -949,6 +992,16 @@ class ContinuousBatchingEngine:
             self.table[slot, j] = b
             self._pending_table_writes.append((slot, j, b))
         return True
+
+    def _kv_reserved(self) -> int:
+        """Blocks the running slots will still take to reach their budgets."""
+        have = (self.table >= 0).sum(axis=1)
+        return operator.index(np.maximum(self.slot_need - have, 0).sum())
+
+    def _kv_available(self) -> int:
+        """Blocks a new admission may count on: the free capacity (in
+        prefix mode with what eviction would free) less the reservations."""
+        return self.kv_free_blocks() - self._kv_reserved()
 
     def _flush_table_writes(self):
         """Apply the accumulated host table-mirror writes to the pinned
@@ -1028,6 +1081,7 @@ class ContinuousBatchingEngine:
         self.sched_lens[slot] = 0
         self.slot_budget[slot] = 0
         self.sched_budget[slot] = 0
+        self.slot_need[slot] = 0  # a slot that stopped early drops its reservation
         self.slot_rid[slot] = -1
         self.slot_tokens[slot] = []
         self.slot_lps[slot] = []
@@ -1209,12 +1263,12 @@ class ContinuousBatchingEngine:
 
     def metrics_snapshot(self) -> dict:
         """Flat host dict of the engine's telemetry. The only device read
-        is the on-device token counter (one explicit transfer), so calling
+        is the on-device counters (one explicit transfer), so calling
         this at scrape cadence costs nothing on the decode path."""
         used = self._n_pool_blocks - len(self.free_blocks)
-        tokens = float(jax.device_get(self.dev_obs["counters"]["tokens"]))
+        counters = jax.device_get(self.dev_obs["counters"])
         snap = {
-            "tokens_generated": tokens,
+            "tokens_generated": float(counters["tokens"]),
             "decode_steps": self.decode_steps,
             "decode_launches": self.decode_launches,
             "decode_drains": self.decode_drains,
@@ -1231,6 +1285,12 @@ class ContinuousBatchingEngine:
             "kv_blocks_used": used,
             "kv_blocks_total": self._n_pool_blocks,
             "kv_utilization": used / max(self._n_pool_blocks, 1),
+            "kv_reserved_blocks": self._kv_reserved(),
+            "kv_block_steps": self.kv_block_steps,
+            "admissions_deferred_kv": self.admissions_deferred_kv,
+            "loop_steps_run": float(counters["loop_steps_run"]),
+            "cache_entries": self.cache_entries,
+            "kv_bytes_per_token": self.kv_bytes_per_token,
         }
         snap["prefill_tokens_computed"] = self.prefill_tokens_computed
         snap["prefill_tokens_cached"] = self.prefill_tokens_cached
@@ -1306,6 +1366,8 @@ class ContinuousBatchingEngine:
         if not free:
             return None
         s = free[0]
+        if self._blocks_needed(P + 1) > self._kv_available():
+            return None  # what is free is reserved for the running slots
         if not self._ensure_blocks(s, P + 1):
             return None
         blocks = [int(b) for b in self.table[s] if b >= 0]
@@ -1355,9 +1417,14 @@ class ContinuousBatchingEngine:
             pad_n = _pow2ceil(n)
             gidx = jnp.asarray(
                 np.asarray(blocks + [blocks[-1]] * (pad_n - n), np.int32))
+
+            def take(a):  # [entries * n, ...]: each entry's blocks in a row
+                got = np.asarray(a[self._block_rows(a, gidx)])
+                got = got.reshape(-1, pad_n, *got.shape[1:])[:, :n]
+                return got.reshape(-1, *got.shape[2:])
+
             kv = tuple(
-                tuple(np.asarray(c[f][gidx])[:n]
-                      for f in _POOL_FIELDS if f in c)
+                tuple(take(c[f]) for f in _POOL_FIELDS if f in c)
                 for c in self.cache
             )
         # the borrowed slot returns immediately: the handoff owns host
@@ -1397,11 +1464,13 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"handoff block_size {ho.block_size} != engine block size "
                 f"{self.block}")
-        n = len(ho.kv[0][0])
+        n = len(ho.kv[0][0]) * len(self.cache) // self.cache_entries  # rows / entries stacked in one
         free = [s for s in range(self.n_slots) if self.slot_rid[s] < 0]
-        if not free or n > len(self.free_blocks):
+        need = max(n, self._blocks_needed(int(ho.lens) + ho.budget + 1))
+        if not free or need > self._kv_available():
             return None
         s = free[0]
+        self.slot_need[s] = need
         blocks = [self.free_blocks.pop() for _ in range(n)]
         for j, b in enumerate(blocks):
             self.table[s, j] = b
@@ -1415,12 +1484,12 @@ class ContinuousBatchingEngine:
         for c, layer_kv in zip(self.cache, ho.kv):
             fields = [f for f in _POOL_FIELDS if f in c]
             for f, host in zip(fields, layer_kv):
-                vals = (
-                    np.concatenate(
-                        [host, np.repeat(host[-1:], pad_n - n, axis=0)])
-                    if pad_n > n else host
-                )
-                c[f] = c[f].at[didx].set(jnp.asarray(vals))
+                vals = host.reshape(-1, n, *host.shape[1:])  # [entries, n, ...]
+                if pad_n > n:
+                    vals = np.concatenate(
+                        [vals, np.repeat(vals[:, -1:], pad_n - n, axis=1)], axis=1)
+                c[f] = c[f].at[self._block_rows(c[f], didx)].set(
+                    jnp.asarray(vals.reshape(-1, *vals.shape[2:])))
         rid = self._next_rid
         self._next_rid += 1
         P = int(ho.lens)
@@ -1487,17 +1556,33 @@ class ContinuousBatchingEngine:
                 f"prompt length {len(prompt)} exceeds the largest prefill "
                 f"bucket {self.buckets[-1]}; raise prompt_buckets"
             )
+        need = self._blocks_needed(len(prompt) + max_new_tokens)
+        if need > self._n_pool_blocks:
+            raise ValueError(
+                f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
+                f"needs {need} KV blocks, the pool has {self._n_pool_blocks}: "
+                "the request could never be admitted"
+            )
         rid = self._next_rid
         self._next_rid += 1
         self.queue.append(Request(
             rid, prompt, max_new_tokens, ctx=current_context(),
-            t_submit=get_tracer().now_us() * 1e-6,
+            t_submit=get_tracer().now_us() * 1e-6, blocks=need,
         ))
         return rid
 
     def _admit(self):
         """Fill free slots from the queue; one bucketed prefill per
         admission round (requests grouped into the round's max bucket).
+
+        Admission is by the POOL, not by the prompt: a request is taken
+        when the blocks it needs to run to its budget (``len(prompt) +
+        max_new_tokens``, known at ``submit``) are free and not reserved
+        by a running slot; it takes its prompt's blocks now and the rest
+        stay reserved for it (``slot_need``), so no running slot ever
+        waits for a block. Where the pool holds every slot's whole table
+        the rule never binds.
+
         Prefill is synchronous — the host needs the first token to settle
         eos/budget immediately — but its device-state updates are fused
         into one jitted masked write, sequenced after any in-flight chunk
@@ -1508,14 +1593,18 @@ class ContinuousBatchingEngine:
         batch: list[tuple[int, Request]] = []
         starts: list[int] = []  # cached-prefix length per admitted row
         cows: list[tuple[int, int]] = []  # (src, dst) block copies this round
+        kv_short = False  # the queue's head did not fit what is free and unreserved
         if self._kvmem is not None:
             for s in free:
                 if not self.queue:
                     break
                 req = self.queue[0]
-                plan = self._kvmem.admit(
-                    req.prompt.tolist(), len(req.prompt) + 1
-                )
+                seq = req.prompt.tolist()
+                total = len(seq) + req.max_new_tokens
+                if self._kvmem.charge(seq, total) > self._kv_available():
+                    kv_short = True
+                    break  # retry after sequences finish
+                plan = self._kvmem.admit(seq, len(seq) + 1)
                 if plan is None:
                     break  # pool exhausted: retry after sequences finish
                 if plan is DEFER_ROUND:
@@ -1528,6 +1617,7 @@ class ContinuousBatchingEngine:
                     self.table[s, j] = b
                     self._pending_table_writes.append((s, j, b))
                 self._slot_lease[s] = plan.lease
+                self.slot_need[s] = req.blocks
                 starts.append(plan.shared_len)
                 if plan.cow is not None:
                     cows.append(plan.cow)
@@ -1537,10 +1627,16 @@ class ContinuousBatchingEngine:
                 if not self.queue:
                     break
                 req = self.queue[0]
-                if not self._ensure_blocks(s, len(req.prompt) + 1):
-                    break  # pool exhausted: retry after sequences finish
+                if req.blocks > self._kv_available():
+                    kv_short = True
+                    break  # retry after sequences finish
+                self._ensure_blocks(s, len(req.prompt) + 1)
+                self.slot_need[s] = req.blocks
                 starts.append(0)
                 batch.append((s, self.queue.pop(0)))
+        # requests a free slot could not take for want of blocks
+        kv_deferred = min(len(free) - len(batch), len(self.queue)) if kv_short else 0
+        self.admissions_deferred_kv += kv_short
         if not batch:
             return
         cached = sum(starts)
@@ -1554,6 +1650,9 @@ class ContinuousBatchingEngine:
                     "queue_depth": len(self.queue),
                     "prefill_tokens": computed,
                     "prefill_cached": cached,
+                    "kv_free_blocks": self.kv_free_blocks(),
+                    "kv_reserved_blocks": self._kv_reserved(),
+                    "kv_deferred": kv_deferred,
                 }
             if self._kvmem is not None:
                 # the compile ladder buckets the SUFFIX, not the prompt: a
@@ -1863,6 +1962,7 @@ class ContinuousBatchingEngine:
             self.decode_steps += chunk
             self.decode_launches += 1
             self.decode_chunk_last = chunk
+            self.kv_block_steps += chunk * np.count_nonzero(self.table >= 0)
             if tracer.enabled:
                 span.args = {"launch": self.decode_launches, "chunk": chunk,
                              "active": np.count_nonzero(run)}
@@ -1971,6 +2071,7 @@ class ContinuousBatchingEngine:
             self.spec_dispatches += 1
             self.spec_draft_tokens += sum(len(d) for d in drafts.values())
             self.decode_steps += 1  # one forward, however many positions
+            self.kv_block_steps += np.count_nonzero(self.table >= 0)
             self.decode_launches += 1
             self.decode_chunk_last = K
             if tracer.enabled:
@@ -2099,8 +2200,8 @@ class ContinuousBatchingEngine:
                         raise RuntimeError(
                             f"block pool too small: request rid="
                             f"{self.queue[0].rid} needs "
-                            f"{self._blocks_needed(len(self.queue[0].prompt) + 1)} "
-                            f"blocks, pool has {len(self.free_blocks)} free"
+                            f"{self.queue[0].blocks} "
+                            f"blocks, pool has {self._kv_available()} free"
                         )
                     return bool(self.queue) or bool((self.slot_rid >= 0).any())
             while len(self._inflight) > 1:
@@ -2122,8 +2223,8 @@ class ContinuousBatchingEngine:
                 raise RuntimeError(
                     f"block pool too small: request rid="
                     f"{self.queue[0].rid} needs "
-                    f"{self._blocks_needed(len(self.queue[0].prompt) + 1)} "
-                    f"blocks, pool has {len(self.free_blocks)} free"
+                    f"{self.queue[0].blocks} "
+                    f"blocks, pool has {self._kv_available()} free"
                 )
             return bool(self.queue) or bool((self.slot_rid >= 0).any())
         while self._inflight:
@@ -2179,6 +2280,7 @@ class ContinuousBatchingEngine:
         self.lens[:] = 0
         self.slot_rid[:] = -1
         self.slot_budget[:] = 0
+        self.slot_need[:] = 0
         self.sched_lens[:] = 0
         self.sched_budget[:] = 0
         self.slot_tokens = [[] for _ in range(n)]

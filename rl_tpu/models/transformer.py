@@ -65,14 +65,61 @@ class TransformerConfig:
     # memory trade for gradient-accumulation microbatching.
     remat: bool = False
     remat_policy: str = "none"
+    # -- the block's variants, as data. The defaults are GPT-2's: pre-LN
+    # LayerNorm, learned positions, a biased GELU FFN, a tied head, heads
+    # d_model / n_heads wide, the stack run once.
+    d_head: int | None = None  # head width; None = d_model // n_heads
+    norm: str = "layernorm"  # "layernorm" | "rmsnorm"
+    norm_eps: float = 1e-6
+    # "pre": a norm before each branch. "sandwich": one before AND one
+    # after each branch (four a block), the residual added after the second
+    norm_placement: str = "pre"
+    position: str = "learned"  # "learned" (wpe, capped at max_seq_len) | "rotary"
+    rope_theta: float = 10000.0
+    ffn: str = "gelu"  # "gelu" (up/down, biased) | "swiglu" (gate/up/down, no bias)
+    tie_embeddings: bool = True  # False: an untied [d_model, vocab] head
+    # looped decoder: the SAME n_layers run loop_steps times, the final norm
+    # after each loop; every (loop, layer) pair keeps its own K/V, cache
+    # entry ``loop * n_layers + layer``. An exit gate weighs the loops: the
+    # non-cache forward leaves at the first loop whose cumulative exit
+    # probability reaches the threshold; the cache paths run every loop and
+    # take the threshold only as 1.0 (= the last loop).
+    loop_steps: int = 1
+    early_exit_threshold: float = 1.0
+    # one `lax.scan` over the layers: parameters stacked under "layers"
+    # with a leading [n_layers] axis, the caches ONE entry whose pools hold
+    # every (loop, layer) pair's blocks side by side (entry e's block b is
+    # row e * n_blocks + b), so a program's size does not grow with depth
+    scan_layers: bool = False
+
+    def __post_init__(self):
+        for field, allowed in (
+            ("norm", ("layernorm", "rmsnorm")),
+            ("norm_placement", ("pre", "sandwich")),
+            ("position", ("learned", "rotary")),
+            ("ffn", ("gelu", "swiglu")),
+        ):
+            if getattr(self, field) not in allowed:
+                raise ValueError(
+                    f"{field} must be one of {'|'.join(allowed)}, got {getattr(self, field)!r}"
+                )
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps must be >= 1, got {self.loop_steps}")
+        if self.scan_layers and self.kv_int8:
+            raise ValueError("scan_layers does not take kv_int8 pools")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def cache_entries(self) -> int:
+        """K/V sets a token leaves in a cache: one a (loop, layer) pair."""
+        return self.loop_steps * self.n_layers
 
 
 def _flash_interpret(cfg) -> bool:
@@ -107,6 +154,14 @@ def _paged_attention(cfg, q, k, v, cache, active):
     S, T = q.shape[0], q.shape[1]
     n_blocks, block = pool_k.shape[0], pool_k.shape[2]
     max_blocks = table.shape[1]
+    # stacked pools (cfg.scan_layers): this call is cache entry ``entry``,
+    # whose blocks are rows [base, base + n_blocks) of the pools; its block
+    # 0 is its own scratch
+    entry = cache.get("entry")
+    base = 0
+    if entry is not None:
+        n_blocks //= cfg.cache_entries
+        base = entry * n_blocks
     # `active` is [S] (whole slots) or [S, T] (token-level — bucketed
     # prefill pads prompts up to the bucket; padded tokens must not land
     # in the cache or advance the length)
@@ -129,7 +184,7 @@ def _paged_attention(cfg, q, k, v, cache, active):
     # clipped out-of-range position would silently corrupt the LAST
     # block's rows (chunked decode can speculate past a slot's budget)
     blk_global = jnp.where(active_t & (blk_slot < max_blocks), blk_global, 0)
-    flat_blk = blk_global.reshape(-1)
+    flat_blk = blk_global.reshape(-1) + base
     flat_off = off.reshape(-1)
     # pools are HEAD-MAJOR [N, Hk, block, D] (the Pallas kernel views them
     # as [N*Hk, block, D] for free — Mosaic needs (block, D) last dims);
@@ -143,6 +198,22 @@ def _paged_attention(cfg, q, k, v, cache, active):
         pool_v, scale_v = quantize_block_write(
             pool_v, scale_v, flat_blk, flat_off, v.reshape(S * T, *v.shape[2:])
         )
+    elif entry is not None:
+        # a token's K (or V) is kv_heads rows of the pool seen as
+        # [N * Hk * block, D]. Written as whole rows of that view, the
+        # scatter wants the row-major layout the read kernel is held to;
+        # written as [Hk, D] windows of the 4-d pool it wants the block
+        # axis outside the heads, and XLA then relays out the whole pool
+        # before every kernel call
+        Hk, D = pool_k.shape[1], pool_k.shape[3]
+        rows = (flat_blk[:, None] * Hk + jnp.arange(Hk)[None, :]) * block
+        rows = (rows + flat_off[:, None]).reshape(-1)
+        pool_k = pool_k.reshape(-1, D).at[rows].set(
+            k.reshape(-1, D), mode="drop"
+        ).reshape(pool_k.shape)
+        pool_v = pool_v.reshape(-1, D).at[rows].set(
+            v.reshape(-1, D), mode="drop"
+        ).reshape(pool_v.shape)
     else:
         pool_k = pool_k.at[flat_blk, :, flat_off].set(
             k.reshape(S * T, *k.shape[2:]), mode="drop"
@@ -162,6 +233,8 @@ def _paged_attention(cfg, q, k, v, cache, active):
         # the block table drives the DMA; the pool is read in place
         interpret = (mode == "interpret") or _flash_interpret(cfg)
         attend = lens + 1  # decode-after-write: positions 0..len inclusive
+        if entry is not None:
+            table = jnp.where(table > 0, table + base, table)
         if int8:
             from ..kernels.paged_attention import paged_flash_decode_int8
 
@@ -193,7 +266,7 @@ def _paged_attention(cfg, q, k, v, cache, active):
     rep = cfg.n_heads // cfg.kv_heads
     scale = cfg.head_dim**-0.5
     L = max_blocks * block
-    safe_table = jnp.clip(table, 0, n_blocks - 1)  # -1 (unassigned) -> scratch
+    safe_table = jnp.clip(table, 0, n_blocks - 1) + base  # -1 (unassigned) -> scratch
     k_all = pool_k[safe_table]  # [S, max_blocks, Hk, block, D]
     v_all = pool_v[safe_table]
     if int8:
@@ -237,6 +310,26 @@ def _advance_paged_cache(cache, pool_k, pool_v, lens, active_t,
     return new_cache
 
 
+def _norm(cfg, name: str):
+    """The block's norm: statistics in float32, the result in ``cfg.dtype``."""
+    cls = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    return cls(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+
+def _rotary(x, positions, theta: float):
+    """Rotary positions, rotate-half over the whole head: x [B, T, H, D],
+    positions [T] or [B, T] (each token's absolute position). Angles and
+    the rotation in float32, the result in x's dtype."""
+    D = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [(B,) T, D/2]
+    ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]  # [(B,) T, 1, D]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
+
+
 class _Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -245,13 +338,14 @@ class _Attention(nn.Module):
         cfg = self.cfg
         B, T, _ = x.shape
         Hk = cfg.kv_heads
+        width = cfg.n_heads * cfg.head_dim
         if Hk == cfg.n_heads:
             qkv = nn.Dense(
-                3 * cfg.d_model, use_bias=False, dtype=cfg.dtype, name="qkv"
+                3 * width, use_bias=False, dtype=cfg.dtype, name="qkv"
             )(x)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:  # GQA/MQA: fewer KV heads — smaller cache, less decode traffic
-            q = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="wq")(x)
+            q = nn.Dense(width, use_bias=False, dtype=cfg.dtype, name="wq")(x)
             kv = nn.Dense(
                 2 * Hk * cfg.head_dim, use_bias=False, dtype=cfg.dtype, name="wkv"
             )(x)
@@ -260,6 +354,11 @@ class _Attention(nn.Module):
         q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
         k = k.reshape(B, T, Hk, cfg.head_dim)
         v = v.reshape(B, T, Hk, cfg.head_dim)
+        if cfg.position == "rotary":
+            # before the cache write: a cached key is stored rotated, by
+            # the position its token has in its own sequence
+            q = _rotary(q, positions, cfg.rope_theta)
+            k = _rotary(k, positions, cfg.rope_theta)
 
         def dense_gqa(q, k, v, attn_mask):
             """XLA attention with KV-head grouping ([B,H,T,S] scores)."""
@@ -292,12 +391,20 @@ class _Attention(nn.Module):
                 cfg, q, k, v, cache, cache.get("active")
             )
         elif cache is not None:
-            # decode step: append to the KV cache at position `positions`
+            # decode step: append to the KV cache at slot `cache["len"]`
             ck, cv, cache_len = cache["k"], cache["v"], cache["len"]
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_len, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_len, axis=1)
+            entry = cache.get("entry")
+            if entry is None:
+                ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_len, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_len, axis=1)
+                k, v = ck, cv
+            else:  # stacked [entries, B, S, Hk, D] (cfg.scan_layers)
+                at = (entry, 0, cache_len, 0, 0)
+                ck = jax.lax.dynamic_update_slice(ck, k[None], at)
+                cv = jax.lax.dynamic_update_slice(cv, v[None], at)
+                k = jax.lax.dynamic_index_in_dim(ck, entry, keepdims=False)
+                v = jax.lax.dynamic_index_in_dim(cv, entry, keepdims=False)
             new_cache = {"k": ck, "v": cv, "len": cache_len + T}
-            k, v = ck, cv
             S = k.shape[1]
             if (
                 cfg.flash_decode
@@ -352,7 +459,7 @@ class _Attention(nn.Module):
                 causal = causal & mask[:, None, None, :]
             o = dense_gqa(q, k, v, causal)
 
-        o = o.reshape(B, T, cfg.d_model)
+        o = o.reshape(B, T, width)
         o = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="proj")(o)
         return o, new_cache
 
@@ -409,19 +516,30 @@ class _Block(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, mask, cache=None):
+    def __call__(self, x, mask, cache=None, positions=None):
         cfg = self.cfg
+        sandwich = cfg.norm_placement == "sandwich"
         h, new_cache = _Attention(cfg, name="attn")(
-            nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x), mask, cache
+            _norm(cfg, "ln1")(x), mask, cache, positions
         )
+        if sandwich:
+            h = _norm(cfg, "ln1_post")(h)
         x = x + h
-        y = nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x)
+        y = _norm(cfg, "ln2")(x)
         if cfg.moe_experts:
             y = _MoEFFN(cfg, name="moe")(y, serving=cache is not None)
+        elif cfg.ffn == "swiglu":
+            gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="gate")(y)
+            up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="up")(y)
+            y = nn.Dense(
+                cfg.d_model, use_bias=False, dtype=cfg.dtype, name="down"
+            )(nn.silu(gate) * up)
         else:
             y = nn.Dense(cfg.d_ff, dtype=cfg.dtype, name="up")(y)
             y = nn.gelu(y)
             y = nn.Dense(cfg.d_model, dtype=cfg.dtype, name="down")(y)
+        if sandwich:
+            y = _norm(cfg, "ln2_post")(y)
         return x + y, new_cache
 
 
@@ -440,14 +558,34 @@ def _remat_policy(name: str):
         ) from None
 
 
+# the fields of a cache entry that hold K/V: what a scanned stack carries
+# from layer to layer (the table, lengths and masks are the same for all)
+_KV_FIELDS = ("pool_k", "pool_v", "k", "v")
+
+
 class TransformerLM(nn.Module):
-    """GPT-style LM: tokens [B, T] -> logits [B, T, V]."""
+    """Decoder-only LM: tokens [B, T] -> logits [B, T, V]. The block's
+    variants (norm, positions, FFN, head, head width, loops) are data on
+    :class:`TransformerConfig`; the defaults are GPT-2's."""
 
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, attention_mask=None, cache=None, positions=None):
+    def __call__(self, tokens, attention_mask=None, cache=None, positions=None,
+                 return_loops: bool = False):
+        """With ``cache``: ``(logits, new_cache)``. Without: the logits of
+        the loop each token exits at (the last, at the threshold 1.0), and
+        with ``return_loops`` also ``{"logits": [loops, B, T, V],
+        "exit_p": [loops, B, T]}``: every loop's logits and the exit
+        distribution over the loops."""
         cfg = self.cfg
+        L, U = cfg.n_layers, cfg.loop_steps
+        if cache is not None and cfg.early_exit_threshold < 1.0:
+            raise ValueError(
+                "the cache paths run every loop for every sequence: "
+                f"early_exit_threshold={cfg.early_exit_threshold} < 1.0 would "
+                "need a step whose depth differs by slot"
+            )
         emb = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="wte")
         if positions is None:
             if cache is not None:
@@ -458,38 +596,122 @@ class TransformerLM(nn.Module):
                     positions = lens + jnp.arange(tokens.shape[1])
             else:
                 positions = jnp.arange(tokens.shape[1])
-        pos_emb = nn.Embed(cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype, name="wpe")
-        x = emb(tokens) + pos_emb(positions)
+        x = emb(tokens)
+        if cfg.position == "learned":
+            x = x + nn.Embed(
+                cfg.max_seq_len, cfg.d_model, dtype=cfg.dtype, name="wpe"
+            )(positions)
 
-        new_caches = [] if cache is not None else None
         block_cls = _Block
         if cfg.remat and cache is None:
             # per-block remat on the training forward only: the KV-cache
             # serving path never runs a backward, so checkpointing it would
             # just disable CSE for nothing
             block_cls = nn.remat(_Block, policy=_remat_policy(cfg.remat_policy))
-        for i in range(cfg.n_layers):
-            layer_cache = cache[i] if cache is not None else None
-            x, nc = block_cls(cfg, name=f"h{i}")(x, attention_mask, layer_cache)
+        ln_f = _norm(cfg, "ln_f")
+        if cfg.scan_layers:
+            block = block_cls(cfg, name="layers")
+            kv = common = None
             if cache is not None:
-                new_caches.append(nc)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
-        logits = emb.attend(x.astype(jnp.float32))  # tied embeddings, fp32 head
+                (entry0,) = cache
+                kv = {f: entry0[f] for f in _KV_FIELDS if f in entry0}
+                common = {f: a for f, a in entry0.items() if f not in kv}
+
+            def layer(block, carry, entry):
+                x, kv = carry
+                if kv is None:
+                    return (block(x, attention_mask, None, positions)[0], None), None
+                x, new = block(
+                    x, attention_mask, {**common, **kv, "entry": entry}, positions
+                )
+                return (x, {f: new[f] for f in kv}), new["len"]
+
+            stack = nn.scan(
+                layer,
+                variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True},
+                length=L,
+            )
+        else:
+            blocks = [block_cls(cfg, name=f"h{i}") for i in range(L)]
+            new_caches = [] if cache is not None else None
+        loops = []  # the normed state each loop leaves, the next loop's input
+        for u in range(U):
+            if cfg.scan_layers:
+                (x, kv), lens = stack(block, (x, kv), u * L + jnp.arange(L))
+            else:
+                for i, blk in enumerate(blocks):
+                    x, nc = blk(
+                        x, attention_mask,
+                        cache[u * L + i] if cache is not None else None, positions,
+                    )
+                    if cache is not None:
+                        new_caches.append(nc)
+            x = ln_f(x)
+            loops.append(x)
+        if cfg.scan_layers and cache is not None:
+            # every layer computed the same advanced length; it is set once
+            new_caches = [{**common, **kv, "len": lens[-1]}]
+
+        if not cfg.tie_embeddings:
+            w_head = self.param(
+                "head", nn.initializers.normal(0.02),
+                (cfg.d_model, cfg.vocab_size), jnp.float32,
+            )
+
+        def head(h):
+            if cfg.tie_embeddings:
+                return emb.attend(h.astype(jnp.float32))  # fp32 head
+            # operands as the weights are held, accumulation in float32
+            return jnp.dot(
+                h.astype(w_head.dtype), w_head, preferred_element_type=jnp.float32
+            )
+
+        exit_p = None
+        if U > 1 and (return_loops or cfg.early_exit_threshold < 1.0
+                      or self.is_initializing()):
+            gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate")
+            lam = jax.nn.sigmoid(
+                jnp.stack([gate(h.astype(jnp.float32))[..., 0] for h in loops])
+            )  # [U, B, T]
+            # what no earlier loop took; the last loop takes the rest
+            before = jnp.concatenate(
+                [jnp.ones_like(lam[:1]), jnp.cumprod(1.0 - lam, axis=0)[:-1]]
+            )
+            exit_p = jnp.concatenate([(lam * before)[:-1], before[-1:]], axis=0)
+        h = loops[-1]
+        if cache is None and U > 1 and cfg.early_exit_threshold < 1.0:
+            hit = jnp.cumsum(exit_p, axis=0) >= cfg.early_exit_threshold
+            exit_at = jnp.argmax(hit.at[-1].set(True), axis=0)  # first True
+            h = jnp.take_along_axis(
+                jnp.stack(loops), exit_at[None, ..., None], axis=0
+            )[0]
+        logits = head(h)
         if cache is not None:
             return logits, new_caches
+        if return_loops:
+            if exit_p is None:
+                exit_p = jnp.ones((1, *tokens.shape), jnp.float32)
+            return logits, {
+                "logits": jnp.stack([head(h) for h in loops]), "exit_p": exit_p,
+            }
         return logits
 
     # -- cache ----------------------------------------------------------------
 
     def init_cache(self, batch_size: int, max_len: int) -> list[dict]:
+        """One entry a (loop, layer) pair; with ``scan_layers`` ONE entry
+        whose ``k``/``v`` are stacked [entries, B, max_len, Hk, D]."""
         cfg = self.cfg
+        shape = (batch_size, max_len, cfg.kv_heads, cfg.head_dim)
+        lead, n = ((cfg.cache_entries,), 1) if cfg.scan_layers else ((), cfg.cache_entries)
         return [
             {
-                "k": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), cfg.dtype),
-                "v": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), cfg.dtype),
+                "k": jnp.zeros(lead + shape, cfg.dtype),
+                "v": jnp.zeros(lead + shape, cfg.dtype),
                 "len": jnp.asarray(0, jnp.int32),
             }
-            for _ in range(cfg.n_layers)
+            for _ in range(n)
         ]
 
     def init_paged_cache(
@@ -499,20 +721,25 @@ class TransformerLM(nn.Module):
         of ``block_size`` tokens shared by ``n_slots`` sequences, each
         owning up to ``max_blocks`` table entries. Block 0 is reserved as
         the scratch write target for inactive slots; -1 marks unassigned
-        table entries. Managed by
+        table entries. One entry a (loop, layer) pair; with
+        ``scan_layers`` ONE entry whose pools hold every pair's blocks,
+        pair e's block b at row ``e * n_blocks + b``. Managed by
         :class:`rl_tpu.models.serving.ContinuousBatchingEngine`."""
         cfg = self.cfg
         pool_dtype = jnp.int8 if cfg.kv_int8 else cfg.dtype
+        stacked, n = (cfg.cache_entries, 1) if cfg.scan_layers else (1, cfg.cache_entries)
 
         def layer():
             c = {
                 # HEAD-MAJOR [N, Hk, block, D]: the Pallas paged-decode
                 # kernel views the pool as [N*Hk, block, D] without a copy
                 "pool_k": jnp.zeros(
-                    (n_blocks, cfg.kv_heads, block_size, cfg.head_dim), pool_dtype
+                    (stacked * n_blocks, cfg.kv_heads, block_size, cfg.head_dim),
+                    pool_dtype,
                 ),
                 "pool_v": jnp.zeros(
-                    (n_blocks, cfg.kv_heads, block_size, cfg.head_dim), pool_dtype
+                    (stacked * n_blocks, cfg.kv_heads, block_size, cfg.head_dim),
+                    pool_dtype,
                 ),
                 "block_table": jnp.full((n_slots, max_blocks), -1, jnp.int32),
                 "len": jnp.zeros((n_slots,), jnp.int32),
@@ -527,7 +754,7 @@ class TransformerLM(nn.Module):
                 c["scale_v"] = init_scales(n_blocks, cfg.kv_heads)
             return c
 
-        return [layer() for _ in range(cfg.n_layers)]
+        return [layer() for _ in range(n)]
 
 
 def param_sharding_rules(params, model_axis: str = "model", expert_axis: str = "expert"):
@@ -541,6 +768,8 @@ def param_sharding_rules(params, model_axis: str = "model", expert_axis: str = "
 
     def rule(path: tuple, x) -> P:
         names = [getattr(p, "key", str(p)) for p in path]
+        if names[0] == "layers":  # scanned stack: a leading [n_layers] axis
+            return P(None, *rule(path[1:], x[0]))
         joined = "/".join(names)
         if "/moe/" in f"/{joined}/":
             if "w1" in names:  # [E, d_model, d_ff]: EP x TP
@@ -556,6 +785,8 @@ def param_sharding_rules(params, model_axis: str = "model", expert_axis: str = "
             or "wkv" in joined
             or "/up/" in joined
             or joined.endswith("up/kernel")
+            or names[-2:] == ["gate", "kernel"]
+            or joined == "head"
         ):
             return P(None, model_axis)
         if "proj" in joined or "down" in joined:

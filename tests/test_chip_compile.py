@@ -8,7 +8,7 @@ does not fit VMEM. Interpret mode shows none of that. Each case compiles
 one kernel at the width its default path runs it (the 110M model: 12
 heads x 64, vocab 32768, ctx 1024, bf16, 16 slots, KV blocks of 16; the
 PER bench: capacity 2**20, batch 256; the paged decode kernel also at the
-benchmark cells' shapes and at a GQA width) and asserts the kernel is in the
+benchmark cells' shapes, 16 x 128 among them, and at a GQA width) and asserts the kernel is in the
 program as a ``tpu_custom_call``.
 
 Nothing runs, so this says nothing about results or speed
@@ -126,6 +126,14 @@ ATTENTION_CASES = {
         [((SLOTS, 1, 32, 128), jnp.bfloat16)]
         + [((N_BLOCKS, 8, BLOCK, 128), jnp.bfloat16)] * 2
         + _TABLE,
+    ),
+    # the looped-decoder cell: 16 heads x 128 on 16 KV heads, 16 slots, one
+    # stacked pool a side (192 entries of 161 blocks), 64-entry tables
+    "paged_flash_decode_looped_cell": (
+        paged_flash_decode,
+        [((16, 1, 16, 128), jnp.bfloat16)]
+        + [((192 * 161, 16, 16, 128), jnp.bfloat16)] * 2
+        + [((16, 64), jnp.int32), ((16,), jnp.int32)],
     ),
     "paged_flash_decode_int8": (
         paged_flash_decode_int8,

@@ -138,9 +138,10 @@ class TestEngine:
             m, params, n_slots=2, block_size=8, n_blocks=2,  # 1 usable block
             prompt_buckets=(16,), greedy=True,
         )
-        eng.submit(np.arange(12), 8)  # needs 2 blocks for prompt+1
-        with pytest.raises(RuntimeError, match="block pool too small"):
-            eng.run()
+        # needs 3 blocks to run to its budget: refused where it is known,
+        # at submit, not after the queue has backed up behind it
+        with pytest.raises(ValueError, match="could never be admitted"):
+            eng.submit(np.arange(12), 8)
 
 
 class TestThroughput:
@@ -224,8 +225,17 @@ class TestAllocatorEdgeCases:
             m, params, n_slots=2, block_size=8, n_blocks=5,  # 4 usable
             prompt_buckets=(16,), greedy=True,
         )
+        # each needs all 4 blocks to reach its budget: admission by the
+        # pool runs them one after the other, and neither ever waits
+        rids = [eng.submit(np.arange(7), 20), eng.submit(np.arange(7), 20)]
+        out = eng.run()
+        assert [len(out[r].tokens) for r in rids] == [20, 20]
+        assert eng.metrics_snapshot()["admissions_deferred_kv"] > 0
+        # the guard behind it stays: blocks taken from under a running
+        # slot raise instead of spinning
         eng.submit(np.arange(7), 20)
-        eng.submit(np.arange(7), 20)
+        eng.step()
+        eng.free_blocks.clear()
         with pytest.raises(RuntimeError, match="stalled"):
             eng.run()
 
