@@ -379,6 +379,10 @@ class ContinuousBatchingEngine:
         self.kv_bytes_per_token = sum(
             c[f].nbytes for c in self.cache for f in ("pool_k", "pool_v")
         ) // (n_blocks * block_size)
+        # kv heads a pool stores side by side in one lane row (1: unpacked)
+        self.kv_heads_per_row = (
+            self.cache[0]["pool_k"].shape[3] // model.cfg.head_dim
+        )
         self.loop_steps = model.cfg.loop_steps
         # host mirrors (the allocator's source of truth)
         self.free_blocks = list(range(1, n_blocks))  # 0 = reserved scratch
@@ -1291,6 +1295,7 @@ class ContinuousBatchingEngine:
             "loop_steps_run": float(counters["loop_steps_run"]),
             "cache_entries": self.cache_entries,
             "kv_bytes_per_token": self.kv_bytes_per_token,
+            "kv_heads_per_row": self.kv_heads_per_row,
         }
         snap["prefill_tokens_computed"] = self.prefill_tokens_computed
         snap["prefill_tokens_cached"] = self.prefill_tokens_cached

@@ -135,16 +135,26 @@ def _flash_interpret(cfg) -> bool:
 def _paged_attention(cfg, q, k, v, cache, active):
     """Attention over a paged KV cache + block-table writes.
 
-    Layout: ``pool_k``/``pool_v`` [n_blocks, Hk, block, D] (HEAD-MAJOR)
+    Layout: ``pool_k``/``pool_v`` [n_blocks, Hk // r, block, r * D]
+    (HEAD-MAJOR, ``r`` kv heads side by side in a lane row so that a row
+    is 128 lanes wide: ``init_paged_cache``; ``r`` is read off the shapes
+    and is 1 for a 128-wide head, an odd head count and int8 pools)
     shared across slots; ``block_table`` [S, max_blocks] int32 (block 0 =
     reserved scratch); ``len`` [S] int32 per-slot lengths. New tokens (q/k/v
-    [S, T, ...]) land at slot-local positions ``len[s] + t``; the read
-    gathers the slot's table blocks in ONE shot and runs a single masked
-    softmax over the assembled range — one gather + two einsums per layer
-    instead of an op chain per block. With
-    ``cfg.flash_decode`` the T=1 read instead runs the Pallas
-    ``paged_flash_decode`` kernel, whose index map reads the block table
-    directly (the pool is read in place, no gather copy at all).
+    [S, T, ...]) land at slot-local positions ``len[s] + t``, written as
+    WHOLE ROWS of the pool seen as [rows, r * D] (a token's K is
+    ``Hk // r`` such rows): a scatter of whole rows keeps the pool in the
+    row-major layout the read kernel is held to, where a scatter of
+    ``[Hk, D]`` windows of the 4-d pool makes XLA lay the block axis
+    outside the heads and relay the whole pool before every kernel call.
+    The read gathers the slot's table blocks in ONE shot (unpacking the
+    gathered blocks, never a pool, to [.., Hk, block, D]) and runs a
+    single masked softmax over the assembled range — one gather + two
+    einsums per layer instead of an op chain per block. The T=1 read
+    instead runs the Pallas ``paged_flash_decode`` kernel where the
+    registry selects it (or ``cfg.flash_decode`` forces it), whose index
+    map reads the block table directly (the pool is read in place, packed
+    as it lies, no gather copy at all).
     """
     pool_k, pool_v = cache["pool_k"], cache["pool_v"]
     table, lens = cache["block_table"], cache["len"]
@@ -152,7 +162,7 @@ def _paged_attention(cfg, q, k, v, cache, active):
     scale_k = cache.get("scale_k")
     scale_v = cache.get("scale_v")
     S, T = q.shape[0], q.shape[1]
-    n_blocks, block = pool_k.shape[0], pool_k.shape[2]
+    n_blocks, block, lanes = pool_k.shape[0], pool_k.shape[2], pool_k.shape[3]
     max_blocks = table.shape[1]
     # stacked pools (cfg.scan_layers): this call is cache entry ``entry``,
     # whose blocks are rows [base, base + n_blocks) of the pools; its block
@@ -186,9 +196,9 @@ def _paged_attention(cfg, q, k, v, cache, active):
     blk_global = jnp.where(active_t & (blk_slot < max_blocks), blk_global, 0)
     flat_blk = blk_global.reshape(-1) + base
     flat_off = off.reshape(-1)
-    # pools are HEAD-MAJOR [N, Hk, block, D] (the Pallas kernel views them
-    # as [N*Hk, block, D] for free — Mosaic needs (block, D) last dims);
-    # separated advanced indices put the gather dim first: value [M, Hk, D]
+    # pools are HEAD-MAJOR [N, Hp, block, lanes] (the Pallas kernel views
+    # them as [N*Hp, block, lanes] for free — Mosaic needs the last two)
+    Hp = pool_k.shape[1]
     if int8:
         from ..kernels.kvcache import quantize_block_write
 
@@ -198,29 +208,18 @@ def _paged_attention(cfg, q, k, v, cache, active):
         pool_v, scale_v = quantize_block_write(
             pool_v, scale_v, flat_blk, flat_off, v.reshape(S * T, *v.shape[2:])
         )
-    elif entry is not None:
-        # a token's K (or V) is kv_heads rows of the pool seen as
-        # [N * Hk * block, D]. Written as whole rows of that view, the
-        # scatter wants the row-major layout the read kernel is held to;
-        # written as [Hk, D] windows of the 4-d pool it wants the block
-        # axis outside the heads, and XLA then relays out the whole pool
-        # before every kernel call
-        Hk, D = pool_k.shape[1], pool_k.shape[3]
-        rows = (flat_blk[:, None] * Hk + jnp.arange(Hk)[None, :]) * block
-        rows = (rows + flat_off[:, None]).reshape(-1)
-        pool_k = pool_k.reshape(-1, D).at[rows].set(
-            k.reshape(-1, D), mode="drop"
-        ).reshape(pool_k.shape)
-        pool_v = pool_v.reshape(-1, D).at[rows].set(
-            v.reshape(-1, D), mode="drop"
-        ).reshape(pool_v.shape)
     else:
-        pool_k = pool_k.at[flat_blk, :, flat_off].set(
-            k.reshape(S * T, *k.shape[2:]), mode="drop"
-        )
-        pool_v = pool_v.at[flat_blk, :, flat_off].set(
-            v.reshape(S * T, *v.shape[2:]), mode="drop"
-        )
+        # a token's K (or V) is Hp whole rows of the pool seen as
+        # [N * Hp * block, lanes]: its kv heads r*j .. r*j + r - 1 are
+        # contiguous in k and fill row j's lanes as they come
+        rows = (flat_blk[:, None] * Hp + jnp.arange(Hp)[None, :]) * block
+        rows = (rows + flat_off[:, None]).reshape(-1)
+        pool_k = pool_k.reshape(-1, lanes).at[rows].set(
+            k.reshape(-1, lanes), mode="drop"
+        ).reshape(pool_k.shape)
+        pool_v = pool_v.reshape(-1, lanes).at[rows].set(
+            v.reshape(-1, lanes), mode="drop"
+        ).reshape(pool_v.shape)
 
     # -- read: Pallas paged-decode kernel or the XLA block loop ---------------
     # kernel selection is registry-driven (rl_tpu.kernels.registry —
@@ -262,13 +261,16 @@ def _paged_attention(cfg, q, k, v, cache, active):
     # table entries unassigned) softmax over a uniform -1e9 score row and
     # produce finite garbage; their outputs are never consumed (the
     # engine discards inactive slots' tokens).
-    Hk = pool_k.shape[1]
+    from ..ops.attention import unpack_kv_heads
+
+    Hk = cfg.kv_heads
+    r = Hk // Hp  # kv heads a pool row holds
     rep = cfg.n_heads // cfg.kv_heads
     scale = cfg.head_dim**-0.5
     L = max_blocks * block
     safe_table = jnp.clip(table, 0, n_blocks - 1) + base  # -1 (unassigned) -> scratch
-    k_all = pool_k[safe_table]  # [S, max_blocks, Hk, block, D]
-    v_all = pool_v[safe_table]
+    k_all = unpack_kv_heads(pool_k[safe_table], r)  # [S, max_blocks, Hk, block, D]
+    v_all = unpack_kv_heads(pool_v[safe_table], r)
     if int8:
         from ..kernels.kvcache import dequantize
 
@@ -724,23 +726,31 @@ class TransformerLM(nn.Module):
         table entries. One entry a (loop, layer) pair; with
         ``scan_layers`` ONE entry whose pools hold every pair's blocks,
         pair e's block b at row ``e * n_blocks + b``. Managed by
-        :class:`rl_tpu.models.serving.ContinuousBatchingEngine`."""
+        :class:`rl_tpu.models.serving.ContinuousBatchingEngine`.
+
+        A pool is ``[N, Hk // r, block, r * D]``: ``r`` kv heads side by
+        side in one lane row, with ``r = 128 // D`` where that makes whole
+        128-lane rows of a bf16/f32 pool, else 1
+        (:func:`rl_tpu.ops.attention.paged_heads_per_row`, which says why:
+        the device's default layout of a pool with a minor dimension under
+        128 wide is not the row-major one the decode kernel reads). 16
+        heads x 64 give ``[N, 8, block, 128]``; 16 x 128, 3 x 64 and every
+        int8 pool keep ``[N, Hk, block, D]``. Block rows stay axis 0, so
+        whoever moves blocks (copy-on-write, hand-off) need not know."""
+        from ..ops.attention import paged_heads_per_row
+
         cfg = self.cfg
         pool_dtype = jnp.int8 if cfg.kv_int8 else cfg.dtype
         stacked, n = (cfg.cache_entries, 1) if cfg.scan_layers else (1, cfg.cache_entries)
+        r = paged_heads_per_row(cfg.kv_heads, cfg.head_dim, pool_dtype)
+        # HEAD-MAJOR: the Pallas paged-decode kernel views the pool as
+        # [N * Hk/r, block, r*D] without a copy
+        shape = (stacked * n_blocks, cfg.kv_heads // r, block_size, r * cfg.head_dim)
 
         def layer():
             c = {
-                # HEAD-MAJOR [N, Hk, block, D]: the Pallas paged-decode
-                # kernel views the pool as [N*Hk, block, D] without a copy
-                "pool_k": jnp.zeros(
-                    (stacked * n_blocks, cfg.kv_heads, block_size, cfg.head_dim),
-                    pool_dtype,
-                ),
-                "pool_v": jnp.zeros(
-                    (stacked * n_blocks, cfg.kv_heads, block_size, cfg.head_dim),
-                    pool_dtype,
-                ),
+                "pool_k": jnp.zeros(shape, pool_dtype),
+                "pool_v": jnp.zeros(shape, pool_dtype),
                 "block_table": jnp.full((n_slots, max_blocks), -1, jnp.int32),
                 "len": jnp.zeros((n_slots,), jnp.int32),
                 "active": jnp.zeros((n_slots,), bool),

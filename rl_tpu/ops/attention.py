@@ -710,11 +710,47 @@ _PAGED_TILE_BYTES = 4 << 20
 _PAGED_STEP_KEYS = 128
 
 
+def paged_heads_per_row(n_kv: int, D: int, dtype) -> int:
+    """KV heads a paged pool stores side by side in one lane row: ``r``.
+
+    A ``[N, Hk, block, D]`` pool with ``D < 128`` does not lie on the
+    device as the decode kernel reads it. The TPU's default layout of an
+    array whose minor dimension is under 128 lanes wide moves another
+    dimension minor-most (at ``[2049, 16, 16, 64]`` bf16 the block index:
+    ``{0,3,2,1}``) rather than pad every row to 128 lanes, while a Pallas
+    operand is held to row-major; so every program that calls the kernel
+    relays each pool whole on the way in and again on the way out, and
+    the row-major copy the kernel reads is lane-padded to ``128 / D``
+    times the pool's bytes. Stored as ``[N, Hk // r, block, r * D]`` with
+    ``r = 128 // D`` (kv heads ``r*j .. r*j + r - 1`` in one 128-lane row)
+    the pool's default layout IS row-major, and nothing is relaid or
+    padded. ``r`` is 1 (the pool as it was) when the rows are already
+    128 wide or wider, when ``D`` does not divide 128, when the kv heads
+    do not come in whole rows, and for int8 pools, whose scales and
+    kernel are per (block, kv head).
+    """
+    if D >= 128 or 128 % D or jnp.dtype(dtype) == jnp.int8:
+        return 1
+    r = 128 // D
+    return r if n_kv % r == 0 else 1
+
+
+def unpack_kv_heads(x: jax.Array, r: int) -> jax.Array:
+    """Packed pool blocks ``[..., Hk // r, block, r * D]`` as
+    ``[..., Hk, block, D]``: lanes ``i*D .. (i+1)*D`` of row j are kv
+    head ``r*j + i``. A copy: for gathered blocks, never a whole pool."""
+    *lead, Hp, block, W = x.shape
+    x = x.reshape(*lead, Hp, block, r, W // r)
+    return jnp.moveaxis(x, -2, -3).reshape(*lead, Hp * r, block, W // r)
+
+
 def _paged_pages(max_blocks, block_k, n_kv, D, itemsize):
     """Table entries one paged-decode step takes: enough for
     ``_PAGED_STEP_KEYS`` keys, halved until the double-buffered K and V
-    blocks fit ``_PAGED_TILE_BYTES`` (VMEM pads the head width to 128
-    lanes), and never more than the table has."""
+    blocks fit ``_PAGED_TILE_BYTES``, and never more than the table has.
+    ``n_kv`` and ``D`` are the pool's rows and lane width as it is stored
+    (packed: ``Hk // r`` and ``r * D``); only a pool left under 128 lanes
+    wide is padded to them in VMEM."""
     pages = min(max(1, -(-_PAGED_STEP_KEYS // block_k)), max_blocks)
     entry = 2 * 2 * n_kv * block_k * max(D, 128) * itemsize
     while pages > 1 and pages * entry > _PAGED_TILE_BYTES:
@@ -803,11 +839,14 @@ def paged_flash_decode(
 
     Args:
         q: [S, 1, H, D] — one query per sequence slot.
-        pool_k, pool_v: [N, Hk, block, D] HEAD-MAJOR shared block pools
-            (``Hk`` may divide H — GQA), so one table entry is one
-            contiguous ``[Hk, block, D]`` run; viewed as [N*Hk, block, D]
-            (the Mosaic block dims are (block, D)). Block 0 is reserved
-            scratch (never attended).
+        pool_k, pool_v: [N, Hk // r, block, r * D] HEAD-MAJOR shared block
+            pools, ``r`` kv heads side by side in a lane row
+            (:func:`paged_heads_per_row`; ``r`` is read off the shapes,
+            the pool's lane width over ``q``'s, and is 1 for the plain
+            ``[N, Hk, block, D]``). ``Hk`` may divide H (GQA). One table
+            entry is one contiguous ``[Hk // r, block, r * D]`` run;
+            viewed as [N*Hk/r, block, r*D] (the Mosaic block dims are the
+            last two). Block 0 is reserved scratch (never attended).
         block_table: [S, max_blocks] int32 — per-slot pool indices;
             -1 = unassigned.
         attend_lens: [S] int32 — attendable positions per slot (for the
@@ -819,27 +858,46 @@ def paged_flash_decode(
     to them; the grid's length is dynamic, the sum over slots of
     ``cdiv(attend_lens, pages * block)``, so there is no contiguous
     per-slot copy and no fetch or compute past a slot's length. ``pages``
-    follows from ``block``, ``D``, ``Hk`` and the pool's dtype
+    follows from ``block``, the stored row shape and the pool's dtype
     (:func:`_paged_pages`).
+
+    A packed pool is, to the kernel, a pool of ``Hk // r`` heads of width
+    ``r * D``, and the kernel is called as it is. The query is made
+    BLOCK-DIAGONAL: packed head j gets ``r * group`` query rows, those of
+    kv head ``r*j + i`` holding their ``D`` values in lanes ``i*D ..
+    (i+1)*D`` and zeros elsewhere. A row's score against a packed key row
+    is then its own head's score plus exact zeros, its softmax is its own
+    head's, and lanes ``i*D .. (i+1)*D`` of its output row are its head's
+    output (the other lanes, the same weights on the neighbours' values,
+    are dropped). The MXU contracts 128 lanes either way.
     """
     from jax.experimental.pallas import tpu as pltpu
 
     S, Tq, H, D = q.shape
     if Tq != 1:
         raise ValueError(f"paged_flash_decode is the T=1 step; got T={Tq}")
-    N, Hk, block_k, _ = pool_k.shape
+    N, Hp, block_k, W = pool_k.shape  # Hp rows of r kv heads, W = r * D lanes
+    if W % D:
+        raise ValueError(f"pool rows ({W} wide) must hold whole heads of {D}")
+    r = W // D
+    Hk = Hp * r
     if H % Hk:
         raise ValueError(f"q heads ({H}) must be a multiple of kv heads ({Hk})")
     group = H // Hk
     scale = scale if scale is not None else D**-0.5
     pages = _paged_pages(
-        block_table.shape[1], block_k, Hk, D, pool_k.dtype.itemsize
+        block_table.shape[1], block_k, Hp, W, pool_k.dtype.itemsize
     )
 
-    # [S, 1, H, D] -> [S*Hk, Gp, D]: a kv head's query heads share its K/V
-    # as the rows of one product (sublane-padded to a multiple of 8)
-    q_b = (q * scale).reshape(S * Hk, group, D)
-    q_b = jnp.pad(q_b, ((0, 0), (0, -group % 8), (0, 0)))
+    # [S, 1, H, D] -> [S*Hp, Gp, W]: the query heads of a pool row's kv
+    # heads share its K/V as the rows of one product (sublane-padded to a
+    # multiple of 8), each in its own head's lanes (block-diagonal)
+    q_b = (q * scale).reshape(S * Hp, r, group, 1, D)
+    if r > 1:
+        own = jnp.eye(r, dtype=bool)[:, None, :, None]  # [r, 1, r, 1]
+        q_b = jnp.where(own, q_b, 0)  # [.., r, group, r, D]
+    q_b = q_b.reshape(S * Hp, r * group, W)
+    q_b = jnp.pad(q_b, ((0, 0), (0, -(r * group) % 8), (0, 0)))
     rows = q_b.shape[1]
     lens = jnp.asarray(attend_lens, jnp.int32).reshape(S)
     # the table as the index maps read it: 0 (scratch) for every entry
@@ -866,9 +924,9 @@ def paged_flash_decode(
     # every step, and two write-backs of one block in flight are unordered.
     last = jnp.any(ends[:, None] == w[None, :] + 1, axis=0)
     row_of = jnp.where(last, slot_of, S)
-    # head-major pool -> [N*Hk, block, D] (a reshape, not a copy)
-    k_flat = pool_k.reshape(N * Hk, block_k, D)
-    v_flat = pool_v.reshape(N * Hk, block_k, D)
+    # head-major pool -> [N*Hp, block, W] (a reshape, not a copy)
+    k_flat = pool_k.reshape(N * Hp, block_k, W)
+    v_flat = pool_v.reshape(N * Hp, block_k, W)
 
     def q_index(w, table_ref, len_ref, slot_ref, chunk_ref, row_ref):
         return (slot_ref[w], 0, 0)
@@ -879,26 +937,29 @@ def paged_flash_decode(
     def kv_spec(i):
         def index(w, table_ref, len_ref, slot_ref, chunk_ref, row_ref):
             entry = chunk_ref[w] * pages + i
-            return (table_ref[slot_ref[w], entry], 0, 0)  # in units of Hk rows
+            return (table_ref[slot_ref[w], entry], 0, 0)  # in units of Hp rows
 
-        return pl.BlockSpec((Hk, block_k, D), index)
+        return pl.BlockSpec((Hp, block_k, W), index)
 
     kv_specs = [kv_spec(i) for i in range(pages)]
     kernel = functools.partial(_paged_decode_kernel, block_k=block_k, pages=pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(ends[-1],),
-        in_specs=[pl.BlockSpec((Hk, rows, D), q_index), *kv_specs, *kv_specs],
-        out_specs=pl.BlockSpec((Hk, rows, D), o_index),
+        in_specs=[pl.BlockSpec((Hp, rows, W), q_index), *kv_specs, *kv_specs],
+        out_specs=pl.BlockSpec((Hp, rows, W), o_index),
         scratch_shapes=[
-            _scratch((Hk, rows, 1)), _scratch((Hk, rows, 1)), _scratch((Hk, rows, D))
+            _scratch((Hp, rows, 1)), _scratch((Hp, rows, 1)), _scratch((Hp, rows, W))
         ],
     )
     out = pl.pallas_call(
         kernel,
         name="_paged_decode_kernel",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(((S + 1) * Hk, rows, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(((S + 1) * Hp, rows, W), q.dtype),
         interpret=interpret,
     )(table, lens, slot_of, chunk_of, row_of, q_b, *[k_flat] * pages, *[v_flat] * pages)
-    return out[: S * Hk, :group].reshape(S, 1, H, D)
+    # a query row's own lanes: row block i, lane block i
+    out = out[: S * Hp, : r * group].reshape(S, Hp, r, group, r, D)
+    out = jnp.stack([out[:, :, i, :, i] for i in range(r)], axis=2)
+    return out.reshape(S, 1, H, D)

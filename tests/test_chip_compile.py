@@ -11,12 +11,19 @@ PER bench: capacity 2**20, batch 256; the paged decode kernel also at the
 benchmark cells' shapes, 16 x 128 among them, and at a GQA width) and asserts the kernel is in the
 program as a ``tpu_custom_call``.
 
+The paged cache's programs are also compiled whole (the row write, then
+the kernel or the gather read, as ``_paged_attention`` makes them) to see
+how the compiler lays the KV pools out: row-major as the kernel reads
+them, with no whole-pool ``copy`` but the undonated output's.
+
 Nothing runs, so this says nothing about results or speed
 (``chip_smoke.py`` on a chip does that); a pass here is not a chip run.
 """
 
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
@@ -28,6 +35,7 @@ from rl_tpu.kernels import registry as kreg
 from rl_tpu.kernels.paged_attention import paged_flash_decode_int8
 from rl_tpu.kernels.sampling import fused_sample
 from rl_tpu.kernels.sumtree import sumtree_update
+from rl_tpu.models.transformer import TransformerConfig, TransformerLM, _paged_attention
 from rl_tpu.ops.attention import flash_attention, flash_decode, paged_flash_decode
 
 # the 110M serving/GRPO widths
@@ -120,6 +128,26 @@ ATTENTION_CASES = {
         + [((2049, 16, 16, 64), jnp.bfloat16)] * 2
         + [((32, 64), jnp.int32), ((32,), jnp.int32)],
     ),
+    # the same two as the cells' pools are stored: two heads to a lane row
+    "paged_flash_decode_grpo_cell_packed": (
+        paged_flash_decode,
+        [((8, 1, 16, 64), jnp.bfloat16)]
+        + [((513, 8, 16, 128), jnp.bfloat16)] * 2
+        + [((8, 64), jnp.int32), ((8,), jnp.int32)],
+    ),
+    "paged_flash_decode_rollout_cell_packed": (
+        paged_flash_decode,
+        [((32, 1, 16, 64), jnp.bfloat16)]
+        + [((2049, 8, 16, 128), jnp.bfloat16)] * 2
+        + [((32, 64), jnp.int32), ((32,), jnp.int32)],
+    ),
+    # the 110M widths packed: 12 heads x 64 as 6 rows of 128
+    "paged_flash_decode_packed": (
+        paged_flash_decode,
+        [((SLOTS, 1, H, D), jnp.bfloat16)]
+        + [((N_BLOCKS, H // 2, BLOCK, 2 * D), jnp.bfloat16)] * 2
+        + _TABLE,
+    ),
     # a Queue B width: GQA (32 query heads on 8), head width 128
     "paged_flash_decode_gqa_d128": (
         paged_flash_decode,
@@ -149,6 +177,88 @@ ATTENTION_CASES = {
 def test_attention_kernel_compiles_for_v5e(chip, name):
     fn, avals = ATTENTION_CASES[name]
     assert "tpu_custom_call" in _compile(fn, chip, *avals)
+
+
+# the benchmark's cells: heads x width, blocks a pool, slots (or admitted
+# rows), new tokens a row, decode steps a chunk, cache entries stacked in
+# one pool. gpt2-medium stores two 64-wide heads to a pool row; the looped
+# decoder's 128-wide heads lie one to a row in one stacked pool a side
+POOL_PROGRAMS = {
+    "gpt2_grpo_decode_chunk": dict(H=16, D=64, n_blocks=513, S=8, T=1, steps=2),
+    "gpt2_rollout_decode_chunk": dict(H=16, D=64, n_blocks=2049, S=32, T=1, steps=4),
+    "gpt2_grpo_prefill": dict(H=16, D=64, n_blocks=513, S=8, T=64),
+    "gpt2_rollout_prefill": dict(H=16, D=64, n_blocks=2049, S=4, T=128),
+    "ouro_decode_chunk": dict(H=16, D=128, n_blocks=161, S=16, T=1, steps=2, entries=192),
+    "ouro_prefill": dict(H=16, D=128, n_blocks=161, S=2, T=64, entries=192),
+}
+
+
+def _whole_copies(hlo, shape):
+    """{computation: its ``copy`` ops as large as an array of ``shape``},
+    the entry computation under "ENTRY" (the async form counts once, at
+    its start). A loop body or a fusion is a computation of its own."""
+    n, found, comp = math.prod(shape), {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = "ENTRY" if head.group(1) else head.group(2)
+            continue
+        op = re.search(r"= \(?\w+\[([\d,]+)\]\S* .*?\b(copy|copy-start)\(", line)
+        if op and math.prod(map(int, op.group(1).split(","))) == n:
+            found[comp] = found.get(comp, 0) + 1
+    return found
+
+
+@pytest.mark.parametrize("name", list(POOL_PROGRAMS))
+def test_kv_pools_lie_as_the_kernel_reads_them(chip, on_tpu, name):
+    """A decode chunk (``lax.scan`` of row write + ``paged_flash_decode``)
+    and a prefill step (row write + gather read), pools in and out
+    undonated as the engine's programs have them: the pools enter
+    row-major, nothing in a loop copies one whole, and the entry
+    computation copies each at most once (the output it may not alias)."""
+    c = POOL_PROGRAMS[name]
+    H, D, S, T, entries = c["H"], c["D"], c["S"], c["T"], c.get("entries", 1)
+    cfg = TransformerConfig(
+        vocab_size=128, d_model=H * D, n_layers=entries, n_heads=H, d_head=D,
+        d_ff=128, max_seq_len=1024, dtype=jnp.bfloat16, scan_layers=entries > 1,
+    )
+    pool = jax.eval_shape(
+        lambda: TransformerLM(cfg).init_paged_cache(S, c["n_blocks"], BLOCK, MAX_BLOCKS)
+    )[0]["pool_k"].shape
+
+    def step(pools, table, lens, q, k, v):
+        cache = dict(pool_k=pools[0], pool_v=pools[1], block_table=table, len=lens)
+        if entries > 1:
+            cache["entry"] = jnp.int32(entries // 2)
+        o, cache = _paged_attention(cfg, q, k, v, cache, None)
+        return o, (cache["pool_k"], cache["pool_v"]), cache["len"]
+
+    def program(pool_k, pool_v, table, lens, q, k, v):
+        if T > 1:
+            return step((pool_k, pool_v), table, lens, q, k, v)[:2]
+
+        def body(carry, _):
+            o, pools, lens = step(*carry, q, k, v)
+            return (pools, table, lens), o
+
+        (pools, _, _), o = jax.lax.scan(
+            body, ((pool_k, pool_v), table, lens), None, length=c["steps"]
+        )
+        return o, pools
+
+    hlo = _compile(
+        program, chip,
+        *[(pool, jnp.bfloat16)] * 2,
+        ((S, MAX_BLOCKS), jnp.int32), ((S,), jnp.int32),
+        *[((S, T, H, D), jnp.bfloat16)] * 3,
+    )
+    assert ("tpu_custom_call" in hlo) == (T == 1)
+    dims = ",".join(map(str, pool))
+    entry = hlo[hlo.index("\nENTRY "):]
+    layouts = re.findall(rf"bf16\[{dims}\]\{{([\d,]+)[^}}]*\}} parameter\(", entry)
+    assert layouts == ["3,2,1,0"] * 2, layouts
+    copies = _whole_copies(hlo, pool)
+    assert set(copies) <= {"ENTRY"} and copies.get("ENTRY", 0) <= 2, copies
 
 
 @pytest.mark.parametrize(

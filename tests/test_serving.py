@@ -36,10 +36,15 @@ def small_model(**kw):
     return m, params
 
 
+# 4 heads x 64 (and 2 kv heads x 64): two kv heads to a 128-lane pool row
+_WIDTHS = {"d16": {}, "d64_two_a_row": dict(d_head=64)}
+
+
 class TestPagedAttention:
+    @pytest.mark.parametrize("width", list(_WIDTHS))
     @pytest.mark.parametrize("gqa", [False, True])
-    def test_prefill_and_decode_match_dense(self, gqa):
-        m, params = small_model(n_kv_heads=2 if gqa else None)
+    def test_prefill_and_decode_match_dense(self, gqa, width):
+        m, params = small_model(n_kv_heads=2 if gqa else None, **_WIDTHS[width])
         toks = jax.random.randint(KEY, (3, 10), 0, 97)
         S, block, nb, maxb = 3, 4, 16, 8
         cache = m.init_paged_cache(S, nb, block, maxb)
@@ -49,6 +54,10 @@ class TestPagedAttention:
         for layer in cache:
             layer["block_table"] = jnp.asarray(table)
             layer["active"] = jnp.ones((S,), bool)
+        r = 2 if width == "d64_two_a_row" else 1
+        assert cache[0]["pool_k"].shape == (
+            nb, m.cfg.kv_heads // r, block, r * m.cfg.head_dim
+        )
         lg, cache = m.apply({"params": params}, toks, cache=cache)
         ref = m.apply({"params": params}, toks)
         assert float(jnp.abs(lg - ref).max()) < 1e-3
@@ -62,10 +71,11 @@ class TestPagedAttention:
             err = float(jnp.abs(lgt[:, 0] - ref_full[:, 10 + t]).max())
             assert err < 1e-3, (t, err)
 
-    def test_ragged_bucketed_prefill(self):
+    @pytest.mark.parametrize("width", list(_WIDTHS))
+    def test_ragged_bucketed_prefill(self, width):
         """Token-level active masks: padded prompts of different lengths
         in ONE prefill call, each matching its unpadded oracle."""
-        m, params = small_model()
+        m, params = small_model(**_WIDTHS[width])
         toks = jax.random.randint(KEY, (3, 10), 0, 97)
         lens = [4, 7, 10]
         S, block, nb, maxb = 3, 4, 16, 8
@@ -85,9 +95,115 @@ class TestPagedAttention:
             assert int(cache[0]["len"][s]) == L
 
 
+def _greedy_full_forward(m, params, prompt, n):
+    """What the full (cache-free prompt, dense-cache decode) forward
+    generates greedily: the engine-independent answer."""
+    g = generate(
+        m, params, jnp.asarray(prompt)[None], jnp.ones((1, len(prompt))),
+        jax.random.key(9), max_new_tokens=n, greedy=True, eos_id=None,
+    )
+    return np.asarray(g.response_tokens[0])
+
+
+# engine paths that touch the pools, each at two kv heads to a row: the
+# prefill's row scatter and gather read, the decode chunk through the
+# gather read and through the kernel, the prefix cache's copy-on-write
+# fork, and the KV hand-off between two engines
+_PACKED_ENGINE_PATHS = {
+    "prefill_and_chunked_decode": dict(engine=dict(decode_chunk=4)),
+    "prefill_and_chunked_decode_kernel": dict(
+        model=dict(flash_decode=True, flash_interpret=True),
+        engine=dict(decode_chunk=4),
+    ),
+    "copy_on_write": dict(engine=dict(prefix_cache=True, block_size=4)),
+    "hand_off": dict(engine=dict(kv_handoff=True), hand_off=True),
+    # one pool a side for both layers, carried through the layer scan
+    "stacked_pools_kernel": dict(
+        model=dict(scan_layers=True, flash_decode=True, flash_interpret=True),
+        engine=dict(decode_chunk=4), entries=2,
+    ),
+}
+
+
+class TestPackedPoolEngine:
+    @pytest.mark.parametrize("path", list(_PACKED_ENGINE_PATHS))
+    def test_engine_matches_full_forward(self, path):
+        """4 heads x 64 store two to a row; every request's greedy tokens
+        equal the full forward's, whichever way its K/V moved."""
+        c = _PACKED_ENGINE_PATHS[path]
+        m, params = small_model(d_head=64, **c.get("model", {}))
+        plain = TransformerLM(
+            TransformerConfig(**{**m.cfg.__dict__, "flash_decode": False})
+        )
+        kw = dict(
+            n_slots=2, block_size=8, n_blocks=65, prompt_buckets=(16, 32),
+            greedy=True, eos_id=None,
+        )
+        kw.update(c["engine"])
+        eng = ContinuousBatchingEngine(m, params, **kw)
+        snap = eng.metrics_snapshot()
+        assert snap["kv_heads_per_row"] == 2
+        assert eng.cache[0]["pool_k"].shape == (
+            c.get("entries", 1) * 65, 2, kw["block_size"], 128
+        )
+        assert snap["kv_bytes_per_token"] == 2 * 2 * 4 * 64 * 4  # K, V x layers
+        rng = np.random.default_rng(5)
+        shared = rng.integers(1, 97, size=14)  # ends inside a block of 4 and of 8
+        prompts = [
+            np.concatenate([shared, rng.integers(1, 97, size=k)]) for k in (3, 5, 9)
+        ]
+        if c.get("hand_off"):
+            other = ContinuousBatchingEngine(m, params, seed=1, **kw)
+            got = []
+            for pr in prompts:
+                ho = eng.prefill_detached(pr, 10)
+                rid = other.adopt_handoff(ho)
+                got.append(other.run()[rid].tokens)
+        else:
+            rids = [eng.submit(pr, 10) for pr in prompts]
+            out = eng.run()
+            got = [out[r].tokens for r in rids]
+            if kw.get("prefix_cache"):
+                assert eng.metrics_snapshot()["kv_cow_copies_total"] >= 1
+        for pr, toks in zip(prompts, got):
+            np.testing.assert_array_equal(
+                toks, _greedy_full_forward(plain, params, pr, 10)
+            )
+
+    @pytest.mark.parametrize(
+        "name, kw, r",
+        [
+            ("gpt2_medium_16x64", dict(n_heads=16, d_head=64), 2),
+            ("base_12x64", dict(n_heads=12, d_head=64), 2),
+            ("gqa_8_on_4x64", dict(n_heads=8, n_kv_heads=4, d_head=64), 2),
+            ("four_32_wide_a_row", dict(n_heads=4, d_head=32), 4),
+            ("odd_kv_heads_3x64", dict(n_heads=3, d_head=64), 1),
+            ("looped_16x128", dict(n_heads=16, d_head=128), 1),
+            ("gqa_32_on_8x128", dict(n_heads=32, n_kv_heads=8, d_head=128), 1),
+            ("int8_4x64", dict(n_heads=4, d_head=64, kv_int8=True), 1),
+            ("too_few_heads_4x16", dict(n_heads=4), 1),
+            ("stacked_4x64", dict(n_heads=4, d_head=64, scan_layers=True), 2),
+        ],
+    )
+    def test_pool_shape_follows_head_width_count_and_dtype(self, name, kw, r):
+        cfg = TransformerConfig(
+            vocab_size=97, d_model=64, n_layers=2, d_ff=128, max_seq_len=128,
+            dtype=jnp.bfloat16, **kw,
+        )
+        cache = jax.eval_shape(lambda: TransformerLM(cfg).init_paged_cache(2, 9, 8, 4))
+        entries = 2 if cfg.scan_layers else 1
+        assert len(cache) == 2 // entries
+        for c in cache:
+            assert c["pool_k"].shape == c["pool_v"].shape == (
+                entries * 9, cfg.kv_heads // r, 8, r * cfg.head_dim
+            )
+            assert c["pool_k"].dtype == (jnp.int8 if cfg.kv_int8 else jnp.bfloat16)
+
+
 class TestEngine:
-    def test_drain_recycle_and_greedy_equivalence(self):
-        m, params = small_model()
+    @pytest.mark.parametrize("width", list(_WIDTHS))
+    def test_drain_recycle_and_greedy_equivalence(self, width):
+        m, params = small_model(**_WIDTHS[width])
         eng = ContinuousBatchingEngine(
             m, params, n_slots=4, block_size=8, n_blocks=65,
             prompt_buckets=(16, 32), greedy=True,
@@ -291,9 +407,17 @@ _F32 = dict(rtol=1e-4, atol=1e-5)
 # bf16 pools: the kernel rounds only its output to bf16 (scores, softmax
 # state and the PV sum are f32), the oracle not at all
 _BF16 = dict(rtol=2e-2, atol=2e-2)
+# r: kv heads a pool row holds. None = the plain [N, Hk, block, D] pool
+# handed over as it is; a number = what ``paged_heads_per_row`` must give
+# for the case's widths, the pool then stored [N, Hk/r, block, r*D]
 _DECODE_BASE = dict(
     H=4, Hk=4, D=64, block=16, max_blocks=24, pages=8, dtype=jnp.float32,
-    lens=_around, holes=(), scan=False, tol=_F32,
+    lens=_around, holes=(), scan=False, tol=_F32, r=None,
+)
+_HOLES = (
+    [(0, e, -1) for e in range(24)]
+    + [(1, 0, 0), (1, 3, -1), (1, 9, 0)]
+    + [(2, e, -1 if e % 2 else 0) for e in range(8)]
 )
 _DECODE_CASES = {
     "mha_d64_block16": {},
@@ -324,16 +448,43 @@ _DECODE_CASES = {
     # its live range; slot 2: a whole step of holes before live entries
     "unassigned_and_scratch_entries": dict(
         lens=lambda Bk, span: [span + 5, 2 * span + 5, 2 * span + 5],
-        holes=[(0, e, -1) for e in range(24)]
-        + [(1, 0, 0), (1, 3, -1), (1, 9, 0)]
-        + [(2, e, -1 if e % 2 else 0) for e in range(8)],
+        holes=_HOLES,
     ),
     "under_lax_scan": dict(lens=lambda Bk, span: [Bk - 1, span - 1, 1], scan=True),
     "bf16_under_lax_scan": dict(
         Hk=2, lens=lambda Bk, span: [Bk - 1, span - 1, 1], scan=True,
         dtype=jnp.bfloat16, tol=_BF16,
     ),
+    # packed pools: two 64-wide kv heads to a 128-lane row
+    "packed_mha_d64": dict(r=2),
+    "packed_mha12_d64": dict(H=12, Hk=12, r=2),
+    "packed_gqa2_d64": dict(H=8, Hk=4, r=2),
+    "packed_gqa12_d64_rows_over_8": dict(H=24, Hk=2, r=2),
+    "packed_d32_four_a_row": dict(D=32, r=4),
+    "packed_bf16_mha_d64": dict(dtype=jnp.bfloat16, tol=_BF16, r=2),
+    "packed_full_64_entry_table": dict(
+        max_blocks=64, lens=lambda Bk, span: [0, -1, -Bk, 150, 1], r=2
+    ),
+    "packed_unassigned_and_scratch_entries": dict(
+        lens=lambda Bk, span: [span + 5, 2 * span + 5, 2 * span + 5],
+        holes=_HOLES, r=2,
+    ),
+    "packed_bf16_under_lax_scan": dict(
+        H=8, lens=lambda Bk, span: [Bk - 1, span - 1, 1], scan=True,
+        dtype=jnp.bfloat16, tol=_BF16, r=2,
+    ),
+    # widths the rule leaves as they were
+    "odd_heads_d64_stay_one_a_row": dict(H=3, Hk=3, r=1),
+    "d128_stays_one_a_row": dict(D=128, r=1),
 }
+
+
+def _pack(pool, r):
+    """[N, Hk, block, D] -> [N, Hk // r, block, r * D]: kv heads r*j ..
+    r*j + r - 1 side by side in row j's lanes."""
+    N, Hk, Bk, D = pool.shape
+    pool = pool.reshape(N, Hk // r, r, Bk, D)
+    return jnp.moveaxis(pool, 2, 3).reshape(N, Hk // r, Bk, r * D)
 
 
 class TestPagedDecodeKernel:
@@ -379,13 +530,18 @@ class TestPagedDecodeKernel:
         """A grid step takes every head of a slot and ``pages`` table
         entries: lengths around a block and a step boundary, holes in the
         table, GQA, both head widths, block sizes and pool dtypes."""
-        from rl_tpu.ops.attention import _paged_pages, paged_flash_decode
+        from rl_tpu.ops.attention import (
+            _paged_pages, paged_flash_decode, paged_heads_per_row,
+        )
 
         c = {**_DECODE_BASE, **_DECODE_CASES[name]}
         H, Hk, D, Bk, maxb, dtype = (
             c["H"], c["Hk"], c["D"], c["block"], c["max_blocks"], c["dtype"]
         )
-        pages = _paged_pages(maxb, Bk, Hk, D, jnp.dtype(dtype).itemsize)
+        r = c["r"] or 1
+        if c["r"] is not None:
+            assert paged_heads_per_row(Hk, D, dtype) == r
+        pages = _paged_pages(maxb, Bk, Hk // r, r * D, jnp.dtype(dtype).itemsize)
         assert pages == c["pages"]  # the boundary the lengths below straddle
         span = pages * Bk
         lens = [n if n > 0 else maxb * Bk + n for n in c["lens"](Bk, span)]
@@ -405,12 +561,14 @@ class TestPagedDecodeKernel:
             table[s_, e] = val
         q = rng.standard_normal((S, 1, H, D)).astype(np.float32)
         q, pool_k, pool_v = (jnp.asarray(a, dtype) for a in (q, pool_k, pool_v))
+        # what the kernel is handed; the oracle reads the plain pools
+        pk, pv = _pack(pool_k, r), _pack(pool_v, r)
         args = (jnp.asarray(table), jnp.asarray(lens, jnp.int32))
         if c["scan"]:
             # chunked decode: the kernel inside a lax.scan body, lengths
             # growing a token a step
             def body(n, _):
-                o = paged_flash_decode(q, pool_k, pool_v, args[0], n, interpret=True)
+                o = paged_flash_decode(q, pk, pv, args[0], n, interpret=True)
                 return n + 1, o
 
             steps = 3
@@ -419,7 +577,7 @@ class TestPagedDecodeKernel:
             )(args[1])
         else:
             steps = 1
-            outs = paged_flash_decode(q, pool_k, pool_v, *args, interpret=True)[None]
+            outs = paged_flash_decode(q, pk, pv, *args, interpret=True)[None]
         assert outs.dtype == q.dtype
         for t in range(steps):
             want = _paged_oracle(q, pool_k, pool_v, table, np.asarray(lens) + t)
@@ -427,14 +585,35 @@ class TestPagedDecodeKernel:
                 np.asarray(outs[t], np.float32), want, **c["tol"]
             )
 
-    def test_model_decode_path_matches_xla_paged(self):
+    def test_packed_call_is_the_plain_call_bit_for_bit(self):
+        """The block-diagonal query adds exact zeros to each score: the
+        packed pool's result is the plain pool's, to the bit."""
+        from rl_tpu.ops.attention import paged_flash_decode
+
+        S, H, Hk, D, N, Bk, maxb = 4, 8, 4, 64, 33, 16, 8
+        rng = np.random.default_rng(3)
+        pool_k, pool_v = (
+            jnp.asarray(rng.standard_normal((N, Hk, Bk, D)), jnp.float32)
+            for _ in range(2)
+        )
+        q = jnp.asarray(rng.standard_normal((S, 1, H, D)), jnp.float32)
+        table = jnp.asarray(1 + np.arange(S * maxb).reshape(S, maxb), jnp.int32)
+        lens = jnp.asarray([1, 17, 100, 128], jnp.int32)
+        plain = paged_flash_decode(q, pool_k, pool_v, table, lens, interpret=True)
+        packed = paged_flash_decode(
+            q, _pack(pool_k, 2), _pack(pool_v, 2), table, lens, interpret=True
+        )
+        np.testing.assert_array_equal(np.asarray(packed), np.asarray(plain))
+
+    @pytest.mark.parametrize("width", list(_WIDTHS))
+    def test_model_decode_path_matches_xla_paged(self, width):
         """TransformerLM with flash_decode=True routes paged decode steps
         through the kernel; logits must match the XLA paged read."""
         cfg_kw = dict(
             vocab_size=97, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
-            d_ff=128, max_seq_len=64, dtype=jnp.float32,
+            d_ff=128, max_seq_len=64, dtype=jnp.float32, **_WIDTHS[width],
         )
-        m_xla, params = small_model(n_kv_heads=2)
+        m_xla, params = small_model(n_kv_heads=2, **_WIDTHS[width])
         from rl_tpu.models import TransformerConfig, TransformerLM
 
         m_krn = TransformerLM(TransformerConfig(
