@@ -13,7 +13,10 @@ R002 donation-after-use — an argument passed through a
     reuse its buffer for the outputs. Referencing it afterwards in the
     same scope (or re-passing it on the next loop iteration without
     rebinding) reads freed memory — PR 5 fixed a real heap corruption
-    from exactly this.
+    from exactly this. A donating dispatch is a ``jax.jit`` wrapper or a
+    program a registry ``register(..., donate_argnums=...)``-ed, also one
+    handed out by a getter (the serving engine's ``_get_*_prog``); a use
+    in the other arm of the ``if`` that holds the call is not "after".
 
 R003 PRNG key reuse — the same key consumed by two randomness calls
     (or split twice) without an intervening rebind silently correlates
@@ -135,36 +138,91 @@ def _donated_positions(call: ast.Call) -> tuple[tuple, tuple] | None:
     return tuple(sorted(set(nums))), tuple(names)
 
 
-def _collect_donating_callables(m: ModuleIndex) -> dict[str, tuple[tuple, tuple]]:
-    """Map trackable callee names ('f', 'self._update') to donated
-    (argnums, argnames). Module-local: assignments of jit(...) results and
-    @partial(jax.jit, donate_*) decorators."""
-    donors: dict[str, tuple] = {}
-    for node in ast.walk(m.tree):
-        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
-            name = canon(node.value.func, m.aliases)
-            if name in _JIT_NAMES:
-                pos = _donated_positions(node.value)
-                if pos is not None:
-                    for t in node.targets:
-                        for tn in _target_names(t):
-                            donors[tn] = pos
+def _is_program_factory(call: ast.Call, m: ModuleIndex) -> bool:
+    """``jax.jit(...)`` or a program registry's ``<x>.register(...)``
+    (``ProgramRegistry.register`` hands its keywords to ``jax.jit``)."""
+    if canon(call.func, m.aliases) in _JIT_NAMES:
+        return True
+    return isinstance(call.func, ast.Attribute) and call.func.attr == "register"
+
+
+def _scoped_nodes(m: ModuleIndex):
+    """``(scope, node)`` over the module: None for nodes outside every
+    function body (class bodies included), else the qualname of the
+    function whose body holds the node (nested defs are their own)."""
+    stack = list(m.tree.body)
+    while stack:
+        node = stack.pop()
+        yield None, node
+        if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)):
+            stack.extend(ast.iter_child_nodes(node))
+    for fn in _iter_functions(m):
+        for node in _body_nodes(fn):
+            yield fn.qualname, node
+
+
+def _collect_donating_callables(m: ModuleIndex) -> tuple[dict, dict]:
+    """``(donors, getters)``. ``donors`` maps ``(scope, name)`` to donated
+    (argnums, argnames): assignments of ``jit(...)`` / ``register(...)``
+    results and @partial(jax.jit, donate_*) decorators; a plain name
+    bound inside a function is that function's (scope = its qualname),
+    ``self.x``, module-level names and decorated defs are the module's
+    (scope None). ``getters`` maps the call names of functions that
+    RETURN such a program (``self._get_decode_prog``) to its donation:
+    what they hand out is tracked one assignment further, and a direct
+    ``self._get_cow_prog(n)(pools, ...)`` is a donating call."""
+    donors: dict[tuple, tuple] = {}
+    getters: dict[str, tuple] = {}
+
+    def bind(scope, targets, pos):
+        for t in targets:
+            for tn in _target_names(t):
+                donors[(scope if "." not in tn else None, tn)] = pos
+
+    def made(value):
+        if isinstance(value, ast.Call) and _is_program_factory(value, m):
+            return _donated_positions(value)
+        return None
+
+    for scope, node in _scoped_nodes(m):
+        if isinstance(node, ast.Assign):
+            pos = made(node.value)
+            if pos is not None:
+                bind(scope, node.targets, pos)
         elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            for dec in node.decorator_list:
-                if isinstance(dec, ast.Call):
-                    cname = canon(dec.func, m.aliases)
-                    is_jit = cname in _JIT_NAMES
-                    is_partial_jit = (
-                        cname in {"functools.partial", "partial"}
-                        and dec.args
-                        and canon(dec.args[0], m.aliases) in _JIT_NAMES
-                    )
-                    if is_jit or is_partial_jit:
-                        pos = _donated_positions(dec)
-                        if pos is not None:
-                            donors[node.name] = pos
-                            donors[f"self.{node.name}"] = pos
-    return donors
+            pos = _decorated_donation(node, m)
+            if pos is not None:
+                donors[(None, node.name)] = donors[(None, f"self.{node.name}")] = pos
+    for fn in _iter_functions(m):
+        name = getattr(fn.node, "name", None)
+        for node in _body_nodes(fn) if name else ():
+            if isinstance(node, ast.Return) and node.value is not None:
+                pos = made(node.value) or donors.get((fn.qualname, _expr_name(node.value)))
+                if pos is not None:
+                    getters[name] = getters[f"self.{name}"] = pos
+    for scope, node in _scoped_nodes(m):
+        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
+            pos = getters.get(_callee_key(node.value))
+            if pos is not None:
+                bind(scope, node.targets, pos)
+    return donors, getters
+
+
+def _decorated_donation(node: ast.AST, m: ModuleIndex) -> tuple | None:
+    for dec in node.decorator_list:
+        if isinstance(dec, ast.Call):
+            cname = canon(dec.func, m.aliases)
+            is_jit = cname in _JIT_NAMES
+            is_partial_jit = (
+                cname in {"functools.partial", "partial"}
+                and dec.args
+                and canon(dec.args[0], m.aliases) in _JIT_NAMES
+            )
+            if is_jit or is_partial_jit:
+                pos = _donated_positions(dec)
+                if pos is not None:
+                    return pos
+    return None
 
 
 def _expr_name(node: ast.AST) -> str | None:
@@ -204,6 +262,20 @@ def _loads_after(fn: FunctionInfo, name: str, after_line: int) -> list[ast.AST]:
     return out
 
 
+def _exclusive_branches(fn: FunctionInfo, a: int, b: int) -> bool:
+    """Lines ``a`` and ``b`` lie in different arms of one ``if``: control
+    that ran the one never runs the other."""
+    def span(stmts):
+        return (stmts[0].lineno, stmts[-1].end_lineno or stmts[-1].lineno) if stmts else (0, -1)
+
+    for node in _body_nodes(fn):
+        if isinstance(node, ast.If):
+            (b0, b1), (e0, e1) = span(node.body), span(node.orelse)
+            if (b0 <= a <= b1 and e0 <= b <= e1) or (e0 <= a <= e1 and b0 <= b <= b1):
+                return True
+    return False
+
+
 def _enclosing_loops(fn: FunctionInfo, line: int) -> list[ast.AST]:
     loops = []
     for node in ast.walk(fn.node):
@@ -213,60 +285,71 @@ def _enclosing_loops(fn: FunctionInfo, line: int) -> list[ast.AST]:
     return loops
 
 
-def _r002(index: PackageIndex, m: ModuleIndex) -> list[Finding]:
-    donors = _collect_donating_callables(m)
+def donating_calls(m: ModuleIndex):
+    """Every call of a donating program R002 sees in ``m``, as
+    ``(function, call node, callee, (argnums, argnames))``: what the rule
+    then checks, and what a test reads to know a clean result is not an
+    empty one."""
+    donors, getters = _collect_donating_callables(m)
     if not donors:
-        return []
-    out: list[Finding] = []
+        return
     for fn in _iter_functions(m):
         for node in _body_nodes(fn):
             if not isinstance(node, ast.Call):
                 continue
             key = _callee_key(node)
-            if key is None or key not in donors:
-                continue
-            nums, names = donors[key]
-            donated_args: list[tuple[str, ast.AST]] = []
-            for p in nums:
-                if p < len(node.args):
-                    nm = _expr_name(node.args[p])
-                    if nm is not None:
-                        donated_args.append((nm, node.args[p]))
-            for kw in node.keywords:
-                if kw.arg in names:
-                    nm = _expr_name(kw.value)
-                    if nm is not None:
-                        donated_args.append((nm, kw.value))
-            call_end = node.end_lineno or node.lineno
-            for nm, _arg in donated_args:
-                assigns = _assign_lines(fn, nm)
-                # straight-line use after the donating call
-                for use in _loads_after(fn, nm, call_end):
-                    killed = any(node.lineno <= a <= use.lineno for a in assigns)
-                    if not killed:
+            pos = donors.get((fn.qualname, key), donors.get((None, key)))
+            if pos is None and isinstance(node.func, ast.Call):
+                key = _callee_key(node.func)  # self._get_prog(n)(pools, ...)
+                pos = getters.get(key)
+            if pos is not None:
+                yield fn, node, key, pos
+
+
+def _r002(index: PackageIndex, m: ModuleIndex) -> list[Finding]:
+    out: list[Finding] = []
+    for fn, node, key, (nums, names) in donating_calls(m):
+        donated_args: list[tuple[str, ast.AST]] = []
+        for p in nums:
+            if p < len(node.args):
+                nm = _expr_name(node.args[p])
+                if nm is not None:
+                    donated_args.append((nm, node.args[p]))
+        for kw in node.keywords:
+            if kw.arg in names:
+                nm = _expr_name(kw.value)
+                if nm is not None:
+                    donated_args.append((nm, kw.value))
+        call_end = node.end_lineno or node.lineno
+        for nm, _arg in donated_args:
+            assigns = _assign_lines(fn, nm)
+            # straight-line use after the donating call
+            for use in _loads_after(fn, nm, call_end):
+                killed = any(node.lineno <= a <= use.lineno for a in assigns)
+                if not killed and not _exclusive_branches(fn, node.lineno, use.lineno):
+                    out.append(Finding(
+                        rule="R002", file=m.path, line=use.lineno,
+                        qualname=fn.display, snippet=m.snippet(use),
+                        message=(
+                            f"'{nm}' used after being donated to {key} "
+                            f"(donate_argnums={nums or names}) at line {node.lineno}"
+                        ),
+                    ))
+                    break  # one finding per (call, arg)
+            else:
+                # loop-carried: donated every iteration, never rebound
+                for loop in _enclosing_loops(fn, node.lineno):
+                    lo, hi = loop.lineno, loop.end_lineno or loop.lineno
+                    if not any(lo <= a <= hi for a in assigns):
                         out.append(Finding(
-                            rule="R002", file=m.path, line=use.lineno,
-                            qualname=fn.display, snippet=m.snippet(use),
+                            rule="R002", file=m.path, line=node.lineno,
+                            qualname=fn.display, snippet=m.snippet(node),
                             message=(
-                                f"'{nm}' used after being donated to {key} "
-                                f"(donate_argnums={nums or names}) at line {node.lineno}"
+                                f"'{nm}' donated to {key} inside a loop without "
+                                "rebinding — second iteration passes a freed buffer"
                             ),
                         ))
-                        break  # one finding per (call, arg)
-                else:
-                    # loop-carried: donated every iteration, never rebound
-                    for loop in _enclosing_loops(fn, node.lineno):
-                        lo, hi = loop.lineno, loop.end_lineno or loop.lineno
-                        if not any(lo <= a <= hi for a in assigns):
-                            out.append(Finding(
-                                rule="R002", file=m.path, line=node.lineno,
-                                qualname=fn.display, snippet=m.snippet(node),
-                                message=(
-                                    f"'{nm}' donated to {key} inside a loop without "
-                                    "rebinding — second iteration passes a freed buffer"
-                                ),
-                            ))
-                            break
+                        break
     return out
 
 

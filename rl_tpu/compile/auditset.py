@@ -26,7 +26,7 @@ import tempfile
 import traceback
 from typing import Any, Callable, Iterable
 
-__all__ = ["AUDIT_TARGETS", "check_spec_programs", "run_ir_audit"]
+__all__ = ["AUDIT_TARGETS", "check_pool_programs", "check_spec_programs", "run_ir_audit"]
 
 
 def _build_serving() -> None:
@@ -109,6 +109,75 @@ def _build_serving_spec() -> None:
     if eng.spec_dispatches < 1:
         raise RuntimeError("speculative audit build never dispatched a verify")
     check_spec_programs(get_program_registry())
+
+
+# the engine's program families that take the KV pools and return them
+POOL_PROGRAM_FAMILIES = (
+    "serving.decode.k", "serving.sdecode.k", "serving.verify.k",
+    "serving.prefill.", "serving.pprefill.", "serving.sprefill.",
+    "serving.spprefill.", "serving.cowcopy.n",
+)
+
+
+def check_pool_programs(auditor: Any, families: Iterable[str] = ()) -> None:
+    """The pools-are-consumed gate over what ``auditor`` has audited: every
+    engine program that takes the KV pools must declare them donated, and
+    the executable must alias EVERY donated pool to an output (R102 fires
+    only when none is; one pool the compiler declines to alias is a
+    whole-pool copy a call). ``families`` must each have been audited.
+    Raises ``RuntimeError`` (rlint --ir reports it and exits 1)."""
+    seen = set()
+    for rep in auditor._snapshot():
+        family = next((f for f in POOL_PROGRAM_FAMILIES if rep.name.startswith(f)), None)
+        if family is None:
+            continue
+        seen.add(family)
+        if not 0 < rep.donated_declared == rep.donated_honored:
+            raise RuntimeError(
+                f"engine program {rep.name!r} donates {rep.donated_declared} "
+                f"pool(s) and the executable aliases {rep.donated_honored}: "
+                "every call copies a pool whole into a fresh output"
+            )
+    missing = sorted(set(families) - seen)
+    if missing:
+        raise RuntimeError(f"pool program families never audited: {missing}")
+
+
+def _build_serving_pools() -> None:
+    """The two pool-program families the other serving builds do not
+    reach: the partial prefill on the legacy stream (prefix cache, two
+    prompts parting inside a block, so the copy-on-write fork runs too)
+    and the full prefill on the slot stream. Ends with the donation gate
+    over every engine program audited so far (``serving`` and
+    ``serving_spec`` run before it in the whole set)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..analysis.ir import get_ir_auditor
+    from ..models import ContinuousBatchingEngine, TransformerConfig, TransformerLM
+    from .registry import get_program_registry
+
+    cfg = TransformerConfig(
+        vocab_size=97, d_model=32, n_layers=1, n_heads=2, d_ff=64,
+        max_seq_len=64, dtype=jnp.float32,
+    )
+    m = TransformerLM(cfg)
+    params = m.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    kw = dict(n_slots=2, block_size=8, n_blocks=17, prompt_buckets=(16,), greedy=True)
+    eng = ContinuousBatchingEngine(m, params, prefix_cache=True, **kw)
+    for tail in (1, 2):
+        eng.submit(np.concatenate([np.arange(11), [tail]]) % 97, 4)
+        eng.run()
+    if eng.metrics_snapshot()["kv_cow_copies_total"] < 1:
+        raise RuntimeError("pool audit build never forked a block")
+    eng = ContinuousBatchingEngine(m, params, slot_rng=True, **kw)
+    eng.submit(np.arange(5) % 97, 4)
+    eng.run()
+    check_pool_programs(
+        get_program_registry().auditor or get_ir_auditor(),
+        ("serving.pprefill.", "serving.cowcopy.n", "serving.sprefill.", "serving.sdecode.k"),
+    )
 
 
 def _build_serving_kernels() -> None:
@@ -238,6 +307,7 @@ def _build_offpolicy() -> None:
 AUDIT_TARGETS: dict[str, Callable[[], None]] = {
     "serving": _build_serving,
     "serving_spec": _build_serving_spec,
+    "serving_pools": _build_serving_pools,
     "serving_kernels": _build_serving_kernels,
     "anakin": _build_anakin,
     "offpolicy": _build_offpolicy,

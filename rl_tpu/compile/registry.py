@@ -38,6 +38,7 @@ import os
 import threading
 import time
 import weakref
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Iterable
 
 from .metrics import compile_scope, install_compile_listener
@@ -57,6 +58,7 @@ _ENV_NO_IR_AUDIT = "RL_TPU_NO_IR_AUDIT"
 _ENV_PEAK_FLOPS = "RL_TPU_PEAK_FLOPS"
 _ENV_PEAK_BW = "RL_TPU_PEAK_BYTES_PER_S"
 _ATTR_SAMPLE_EVERY = 8
+_WARMUP_THREADS = 4  # signatures an aot_warmup() builds or loads at a time
 
 
 def _attr_worker(q) -> None:
@@ -572,10 +574,19 @@ class ProgramRegistry:
             todo = [p for name in want for p in self._alive(name)]
 
         def work() -> dict:
+            # a few at a time: a store load (read, decompress, hand to the
+            # runtime) and a compile both leave the interpreter lock, and an
+            # engine's ladder is twenty of them
+            jobs = [(prog, sig) for prog in todo for sig in prog.signatures]
             out: dict[str, list] = {}
-            for prog in todo:
-                for sig in prog.signatures:
-                    out.setdefault(prog.name, []).append(prog.warmup(*sig))
+            with ThreadPoolExecutor(
+                max_workers=min(_WARMUP_THREADS, len(jobs)) or 1,
+                thread_name_prefix="aot-warmup",
+            ) as pool:
+                for (prog, _), res in zip(
+                    jobs, pool.map(lambda j: j[0].warmup(*j[1]), jobs)
+                ):
+                    out.setdefault(prog.name, []).append(res)
             return out
 
         if not background:

@@ -447,6 +447,10 @@ class ContinuousBatchingEngine:
         # and the blocks the slots' tables held, summed over decode steps
         self.admissions_deferred_kv = 0
         self.kv_block_steps = 0
+        # program calls that took the pools, and those that consumed them
+        # (the donation engaged): equal wherever the backend donates
+        self.kv_pool_calls = 0
+        self.kv_pool_calls_aliased = 0
         self.completions: dict[str, int] = {"eos": 0, "length": 0}
         # speculative accounting: dispatches that carried drafts, tokens
         # proposed/accepted, and the accept-rate EMA the fleet's lane
@@ -664,6 +668,7 @@ class ContinuousBatchingEngine:
         prog = self._decode_progs[chunk] = self._registry.register(
             f"serving.decode.k{chunk}", fn, fingerprint=self._fingerprint,
             ir_contract=self._ir_contract_decode,
+            donate_argnums=(1,),
         )
         return prog
 
@@ -675,6 +680,7 @@ class ContinuousBatchingEngine:
                 self._prefill_fn,
                 fingerprint=self._fingerprint,
                 ir_contract=self._ir_contract_sample,
+                donate_argnums=(1,),
             )
         return prog
 
@@ -708,6 +714,7 @@ class ContinuousBatchingEngine:
                 self._pprefill_fn,
                 fingerprint=self._fingerprint,
                 ir_contract=self._ir_contract_sample,
+                donate_argnums=(1,),
             )
         return prog
 
@@ -744,18 +751,39 @@ class ContinuousBatchingEngine:
                 f"serving.cowcopy.n{n}", self._cow_copy_fn,
                 fingerprint=self._fingerprint,
                 ir_contract=self._ir_contract,
+                donate_argnums=(0,),
             )
         return prog
 
-    def _dispatch_cow(self, pools, cows):
+    def _dispatch_cow(self, cows):
         """Run the round's COW copies as one fixed-shape program (pair
         count padded up the power-of-two ladder by repeating the last
-        pair — re-copying the same src->dst is idempotent)."""
+        pair — re-copying the same src->dst is idempotent). Takes the
+        cache's pools and leaves its outputs there for the round's
+        partial prefill: cache -> cow -> cache -> prefill -> cache."""
         n = _pow2ceil(len(cows))
         cows = cows + [cows[-1]] * (n - len(cows))
         src = jnp.asarray([c[0] for c in cows], jnp.int32)
         dst = jnp.asarray([c[1] for c in cows], jnp.int32)
-        return self._get_cow_prog(n)(pools, src, dst)
+        pools = _pools_from(self.cache)
+        self._rebind_pools(self._get_cow_prog(n)(pools, src, dst))
+
+    def _rebind_pools(self, new_pools):
+        """Make a program call's output pools the cache. Every engine
+        program that takes the pools is registered with them DONATED, so
+        the arrays that went in are consumed by the call (the output
+        aliases their memory: no copy, no second set of pools) and
+        ``self.cache`` is the only live reference from here on. Nothing
+        may keep a pool across a call; what a caller needs of one it
+        reads out before (``np.asarray`` of a gather). Also the counter
+        that says the donation engages: the input is still the cache's
+        first pool here, and ``is_deleted`` is a host flag, no device
+        read. A backend that declines the donation leaves it False and
+        nothing else differs."""
+        self.kv_pool_calls += 1
+        self.kv_pool_calls_aliased += self.cache[0]["pool_k"].is_deleted()
+        for layer, bufs in zip(self.cache, new_pools):
+            layer.update(zip(_POOL_FIELDS, bufs))
 
     def _sample(self, logits, key):
         """(token, behavior log-prob of that token) per row — ONE source
@@ -802,6 +830,7 @@ class ContinuousBatchingEngine:
                 self._sprefill_fn,
                 fingerprint=self._fingerprint,
                 ir_contract=self._ir_contract_sample,
+                donate_argnums=(1,),
             )
         return prog
 
@@ -827,6 +856,7 @@ class ContinuousBatchingEngine:
                 self._spprefill_fn,
                 fingerprint=self._fingerprint,
                 ir_contract=self._ir_contract_sample,
+                donate_argnums=(1,),
             )
         return prog
 
@@ -887,6 +917,7 @@ class ContinuousBatchingEngine:
         prog = self._sdecode_progs[chunk] = self._registry.register(
             f"serving.sdecode.k{chunk}", fn, fingerprint=self._fingerprint,
             ir_contract=self._ir_contract_decode,
+            donate_argnums=(1,),
         )
         return prog
 
@@ -963,6 +994,7 @@ class ContinuousBatchingEngine:
             # decode kernel never lowers here — only the sampler is owed
             f"serving.verify.k{K}", fn, fingerprint=self._fingerprint,
             ir_contract=self._ir_contract_sample,
+            donate_argnums=(1,),
         )
         return prog
 
@@ -1296,6 +1328,8 @@ class ContinuousBatchingEngine:
             "cache_entries": self.cache_entries,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_heads_per_row": self.kv_heads_per_row,
+            "kv_pool_calls": self.kv_pool_calls,
+            "kv_pool_calls_aliased": self.kv_pool_calls_aliased,
         }
         snap["prefill_tokens_computed"] = self.prefill_tokens_computed
         snap["prefill_tokens_cached"] = self.prefill_tokens_cached
@@ -1404,8 +1438,7 @@ class ContinuousBatchingEngine:
                 self.params, pools, self.dev_table[jnp.asarray(slots)],
                 jnp.asarray(tokens), jnp.asarray(mask), k,
             )
-        for layer, bufs in zip(self.cache, new_pools):
-            layer.update(zip(_POOL_FIELDS, bufs))
+        self._rebind_pools(new_pools)
         self.admissions += 1
         self.prefill_token_slots += pad_a * bucket
         self.prefill_tokens_computed += P
@@ -1701,10 +1734,10 @@ class ContinuousBatchingEngine:
                 self._key, k = jax.random.split(self._key)
             rid_v = np.full(pad_a, -1, np.int32)
             rid_v[:A] = [req.rid for _, req in batch]
+            if cows:
+                self._dispatch_cow(cows)
             pools = _pools_from(self.cache)
             if self._kvmem is not None:
-                if cows:
-                    pools = self._dispatch_cow(pools, cows)
                 start_v = np.zeros(pad_a, np.int32)
                 start_v[:A] = starts
                 if self.slot_rng:
@@ -1732,14 +1765,13 @@ class ContinuousBatchingEngine:
                     jnp.asarray(mask),
                     *tail,
                 )
+            self._rebind_pools(new_pools)
             if self._kvmem is not None:
                 # the round's published blocks are now behind a dispatched
                 # prefill: safe for next round's admissions to share
                 self._kvmem.end_round()
                 self.prefill_tokens_cached += cached
             self.prefill_tokens_computed += computed
-            for layer, bufs in zip(self.cache, new_pools):
-                layer.update(zip(_POOL_FIELDS, bufs))
             self.prefill_token_slots += A * bucket
             with tracer.span("engine.prefill.wait"):
                 tok_host, lp_host = np.asarray(tok), np.asarray(lp)
@@ -1951,8 +1983,7 @@ class ContinuousBatchingEngine:
                         k,
                         self.dev_obs,
                     )
-                for layer, bufs in zip(self.cache, new_pools):
-                    layer.update(zip(_POOL_FIELDS, bufs))
+                self._rebind_pools(new_pools)
                 try:  # start the device->host copy early; the drain just awaits it
                     toks.copy_to_host_async()
                     lps.copy_to_host_async()
@@ -2055,8 +2086,7 @@ class ContinuousBatchingEngine:
                     self._base_key,
                     self.dev_obs,
                 )
-                for layer, bufs in zip(self.cache, new_pools):
-                    layer.update(zip(_POOL_FIELDS, bufs))
+                self._rebind_pools(new_pools)
                 try:
                     toks.copy_to_host_async()
                     lps.copy_to_host_async()
@@ -2272,8 +2302,18 @@ class ContinuousBatchingEngine:
         the RNG stream, and the monotone counters (``_next_rid``,
         completions, token totals) all survive — this is how the fleet
         recycles a crashed replica without paying recompilation, and why a
-        request id never collides across a crash."""
+        request id never collides across a crash. One exception: the
+        programs consume the pools they are handed (donation), so a call
+        that raised after taking them left ``self.cache`` holding deleted
+        arrays; each such pool is made anew here, zeroed, with the shape,
+        dtype and sharding it had — the programs are keyed on those, so
+        nothing recompiles."""
         n = self.n_slots
+        for layer in self.cache:
+            for f in _POOL_FIELDS:
+                a = layer.get(f)
+                if a is not None and a.is_deleted():
+                    layer[f] = jnp.zeros(a.shape, a.dtype, device=a.sharding)
         if self._kvmem is not None:
             # in place: self.free_blocks stays the allocator's list object;
             # the cached tree is dropped (pool contents are unreachable)

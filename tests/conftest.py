@@ -107,6 +107,22 @@ def pytest_sessionfinish(session, exitstatus):
         session.exitstatus = 1
 
 
+@pytest.fixture
+def undonated_programs(monkeypatch):
+    """A function that, once called, makes ``ProgramRegistry.register``
+    drop ``donate_argnums``: the programs as they were before the engine
+    donated its KV pools, for tests that hold the two against each other."""
+    from rl_tpu.compile import ProgramRegistry
+
+    register = ProgramRegistry.register
+
+    def plain(self, name, fn, **kw):
+        kw.pop("donate_argnums", None)
+        return register(self, name, fn, **kw)
+
+    return lambda: monkeypatch.setattr(ProgramRegistry, "register", plain)
+
+
 @pytest.fixture(autouse=True)
 def _hot_path_transfer_guard(request):
     """``@pytest.mark.hot_path_guard``: run the test body under

@@ -193,10 +193,13 @@ POOL_PROGRAMS = {
 }
 
 
-def _whole_copies(hlo, shape):
+def _whole_copies(hlo, shape, prefetches=True):
     """{computation: its ``copy`` ops as large as an array of ``shape``},
     the entry computation under "ENTRY" (the async form counts once, at
-    its start). A loop body or a fusion is a computation of its own."""
+    its start). A loop body or a fusion is a computation of its own.
+    ``prefetches=False`` leaves out a copy into or out of the chip's fast
+    memory (``S(1)`` in a layout: the compiler's own prefetch of an array
+    small enough, not a second copy of it in HBM)."""
     n, found, comp = math.prod(shape), {}, None
     for line in hlo.splitlines():
         head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
@@ -205,7 +208,8 @@ def _whole_copies(hlo, shape):
             continue
         op = re.search(r"= \(?\w+\[([\d,]+)\]\S* .*?\b(copy|copy-start)\(", line)
         if op and math.prod(map(int, op.group(1).split(","))) == n:
-            found[comp] = found.get(comp, 0) + 1
+            if prefetches or "S(1)" not in line[: op.end()]:
+                found[comp] = found.get(comp, 0) + 1
     return found
 
 
@@ -259,6 +263,65 @@ def test_kv_pools_lie_as_the_kernel_reads_them(chip, on_tpu, name):
     assert layouts == ["3,2,1,0"] * 2, layouts
     copies = _whole_copies(hlo, pool)
     assert set(copies) <= {"ENTRY"} and copies.get("ENTRY", 0) <= 2, copies
+
+
+@pytest.mark.parametrize("name", list(POOL_PROGRAMS))
+def test_engine_programs_alias_their_pools(chip, on_tpu, name):
+    """The twin of the test above with the pools DONATED, on the engine's
+    own registered programs (``serving.decode.k*``, ``serving.prefill.*``:
+    whatever ``ContinuousBatchingEngine`` registers them with is what
+    compiles here, at the cells' pool shapes): every pool is an
+    ``input_output_alias`` of the executable and nothing, the entry
+    computation included, copies one whole in HBM. Undonated, each pool
+    has no alias and one such copy. The model is two GPT-2-wide blocks,
+    or the looped stack over one stacked pool a side; only shapes are
+    handed over, the engine itself is built with a pool of 3 blocks."""
+    from rl_tpu.analysis.ir import honored_alias_count
+    from rl_tpu.models.serving import ContinuousBatchingEngine, _pools_from
+
+    c = POOL_PROGRAMS[name]
+    H, D, S, T, entries = c["H"], c["D"], c["S"], c["T"], c.get("entries", 1)
+    depth = dict(n_layers=entries // 4, loop_steps=4, scan_layers=True) if entries > 1 else dict(n_layers=2)
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=H * D, n_heads=H, d_head=D, d_ff=256,
+        max_seq_len=1024, dtype=jnp.bfloat16, **depth,
+    )
+    model = TransformerLM(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree
+        )
+
+    params = on_chip(
+        jax.eval_shape(model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    )
+    eng = ContinuousBatchingEngine(
+        model, params, n_slots=S, block_size=BLOCK, n_blocks=3, max_seq_len=1024,
+        prompt_buckets=(64, 128),
+    )
+    pools = on_chip(jax.eval_shape(
+        lambda: _pools_from(model.init_paged_cache(S, c["n_blocks"], BLOCK, MAX_BLOCKS))
+    ))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    table, vec, flag = (
+        jax.ShapeDtypeStruct(s, d, sharding=chip)
+        for s, d in (((S, MAX_BLOCKS), jnp.int32), ((S,), jnp.int32), ((S,), bool))
+    )
+    if T == 1:
+        prog = eng._get_decode_prog(c["steps"])
+        args = (params, pools, table, vec, flag, vec, vec, flag, key, on_chip(eng.dev_obs))
+    else:
+        prog = eng._get_prefill_prog(S, T)
+        tokens = jax.ShapeDtypeStruct((S, T), jnp.int32, sharding=chip)
+        mask = jax.ShapeDtypeStruct((S, T), bool, sharding=chip)
+        args = (params, pools, table, tokens, mask, key)
+    hlo = prog._jit.lower(*args).compile().as_text()
+    assert "_fused_sample_kernel" in hlo
+    assert ("_paged_decode_kernel" in hlo) == (T == 1)
+    n_pools = len(jax.tree.leaves(pools))
+    assert honored_alias_count(hlo) == n_pools, hlo.splitlines()[0][:400]
+    assert _whole_copies(hlo, pools[0][0].shape, prefetches=False) == {}
 
 
 @pytest.mark.parametrize(

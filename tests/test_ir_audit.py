@@ -349,6 +349,22 @@ class TestAuditSet:
         # every audited program carries a usable static cost
         for rep in aud._snapshot():
             assert rep.cost is not None and rep.cost.eqns > 0
+        # every family of engine program that takes the KV pools was
+        # audited, declares them donated, and has each one aliased
+        from rl_tpu.compile.auditset import POOL_PROGRAM_FAMILIES, check_pool_programs
+
+        check_pool_programs(aud, POOL_PROGRAM_FAMILIES)
+        assert "R102" not in rules_of(aud.findings())
+
+    def test_undonated_pool_program_fails_the_set(self, undonated_programs):
+        """Take the donation off the engine's registrations (the parent's
+        programs): the pool gate names the first program that copies."""
+        from rl_tpu.compile.auditset import run_ir_audit
+
+        undonated_programs()
+        _, status = run_ir_audit(include=["serving_pools"])
+        assert status["serving_pools"].startswith("build failed: RuntimeError: engine program")
+        assert "donates 0 pool(s)" in status["serving_pools"]
 
 
 # ---------------------------------------------------------------------------

@@ -152,6 +152,75 @@ def train(state):
         out = analyze_sources({"m": src}, rules=["R002"])
         assert [f.qualname for f in out] == ["train"]
 
+    # the serving engine's idiom: programs made by a registry's
+    # ``register(..., donate_argnums=...)``, handed out by a getter
+    ENGINE = """
+class Engine:
+    def _get_prog(self, k):
+        prog = self._progs.get(k)
+        if prog is None:
+            prog = self._progs[k] = self._registry.register(
+                f"decode.k{k}", self._fn, donate_argnums=(1,))
+        return prog
+
+    def _get_copy(self, n):
+        return self._registry.register("copy", self._copy_fn, donate_argnums=(0,))
+
+    def ok(self, k):
+        pools = self.cache
+        if self.flag:
+            tok, new_pools = self._get_prog(k)(self.params, pools)
+        else:
+            prog = self._get_prog(k)
+            tok, new_pools = prog(self.params, pools)
+        self.cache = new_pools
+        return tok
+
+    def reads_consumed(self, k):
+        pools = self.cache
+        prog = self._get_prog(k)
+        tok, new_pools = prog(self.params, pools)
+        self.cache = new_pools
+        return pools[0].sum()
+
+    def direct_call_reads_consumed(self, n, src, dst):
+        pools = self.cache
+        self.cache = self._get_copy(n)(pools, src, dst)
+        return pools
+
+    def loop_without_rebinding(self, k):
+        pools = self.cache
+        prog = self._get_prog(k)
+        for _ in range(3):
+            out = prog(self.params, pools)
+        return out
+"""
+
+    def test_registry_programs_behind_getters_are_tracked(self):
+        out = analyze_sources({"m": self.ENGINE}, rules=["R002"])
+        assert sorted(f.qualname for f in out) == [
+            "Engine.direct_call_reads_consumed",
+            "Engine.loop_without_rebinding",
+            "Engine.reads_consumed",
+        ]
+
+    def test_serving_engine_donations_are_seen_and_clean(self):
+        """The rule sees every call of a pool-taking engine program (a
+        clean result is not an empty one) and none reads a consumed pool."""
+        from rl_tpu.analysis.core import ModuleIndex
+        from rl_tpu.analysis.rules import donating_calls
+
+        path = os.path.join(REPO, "rl_tpu", "models", "serving.py")
+        with open(path) as f:
+            m = ModuleIndex("rl_tpu.models.serving", "rl_tpu/models/serving.py", f.read())
+        seen = {(fn.display.split(".")[-1], pos[0]) for fn, _, _, pos in donating_calls(m)}
+        assert seen == {
+            ("_admit", (1,)), ("_launch", (1,)), ("_launch_spec", (1,)),
+            ("prefill_detached", (1,)), ("_dispatch_cow", (0,)),
+        }
+        out = analyze_paths([path], root=REPO, rules=["R002"])
+        assert out == [], [f.format() for f in out]
+
 
 # ---------------------------------------------------------------------------
 # R003: PRNG key reuse

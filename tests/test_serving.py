@@ -200,6 +200,205 @@ class TestPackedPoolEngine:
             assert c["pool_k"].dtype == (jnp.int8 if cfg.kv_int8 else jnp.bfloat16)
 
 
+# every flavour of engine the repo tests, each through the programs it
+# alone runs: the plain and the slot-stream decode and prefill, the
+# partial prefill behind a copy-on-write fork, the speculative verify,
+# four arrays a layer (int8 pools and scales), one stacked pool a side
+_DONATED_FLAVOURS = {
+    "greedy": dict(engine=dict(greedy=True)),
+    "sampled": dict(engine=dict(temperature=0.9)),
+    "slot_rng": dict(engine=dict(slot_rng=True)),
+    "prefix_cache_cow_fork": dict(engine=dict(prefix_cache=True), counts="kv_cow_copies_total"),
+    "speculative": dict(
+        engine=dict(prefix_cache=True, speculative=True, spec_lookahead=3, greedy=True),
+        counts="spec_dispatches", passes=2,
+    ),
+    "int8_pools": dict(model=dict(kv_int8=True), engine=dict(greedy=True)),
+    # (an auditor of its own: flax's lifted scan leaves a dead broadcast
+    # pass in the traced program, which the session's IR gate would report)
+    "looped_stacked_pool": dict(
+        model=dict(loop_steps=2, scan_layers=True, tie_embeddings=False),
+        engine=dict(greedy=True), own_auditor=True,
+    ),
+}
+
+
+def _pool_arrays(eng):
+    from rl_tpu.models.serving import _pools_from
+
+    return jax.tree.leaves(_pools_from(eng.cache))
+
+
+def _serve(m, params, engine_kw, passes=1, own_auditor=False):
+    """(engine, [(tokens, log-probs) in submit order, a pass after another])
+    over prompts that share a prefix ending inside a block."""
+    if own_auditor:
+        from rl_tpu.analysis.ir import IRAuditor
+        from rl_tpu.compile import ProgramRegistry
+
+        engine_kw = dict(engine_kw, registry=ProgramRegistry(auditor=IRAuditor()))
+    eng = ContinuousBatchingEngine(
+        m, params, n_slots=3, block_size=4, n_blocks=65, prompt_buckets=(16, 32),
+        eos_id=None, seed=3, decode_chunk=2, **engine_kw,
+    )
+    rng = np.random.default_rng(11)
+    shared = rng.integers(1, 97, size=14)
+    prompts = [np.concatenate([shared, rng.integers(1, 97, size=k)]) for k in (3, 5, 9, 2, 7)]
+    prompts.append(shared.copy())
+    streams = []
+    for _ in range(passes):
+        rids = [eng.submit(pr, 6 + i) for i, pr in enumerate(prompts)]
+        out = eng.run()
+        streams += [(out[r].tokens, out[r].log_probs) for r in rids]
+    return eng, streams
+
+
+@pytest.fixture
+def undonated(monkeypatch, undonated_programs):
+    """Arms ``undonated_programs`` (conftest) when called, for the second
+    engine of a test."""
+    arm = undonated_programs
+
+    # one schedule for both engines: whether a step settles the chunk in
+    # flight before it admits follows the device's clock otherwise, and the
+    # legacy sampling stream splits a key a dispatch
+    monkeypatch.setattr(ContinuousBatchingEngine, "_inflight_ready", lambda self: False)
+    return arm
+
+
+class TestDonatedPools:
+    """Every engine program that takes the KV pools consumes them: the
+    output aliases the input, ``engine.cache`` is the one live reference."""
+
+    @pytest.mark.parametrize("flavour", list(_DONATED_FLAVOURS))
+    def test_donated_recorded_and_streams_equal_the_undonated(self, flavour, undonated):
+        c = _DONATED_FLAVOURS[flavour]
+        m, params = small_model(**c.get("model", {}))
+        served = (m, params, c["engine"], c.get("passes", 1), c.get("own_auditor", False))
+        eng, got = _serve(*served)
+        snap = eng.metrics_snapshot()
+        assert snap["kv_pool_calls"] > 0
+        assert snap["kv_pool_calls_aliased"] == snap["kv_pool_calls"]
+        assert snap["kv_pool_calls"] >= snap["decode_launches"] + 1  # and the prefills
+        if "counts" in c:  # the flavour's own program ran
+            assert snap[c["counts"]] >= 1
+        assert not any(a.is_deleted() for a in _pool_arrays(eng))
+        undonated()
+        ref_eng, want = _serve(*served)
+        ref = ref_eng.metrics_snapshot()
+        # a backend (here: a registration) that declines the donation only
+        # moves the counter
+        assert ref["kv_pool_calls"] == snap["kv_pool_calls"]
+        assert ref["kv_pool_calls_aliased"] == 0
+        assert len(got) == len(want)
+        for (tok, lp), (rtok, rlp) in zip(got, want):
+            np.testing.assert_array_equal(tok, rtok)
+            np.testing.assert_array_equal(lp, rlp)  # bit for bit
+
+    def test_every_pool_program_is_registered_donated(self):
+        """No program that returns the pools is left undonated, whichever
+        family it belongs to (one undonated program in the rotation copies
+        every pool again)."""
+        m, params = small_model()
+        kw = dict(n_slots=2, block_size=4, n_blocks=33, prompt_buckets=(16,))
+        plain = ContinuousBatchingEngine(m, params, **kw)
+        spec = ContinuousBatchingEngine(
+            m, params, prefix_cache=True, speculative=True, **kw
+        )
+        progs = {
+            "decode": plain._get_decode_prog(2), "prefill": plain._get_prefill_prog(1, 16),
+            "pprefill": plain._get_pprefill_prog(1, 16), "cowcopy": spec._get_cow_prog(1),
+            "sdecode": spec._get_sdecode_prog(2), "sprefill": spec._get_sprefill_prog(1, 16),
+            "spprefill": spec._get_spprefill_prog(1, 16), "verify": spec._get_verify_prog(2),
+        }
+        for family, prog in progs.items():
+            at = (0,) if family == "cowcopy" else (1,)
+            assert prog.jit_kwargs.get("donate_argnums") == at, family
+        # the slot state stays undonated: the host keeps aliases of it
+        assert "donate_argnums" not in plain._admit_update.jit_kwargs
+
+    def test_no_reader_meets_a_consumed_pool(self):
+        """Every public reader, between launches with a chunk in flight and
+        after a run; the hand-off reads its blocks out of the pools the
+        prefill returned, and the adopting engine's scatter leaves it whole."""
+        m, params = small_model()
+        kw = dict(n_slots=2, block_size=4, n_blocks=65, prompt_buckets=(16, 32),
+                  greedy=True, eos_id=None, kv_handoff=True)
+        a = ContinuousBatchingEngine(m, params, **kw)
+        b = ContinuousBatchingEngine(m, params, seed=1, **kw)
+        rng = np.random.default_rng(2)
+        prompts = [rng.integers(1, 97, size=n) for n in (9, 13, 6, 11)]
+        for pr in prompts[:3]:
+            a.submit(pr, 12)
+        for _ in range(4):  # several launches, the last one still in flight
+            assert a.step()
+            snap = a.metrics_snapshot()
+            assert snap["kv_pool_calls_aliased"] == snap["kv_pool_calls"] > 0
+            assert 0 <= a.kv_free_blocks() <= 64
+            assert a.kv_admission_probe(prompts[3], 4) == (0, 4)
+            assert not any(x.is_deleted() for x in _pool_arrays(a))
+        a.run()
+        ho = a.prefill_detached(prompts[3], 10)
+        assert ho is not None and len(ho.kv) == len(a.cache)
+        b.submit(prompts[0], 5)
+        b.step()
+        rid = b.adopt_handoff(ho)
+        out = b.run()
+        np.testing.assert_array_equal(
+            out[rid].tokens, _greedy_full_forward(m, params, prompts[3], 10)
+        )
+        for eng in (a, b):
+            assert not any(x.is_deleted() for x in _pool_arrays(eng))
+            snap = eng.metrics_snapshot()
+            assert snap["kv_pool_calls_aliased"] == snap["kv_pool_calls"] > 0
+
+    def test_reset_rebuilds_the_pools_a_failed_call_took(self):
+        """A program call that raises after consuming its inputs leaves
+        ``engine.cache`` holding deleted arrays; ``reset()`` makes them
+        anew (zeroed, same shape, dtype, sharding), nothing recompiles,
+        and the next run serves every request."""
+        m, params = small_model()
+        kw = dict(n_slots=2, block_size=4, n_blocks=33, prompt_buckets=(16,),
+                  greedy=True, eos_id=None, decode_chunk=2)
+        eng = ContinuousBatchingEngine(m, params, **kw)
+        rng = np.random.default_rng(4)
+        prompts = [rng.integers(1, 97, size=n) for n in (7, 12, 5)]
+        rids = [eng.submit(pr, 8) for pr in prompts]
+        want = eng.run()
+        progs = [eng._decode_progs[2], *eng._prefills.values()]
+        compiles = [p.stats["compiles"] for p in progs]
+        shapes = [(x.shape, x.dtype, x.sharding) for x in _pool_arrays(eng)]
+
+        real = eng._decode_progs[2]
+
+        def consumes_then_raises(*args):
+            real(*args)
+            raise RuntimeError("the device fell over after the launch")
+
+        eng._decode_progs[2] = consumes_then_raises
+        for pr in prompts:
+            eng.submit(pr, 8)
+        with pytest.raises(RuntimeError, match="fell over"):
+            eng.run()
+        assert all(x.is_deleted() for x in _pool_arrays(eng))
+        eng._decode_progs[2] = real
+        eng.reset()
+        pools = _pool_arrays(eng)
+        assert not any(x.is_deleted() for x in pools)
+        assert [(x.shape, x.dtype, x.sharding) for x in pools] == shapes
+        assert all(not np.asarray(x).any() for x in pools)
+        again = [eng.submit(pr, 8) for pr in prompts]
+        out = eng.run()
+        assert sorted(out) == again  # nothing lost, no id reused
+        for r0, r1 in zip(rids, again):
+            np.testing.assert_array_equal(out[r1].tokens, want[r0].tokens)
+        assert [p.stats["compiles"] for p in progs] == compiles
+        # pools that survived are left alone
+        keep = _pool_arrays(eng)
+        eng.reset()
+        assert all(x is y for x, y in zip(keep, _pool_arrays(eng)))
+
+
 class TestEngine:
     @pytest.mark.parametrize("width", list(_WIDTHS))
     def test_drain_recycle_and_greedy_equivalence(self, width):
