@@ -31,8 +31,9 @@ TPU-in-this-image, so this is the native equivalent, built the XLA way:
   next one runs.
 - **Host-side allocator.** Block bookkeeping (free list, table mirror,
   per-slot lengths) is plain numpy on the host. The device holds a
-  pinned mirror of the block table updated by one incremental scatter
-  per round (not a full host->device table upload per step), and the
+  pinned mirror of the block table that one registered program brings
+  up to the host's table per round (the changed entries only, never a
+  full host->device table upload per step), and the
   host accepts each drained chunk with one vectorized pass over all S
   slots (no per-token Python loop). The host mirrors are exact by
   construction: the device's stop rule (accept tokens up to
@@ -413,9 +414,14 @@ class ContinuousBatchingEngine:
         self.slot_prompt: dict[int, np.ndarray] = {}
 
         # device-resident decode state (threaded through every program; the
-        # table is pinned and updated by incremental scatters, never
-        # re-uploaded wholesale)
+        # table is pinned and brought up to the host mirror by the
+        # ``serving.table_write`` program, never re-uploaded wholesale)
         self.dev_table = jnp.full((n_slots, self.max_blocks), -1, jnp.int32)
+        # what ``dev_table`` holds, on the host: a flush writes where the
+        # mirror differs from it (so no mutation of ``table`` is missed)
+        self._table_on_device = self.table.copy()
+        self.table_write_calls = 0  # flushes that wrote
+        self.table_writes = 0  # entries those flushes wrote
         self.dev_lens = jnp.zeros(n_slots, jnp.int32)
         self.dev_active = jnp.zeros(n_slots, bool)
         self.dev_budget = jnp.zeros(n_slots, jnp.int32)
@@ -426,7 +432,6 @@ class ContinuousBatchingEngine:
         self.dev_rid = jnp.full(n_slots, -1, jnp.int32)
         self.dev_ntok = jnp.zeros(n_slots, jnp.int32)
         self._dev_all_slots = jnp.ones(n_slots, bool)
-        self._pending_table_writes: list[tuple[int, int, int]] = []
         self._inflight: collections.deque[_InFlight] = collections.deque()
 
         self.queue: list[Request] = []
@@ -532,6 +537,19 @@ class ContinuousBatchingEngine:
             if self.slot_rng
             else None
         )
+        # the host loop's own bookkeeping on the device: the table writes,
+        # and (legacy stream) the admission's key split. Undonated: the
+        # chunk in flight still reads the table that goes in
+        self._table_write = self._registry.register(
+            "serving.table_write", _table_write_fn, ir_contract=self._ir_contract,
+        )
+        self._key_split = (
+            None
+            if self.slot_rng
+            else self._registry.register(
+                "serving.key_split", _key_split_fn, ir_contract=self._ir_contract,
+            )
+        )
         # draft source: explicit instance > named source > best available
         # (the prefix tree already holds every served continuation when
         # prefix_cache is on; host n-gram prompt-lookup otherwise)
@@ -624,9 +642,12 @@ class ContinuousBatchingEngine:
             step and deactivates itself when it samples eos or runs out —
             inactive slots write to scratch and freeze their length, so
             the host only needs the token values to DRAIN outputs, never
-            to decide continuation. Returns tokens/log-probs [S, K] plus
-            the advanced device state (and the on-device metrics state,
-            which counts tokens from effectively-active slots)."""
+            to decide continuation. ``key`` is the engine's stream: the
+            program splits it as the host's ``jax.random.split`` would
+            and samples with the second half. Returns tokens/log-probs
+            [S, K] plus the advanced device state, the stream's next key
+            and the on-device metrics state, which counts tokens from
+            effectively-active slots."""
 
             def body(carry, k):
                 pools, lens, active, budget, last, dm = carry
@@ -649,7 +670,8 @@ class ContinuousBatchingEngine:
                 last = jnp.where(eff, tok, last)
                 return (new_pools, lens, active, budget, last, dm), (tok, lp)
 
-            keys = jax.random.split(key, chunk)
+            key, sub = jax.random.split(key)
+            keys = jax.random.split(sub, chunk)
             carry = (tuple(pools), lens, active, budget, last, dm)
             (pools, lens, active, budget, last, dm), (toks, lps) = jax.lax.scan(
                 body, carry, keys
@@ -662,6 +684,7 @@ class ContinuousBatchingEngine:
                 active,
                 budget,
                 last,
+                key,
                 dm,
             )
 
@@ -763,8 +786,7 @@ class ContinuousBatchingEngine:
         partial prefill: cache -> cow -> cache -> prefill -> cache."""
         n = _pow2ceil(len(cows))
         cows = cows + [cows[-1]] * (n - len(cows))
-        src = jnp.asarray([c[0] for c in cows], jnp.int32)
-        dst = jnp.asarray([c[1] for c in cows], jnp.int32)
+        src, dst = jax.device_put(tuple(np.asarray(cows, np.int32).T))
         pools = _pools_from(self.cache)
         self._rebind_pools(self._get_cow_prog(n)(pools, src, dst))
 
@@ -1017,16 +1039,12 @@ class ContinuousBatchingEngine:
             got = self._kvmem.alloc(need - have)
             if got is None:
                 return False
-            for j, b in zip(range(have, need), got):
-                self.table[slot, j] = b
-                self._pending_table_writes.append((slot, j, b))
+            self.table[slot, have:need] = got
             return True
         if need - have > len(self.free_blocks):
             return False
         for j in range(have, need):
-            b = self.free_blocks.pop()
-            self.table[slot, j] = b
-            self._pending_table_writes.append((slot, j, b))
+            self.table[slot, j] = self.free_blocks.pop()
         return True
 
     def _kv_reserved(self) -> int:
@@ -1040,20 +1058,25 @@ class ContinuousBatchingEngine:
         return self.kv_free_blocks() - self._kv_reserved()
 
     def _flush_table_writes(self):
-        """Apply the accumulated host table-mirror writes to the pinned
-        device table in ONE scatter (padded to a power-of-two count so the
-        eager scatter compiles for O(log) distinct shapes, not one per
-        count; duplicate indices carry duplicate values, so padding by
-        repetition is idempotent)."""
-        if not self._pending_table_writes:
+        """Bring the pinned device table up to the host mirror: every entry
+        that differs from what the device holds (blocks handed out, and
+        the -1s of freed rows) in ONE call of ``serving.table_write``,
+        whose positions and values go over in one ``[2, table.size]``
+        transfer. One shape for any number of writes, warmed by
+        ``aot_warmup``."""
+        pos = np.flatnonzero(self.table != self._table_on_device)
+        if not len(pos):
             return
-        w = self._pending_table_writes
-        with get_tracer().span("engine.flush_tables", {"writes": len(w)}):
-            n = _pow2ceil(len(w))
-            w = w + [w[-1]] * (n - len(w))
-            rows, cols, vals = (np.asarray(c, np.int32) for c in zip(*w))
-            self.dev_table = self.dev_table.at[rows, cols].set(jnp.asarray(vals))
-            self._pending_table_writes.clear()
+        n = len(pos)
+        with get_tracer().span("engine.flush_tables", {"writes": n}):
+            writes = np.zeros((2, self.table.size), np.int32)
+            writes[0] = self.table.size  # past the n real writes: out of range, dropped
+            writes[0, :n] = pos
+            writes[1, :n] = self.table.flat[pos]
+            self.dev_table = self._table_write(self.dev_table, jax.device_put(writes))
+            self._table_on_device.flat[pos] = writes[1, :n]
+            self.table_write_calls += 1
+            self.table_writes += n
 
     def _free_slot(self, slot: int, reason: str):
         self.completions[reason] = self.completions.get(reason, 0) + 1
@@ -1122,10 +1145,8 @@ class ContinuousBatchingEngine:
         self.slot_tokens[slot] = []
         self.slot_lps[slot] = []
         # no device-side cleanup is needed: the slot deactivated ITSELF on
-        # device (that is what finished it), and stale table-row tails are
-        # unreachable — every read is gated on kv_pos <= len, and a future
-        # occupant's len never reaches positions covered only by stale
-        # entries before fresh blocks overwrite them
+        # device (that is what finished it), and the row's -1s reach the
+        # device table with the next flush
 
     # -- public surface --------------------------------------------------------
 
@@ -1140,7 +1161,8 @@ class ContinuousBatchingEngine:
         """Pre-build the engine's whole program ladder ahead of traffic.
 
         Every ``(admit size x prompt bucket)`` prefill, every decode-chunk
-        program, and the admit merge get their abstract signatures
+        program, the admit merge, the block-table write and the key split
+        get their abstract signatures
         registered and driven through ``lower().compile()`` — or loaded
         from the persistent executable store when a previous process
         already built them. After this, steady-state traffic is
@@ -1294,7 +1316,12 @@ class ContinuousBatchingEngine:
                 vec_i32, vec_bool, vec_i32, vec_i32,
                 vec_bool, vec_i32, vec_i32, vec_i32,
             )
-            progs.append(self._admit_update)
+            self._key_split.add_signature(key_abs)
+            progs += [self._admit_update, self._key_split]
+        self._table_write.add_signature(
+            table_abs, jax.ShapeDtypeStruct((2, S * self.max_blocks), jnp.int32)
+        )
+        progs.append(self._table_write)
         return self._registry.aot_warmup(programs=progs, background=background)
 
     def metrics_snapshot(self) -> dict:
@@ -1330,6 +1357,8 @@ class ContinuousBatchingEngine:
             "kv_heads_per_row": self.kv_heads_per_row,
             "kv_pool_calls": self.kv_pool_calls,
             "kv_pool_calls_aliased": self.kv_pool_calls_aliased,
+            "table_write_calls": self.table_write_calls,
+            "table_writes": self.table_writes,
         }
         snap["prefill_tokens_computed"] = self.prefill_tokens_computed
         snap["prefill_tokens_cached"] = self.prefill_tokens_cached
@@ -1420,23 +1449,24 @@ class ContinuousBatchingEngine:
         slots[0] = s
         rid = self._next_rid
         self._next_rid += 1
-        self._flush_table_writes()
+        # the prefill reads its table rows from the host mirror; the
+        # borrowed blocks are back in the pool before any program reads
+        # the device's table, which never sees them
+        rows = self.table[slots]
         pools = _pools_from(self.cache)
         if self.slot_rng:
             rid_v = np.full(pad_a, -1, np.int32)
             rid_v[0] = rid
             fn = self._get_sprefill_prog(pad_a, bucket)
             tok, lp, new_pools = fn(
-                self.params, pools, self.dev_table[jnp.asarray(slots)],
-                jnp.asarray(tokens), jnp.asarray(mask),
-                jnp.asarray(rid_v), self._base_key,
+                self.params, pools, *jax.device_put((rows, tokens, mask, rid_v)),
+                self._base_key,
             )
         else:
-            self._key, k = jax.random.split(self._key)
+            self._key, k = self._key_split(self._key)
             fn = self._get_prefill_prog(pad_a, bucket)
             tok, lp, new_pools = fn(
-                self.params, pools, self.dev_table[jnp.asarray(slots)],
-                jnp.asarray(tokens), jnp.asarray(mask), k,
+                self.params, pools, *jax.device_put((rows, tokens, mask)), k,
             )
         self._rebind_pools(new_pools)
         self.admissions += 1
@@ -1510,9 +1540,7 @@ class ContinuousBatchingEngine:
         s = free[0]
         self.slot_need[s] = need
         blocks = [self.free_blocks.pop() for _ in range(n)]
-        for j, b in enumerate(blocks):
-            self.table[s, j] = b
-            self._pending_table_writes.append((s, j, b))
+        self.table[s, :n] = blocks
         # scatter the KV in, padded to a pow2 count with duplicate
         # index+value pairs (idempotent — the table-flush trick), so the
         # eager scatter compiles for O(log) distinct widths
@@ -1558,9 +1586,7 @@ class ContinuousBatchingEngine:
             ) = self._sadmit_update(
                 self.dev_lens, self.dev_active, self.dev_budget,
                 self.dev_last, self.dev_rid, self.dev_ntok,
-                jnp.asarray(surv), jnp.asarray(new_lens),
-                jnp.asarray(new_budget), jnp.asarray(new_last),
-                jnp.asarray(new_rid),
+                *jax.device_put((surv, new_lens, new_budget, new_last, new_rid)),
             )
         else:
             (
@@ -1568,8 +1594,8 @@ class ContinuousBatchingEngine:
                 self.dev_last,
             ) = self._admit_update(
                 self.dev_lens, self.dev_active, self.dev_budget,
-                self.dev_last, jnp.asarray(surv), jnp.asarray(new_lens),
-                jnp.asarray(new_budget), jnp.asarray(new_last),
+                self.dev_last,
+                *jax.device_put((surv, new_lens, new_budget, new_last)),
             )
         # on_admit deliberately NOT fired: it runs on the caller's thread
         # (the fleet dispatcher), and admit_events is stepper-thread-only.
@@ -1651,9 +1677,7 @@ class ContinuousBatchingEngine:
                     # dispatched yet — stop batching; next round the
                     # dispatch order makes the share safe
                     break
-                for j, b in enumerate(plan.blocks):
-                    self.table[s, j] = b
-                    self._pending_table_writes.append((s, j, b))
+                self.table[s, : len(plan.blocks)] = plan.blocks
                 self._slot_lease[s] = plan.lease
                 self.slot_need[s] = req.blocks
                 starts.append(plan.shared_len)
@@ -1722,16 +1746,19 @@ class ContinuousBatchingEngine:
                 self.slot_prompt[req.rid] = req.prompt
                 self.slot_tokens[s] = []
                 self.slot_lps[s] = []
-            # pad rows gather slot 0's (or any) table row — harmless, since an
+            # pad rows take slot 0's (or any) table row — harmless, since an
             # inactive row never writes through its table and reads are masked
             slots = np.zeros(pad_a, np.int64)
             slots[:A] = [s for s, _ in batch]
-            self._flush_table_writes()  # prefill reads the new rows on device
+            # the prefill reads its rows from the host mirror; the device
+            # table takes them now for the decode that follows
+            rows = self.table[slots]
+            self._flush_table_writes()
             if not self.slot_rng:
                 # the legacy engine stream splits here; slot-stream mode
                 # derives keys in-program from (base_key, rid, 0) instead and
                 # must leave this stream byte-for-byte untouched
-                self._key, k = jax.random.split(self._key)
+                self._key, k = self._key_split(self._key)
             rid_v = np.full(pad_a, -1, np.int32)
             rid_v[:A] = [req.rid for _, req in batch]
             if cows:
@@ -1742,28 +1769,24 @@ class ContinuousBatchingEngine:
                 start_v[:A] = starts
                 if self.slot_rng:
                     fn = self._get_spprefill_prog(pad_a, bucket)
-                    tail = (jnp.asarray(start_v), jnp.asarray(rid_v), self._base_key)
+                    host, tail = (start_v, rid_v), (self._base_key,)
                 else:
                     fn = self._get_pprefill_prog(pad_a, bucket)
-                    tail = (jnp.asarray(start_v), k)
+                    host, tail = (start_v,), (k,)
             elif self.slot_rng:
                 fn = self._get_sprefill_prog(pad_a, bucket)
-                tail = (jnp.asarray(rid_v), self._base_key)
+                host, tail = (rid_v,), (self._base_key,)
             else:
                 fn = self._get_prefill_prog(pad_a, bucket)
-                tail = (k,)
+                host, tail = (), (k,)
             t_admit = tracer.now_us() * 1e-6
             # the program call is a span of its own: on this runtime a
             # dispatch blocks while its output pools cannot be allocated,
             # and that wait must not read as the admission's host work
             with tracer.span("engine.prefill.dispatch"):
                 tok, lp, new_pools = fn(
-                    self.params,
-                    pools,
-                    self.dev_table[jnp.asarray(slots)],
-                    jnp.asarray(tokens),
-                    jnp.asarray(mask),
-                    *tail,
+                    self.params, pools,
+                    *jax.device_put((rows, tokens, mask, *host)), *tail,
                 )
             self._rebind_pools(new_pools)
             if self._kvmem is not None:
@@ -1833,11 +1856,7 @@ class ContinuousBatchingEngine:
                         self.dev_last,
                         self.dev_rid,
                         self.dev_ntok,
-                        jnp.asarray(surv),
-                        jnp.asarray(new_lens),
-                        jnp.asarray(new_budget),
-                        jnp.asarray(new_last),
-                        jnp.asarray(new_rid),
+                        *jax.device_put((surv, new_lens, new_budget, new_last, new_rid)),
                     )
                 else:
                     (
@@ -1850,10 +1869,7 @@ class ContinuousBatchingEngine:
                         self.dev_active,
                         self.dev_budget,
                         self.dev_last,
-                        jnp.asarray(surv),
-                        jnp.asarray(new_lens),
-                        jnp.asarray(new_budget),
-                        jnp.asarray(new_last),
+                        *jax.device_put((surv, new_lens, new_budget, new_last)),
                     )
 
     # -- the de-synced decode loop ---------------------------------------------
@@ -1923,7 +1939,7 @@ class ContinuousBatchingEngine:
         tracer = get_tracer()
         with tracer.span("engine.launch") as span:
             self._flush_table_writes()
-            run_dev = self._dev_all_slots if run.all() else jnp.asarray(run)
+            run_dev = self._dev_all_slots if run.all() else jax.device_put(run)
             pools = _pools_from(self.cache)
             if self.slot_rng:
                 fresh = chunk not in self._sdecode_progs
@@ -1931,7 +1947,6 @@ class ContinuousBatchingEngine:
             else:
                 fresh = chunk not in self._decode_progs
                 prog = self._get_decode_prog(chunk)
-                self._key, k = jax.random.split(self._key)
             # the program call to the async copies' start: the tuner's
             # dispatch interval, and a span of its own because a dispatch
             # can block on the device (its output pools' allocation)
@@ -1970,6 +1985,7 @@ class ContinuousBatchingEngine:
                         self.dev_active,
                         self.dev_budget,
                         self.dev_last,
+                        self._key,
                         self.dev_obs,
                     ) = prog(
                         self.params,
@@ -1980,7 +1996,7 @@ class ContinuousBatchingEngine:
                         self.dev_budget,
                         self.dev_last,
                         run_dev,
-                        k,
+                        self._key,
                         self.dev_obs,
                     )
                 self._rebind_pools(new_pools)
@@ -2058,7 +2074,7 @@ class ContinuousBatchingEngine:
             self._flush_table_writes()
             fresh = K not in self._verify_progs
             prog = self._get_verify_prog(K)
-            run_dev = self._dev_all_slots if run.all() else jnp.asarray(run)
+            run_dev = self._dev_all_slots if run.all() else jax.device_put(run)
             pools = _pools_from(self.cache)
             with tracer.span("engine.launch.dispatch") as disp:
                 (
@@ -2080,7 +2096,7 @@ class ContinuousBatchingEngine:
                     self.dev_budget,
                     self.dev_last,
                     run_dev,
-                    jnp.asarray(draft_np),
+                    jax.device_put(draft_np),
                     self.dev_rid,
                     self.dev_ntok,
                     self._base_key,
@@ -2331,7 +2347,7 @@ class ContinuousBatchingEngine:
         self.slot_tokens = [[] for _ in range(n)]
         self.slot_lps = [[] for _ in range(n)]
         self.slot_prompt.clear()
-        self.dev_table = jnp.full_like(self.dev_table, -1)
+        # the device table keeps its entries; the next flush writes the -1s
         self.dev_lens = jnp.zeros_like(self.dev_lens)
         self.dev_active = jnp.zeros_like(self.dev_active)
         self.dev_budget = jnp.zeros_like(self.dev_budget)
@@ -2339,10 +2355,25 @@ class ContinuousBatchingEngine:
         self.dev_rid = jnp.full_like(self.dev_rid, -1)
         self.dev_ntok = jnp.zeros_like(self.dev_ntok)
         self._slot_ctx.clear()
-        self._pending_table_writes.clear()
         self._inflight.clear()
         self.queue.clear()
         self.finished.clear()
+
+
+def _table_write_fn(table, writes):
+    """Write ``writes[1]`` at the flat positions ``writes[0]`` of the block
+    table. One shape for any number of writes: the rows past the real
+    ones carry the position ``table.size``, out of range, and are
+    dropped."""
+    flat = table.reshape(-1).at[writes[0]].set(writes[1], mode="drop")
+    return flat.reshape(table.shape)
+
+
+def _key_split_fn(key):
+    """The engine stream's next key and the key one program samples with:
+    ``jax.random.split`` as one registered program."""
+    new, sub = jax.random.split(key)
+    return new, sub
 
 
 def _admit_update_fn(lens, active, budget, last, mask, new_lens, new_budget, new_last):

@@ -190,6 +190,9 @@ class TestEngineSpans:
         for parent, call in (("engine.launch", "engine.launch.dispatch"), ("engine.admit", "engine.prefill.dispatch")):
             for p in spans(tracer, parent):
                 assert [k["name"] for k in children(evs, p)].count(call) == 1
+        # the table write's program call is the flush's own work: no child
+        flushes = spans(tracer, "engine.flush_tables")
+        assert flushes and all(children(evs, f) == [] for f in flushes)
 
     @pytest.mark.parametrize("chunk", [1, 4])
     def test_one_request_event_a_finished_request(self, tracer, model, chunk):

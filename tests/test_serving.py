@@ -399,6 +399,178 @@ class TestDonatedPools:
         assert all(x is y for x, y in zip(keep, _pool_arrays(eng)))
 
 
+
+def _churn(eng, seed, after_step, n=16):
+    """Random admissions and finishes: before each ``step()`` submit 0-2
+    requests (prompts that share a prefix ending inside a block, each
+    served again once its first pass is done) with budgets of 1-11, and
+    call ``after_step(eng)`` after it."""
+    rng = np.random.default_rng(seed)
+    shared = rng.integers(1, 97, size=14)
+    prompts = [np.concatenate([shared, rng.integers(1, 97, size=k)]) for k in (3, 5, 9, 2, 7)]
+    prompts.append(shared.copy())
+    sent = 0
+    while sent < n or eng.pending():
+        for _ in range(min(int(rng.integers(0, 3)), n - sent)):
+            eng.submit(prompts[sent % len(prompts)], int(rng.integers(1, 12)))
+            sent += 1
+        eng.step()
+        after_step(eng)
+    return eng.harvest()
+
+
+def _key_chain(seed, n):
+    """The legacy stream as the host would split it: key(seed), then each
+    key's first half, ``n`` times."""
+    keys = [jax.random.key(seed)]
+    for _ in range(n):
+        keys.append(jax.random.split(keys[-1])[0])
+    return [np.asarray(jax.random.key_data(k)).tolist() for k in keys]
+
+
+class TestTableWritesAndKeyStream:
+    """The host loop's own device work (block-table writes, the
+    admission's table rows, the sampling-key split) runs through
+    registered programs, with the same results the eager ops gave."""
+
+    @pytest.mark.parametrize("flavour", list(_DONATED_FLAVOURS))
+    def test_table_mirror_writes_and_key_stream(self, flavour):
+        c = _DONATED_FLAVOURS[flavour]
+        m, params = small_model(**c.get("model", {}))
+        kw = dict(c["engine"])
+        if c.get("own_auditor"):
+            from rl_tpu.analysis.ir import IRAuditor
+            from rl_tpu.compile import ProgramRegistry
+
+            kw["registry"] = ProgramRegistry(auditor=IRAuditor())
+        eng = ContinuousBatchingEngine(
+            m, params, n_slots=3, block_size=4, n_blocks=65, prompt_buckets=(16, 32),
+            eos_id=None, seed=3, decode_chunk=2, **kw,
+        )
+        # every table write: it lands where it says, and changes the entry
+        real_write, issued = eng._table_write, []
+
+        def table_write(table, writes):
+            before = np.asarray(table).reshape(-1)
+            pos, vals = np.asarray(writes)
+            keep = pos < before.size
+            assert (pos[~keep] == before.size).all()
+            assert (before[pos[keep]] != vals[keep]).all()  # no redundant write
+            out = real_write(table, writes)
+            want = before.copy()
+            want[pos[keep]] = vals[keep]
+            np.testing.assert_array_equal(np.asarray(out).reshape(-1), want)
+            issued.append(int(keep.sum()))
+            return out
+
+        eng._table_write = table_write
+        # every key the legacy stream hands out, in order: the admission's
+        # split takes it on the host side, a decode program inside
+        taken = []
+        if not eng.slot_rng:
+            real_split, real_get = eng._key_split, eng._get_decode_prog
+
+            def key_split(key):
+                taken.append(("admit", key))
+                return real_split(key)
+
+            def get_decode_prog(chunk):
+                prog = real_get(chunk)
+
+                def call(*args):
+                    taken.append(("launch", args[8]))
+                    return prog(*args)
+
+                return call
+
+            eng._key_split, eng._get_decode_prog = key_split, get_decode_prog
+
+        def after_step(eng):
+            dev = np.asarray(eng.dev_table)
+            np.testing.assert_array_equal(dev, eng._table_on_device)
+            live = eng.slot_rid >= 0
+            np.testing.assert_array_equal(dev[live], eng.table[live])
+            # what the device has not taken yet is a freed row's -1
+            assert ((dev == eng.table) | (eng.table < 0)).all()
+
+        done = _churn(eng, seed=7, after_step=after_step)
+        assert len(done) == 16
+        eng._flush_table_writes()
+        np.testing.assert_array_equal(np.asarray(eng.dev_table), eng.table)
+        assert (eng.table < 0).all()
+        snap = eng.metrics_snapshot()
+        assert snap["table_write_calls"] == len(issued) > 0
+        assert snap["table_writes"] == sum(issued)
+        if "counts" in c:
+            assert snap[c["counts"]] >= 1
+        if eng.slot_rng:  # the legacy stream is left as it was
+            assert eng._key_split is None
+            np.testing.assert_array_equal(
+                jax.random.key_data(eng._key), jax.random.key_data(jax.random.key(3)))
+            return
+        prefills = sum(p.stats["calls"] for p in (*eng._prefills.values(), *eng._pprefills.values()))
+        kinds = [k for k, _ in taken]
+        assert kinds.count("admit") == prefills > 0
+        assert kinds.count("launch") == snap["decode_launches"] > 0
+        chain = _key_chain(3, len(taken))
+        assert [np.asarray(jax.random.key_data(k)).tolist() for _, k in taken] == chain[:-1]
+        assert np.asarray(jax.random.key_data(eng._key)).tolist() == chain[-1]
+
+    @pytest.mark.parametrize(
+        "flavour", ["sampled", "speculative"],
+    )
+    def test_warmed_engine_steps_without_compiling_or_eager_ops(self, flavour, monkeypatch):
+        """After ``aot_warmup()`` alone, traffic that admits every size of
+        the admit ladder and writes the table at many counts compiles
+        nothing, and ``step()`` binds no primitive outside a registered
+        program (host-to-device transfers aside)."""
+        from jax._src import core
+
+        from rl_tpu.compile import CompileDelta
+
+        c = _DONATED_FLAVOURS[flavour]
+        m, params = small_model()
+        # shapes of this test's own, so nothing earlier in the process warmed them
+        eng = ContinuousBatchingEngine(
+            m, params, n_slots=5, block_size=4, n_blocks=71, max_seq_len=88,
+            prompt_buckets=(16, 24), eos_id=None, seed=3, decode_chunk=1, **c["engine"],
+        )
+        eng.aot_warmup()
+        bound, stepping = [], [False]
+        bind = core.Primitive.bind
+
+        def counting_bind(self, *args, **params):
+            if stepping[0]:
+                bound.append(self.name)
+            return bind(self, *args, **params)
+
+        monkeypatch.setattr(core.Primitive, "bind", counting_bind)
+        real_step = eng.step
+
+        def step():
+            stepping[0] = True
+            try:
+                return real_step()
+            finally:
+                stepping[0] = False
+
+        eng.step = step
+        rng = np.random.default_rng(2)
+        with CompileDelta() as d:
+            for a in (1, 2, 3, 4, 5, 5):  # admit rounds of every ladder size
+                for _ in range(a):
+                    eng.submit(rng.integers(1, 97, size=int(rng.integers(3, 22))), int(rng.integers(1, 9)))
+                while eng.step():
+                    pass
+            _churn(eng, seed=5, after_step=lambda e: None, n=20)
+        assert not d.supported or d.delta == 0, d.explain()
+        assert set(bound) <= {"device_put"}, sorted(set(bound))
+        snap = eng.metrics_snapshot()
+        assert snap["table_write_calls"] > 0 and snap["decode_launches"] > 0
+        if "counts" in c:
+            assert snap[c["counts"]] >= 1
+
+
 class TestEngine:
     @pytest.mark.parametrize("width", list(_WIDTHS))
     def test_drain_recycle_and_greedy_equivalence(self, width):
